@@ -1,0 +1,659 @@
+"""chip_smoke.py — the served blob path, once, on the chip.
+
+    python chip_smoke.py        (no arguments; needs one TPU, or four)
+
+One process owns the chip and hosts every codec caller of a blob
+deployment — ClusterMgr, 24 disks over 6 BlobNodes, AccessHandler,
+Scheduler, RepairWorker and one CodecService sidecar — built from the
+classes cmd.run_role builds, over the in-process transport (the only
+topology in which access, worker and blobnodes share batcher.DEFAULT
+and so one device queue). The deployment is the upstream default at its
+own widths: AccessConfig() as shipped (8 MiB blobs, EC3P3 <= 256 KiB <
+EC6P6 <= 4 MiB < EC12P4) with engine="tpu" on access, worker and
+sidecar. Data comes from one seed.
+
+It PUTs and GETs every size class plus one LRC and one MSR object,
+checks a sample of stored stripes against the numpy table engine and
+zlib, breaks a disk (degraded GET, scheduler -> worker repair, rebuilt
+shards bit-identical), drives the sidecar RPCs and the fused CRC kernel,
+and then proves the device did the work: no engine quarantined, no
+matrix refused by the Pallas gate, every large-class step on the fused
+kernel (or, with several devices, dp steps holding data on every one).
+The first failed check ends the run non-zero; nothing is downgraded to
+a warning. Without a TPU it fails before printing any result.
+
+Stdout: first line the device as JAX reports it; then the run's summary
+as one JSON line (per-phase records, bytes, compile seconds, ending in
+`"claim": null`; also written to chiprun_out/chip_smoke/summary.json);
+last line the driver's result object, exactly
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
+Wall times in the summary are smoke wall times (compilation and host
+work included), not benchmark numbers. tests/test_chip_bringup.py runs
+the same phases at TINY sizes on CPU with only the device assertions
+skipped.
+"""
+
+from __future__ import annotations
+
+import hashlib
+import importlib.metadata
+import json
+import logging
+import os
+import shutil
+import sys
+import time
+import zlib
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
+SEED = 20260926
+
+
+@dataclass(frozen=True)
+class Sizes:
+    """Object population and cluster shape. FULL is what the driver runs
+    on the chip; TINY keeps every phase and every size class (the policy
+    boundaries are AccessConfig's, so "large" still means > 4 MiB) at a
+    scale the CPU test suite can afford."""
+
+    nodes: int = 6
+    disks_per_node: int = 4
+    blob_size: int | None = None  # None = AccessConfig() as shipped
+    put_threads: int = 4
+    large: tuple[int, int] = (16, 64 << 20)  # (objects, bytes) -> EC12P4
+    mid: tuple[int, int] = (64, 1 << 20)  # -> EC6P6
+    small: tuple[int, int] = (256, 64 << 10)  # -> EC3P3
+    special_bytes: int = 64 << 20  # one object each: EC6P10L2, EC6P6MSR
+    ref_stripes: int = 2  # stripes per codemode checked against numpy
+    sidecar_shard: int = 4 << 20  # RS(12+4) shard bytes over RPC
+    crc_blocks: int = 1024
+    crc_block_len: int = 128 << 10
+    crc_tiles: tuple[int, ...] = (128, 256, 512)
+
+
+FULL = Sizes()
+TINY = Sizes(blob_size=1 << 20, put_threads=2,
+             large=(2, (4 << 20) + 4099), mid=(2, (256 << 10) + 1001),
+             small=(3, 10_007), special_bytes=300_007, ref_stripes=1,
+             sidecar_shard=8192, crc_blocks=8, crc_block_len=8192,
+             crc_tiles=(8,))
+
+# what .gitignore lists: the only paths a run may create or change
+_IGNORED_DIRS = {".git", ".jax_cache", "chiprun_out", "__pycache__",
+                 ".pytest_cache", ".hypothesis", ".cache"}
+_IGNORED_FILES = {"PROGRESS.jsonl", "COPYCHECK.json"}
+_IGNORED_SUFFIXES = (".pyc", ".so", ".so.srchash")
+
+
+def log(msg: str) -> None:
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+
+
+def payload(kind: int, idx: int, size: int) -> bytes:
+    return np.random.default_rng([SEED, kind, idx]).bytes(size)
+
+
+def tree_digest(root: str) -> dict[str, str]:
+    """sha256 of every file git would commit under ``root``."""
+    out = {}
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = [d for d in dirnames if d not in _IGNORED_DIRS]
+        for name in filenames:
+            if name in _IGNORED_FILES or name.endswith(_IGNORED_SUFFIXES):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    f.read()).hexdigest()
+    return out
+
+
+class CompileClock:
+    """Sums JAX's backend-compile durations (a persistent-cache hit
+    counts its retrieval time) and counts cache hits and misses, so a
+    cold run and a warm run can be told apart from the summary."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.seconds = 0.0
+        self.compiles = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def close(self) -> None:
+        import jax.monitoring as mon
+
+        mon.unregister_event_duration_listener(self._on_duration)
+        mon.unregister_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += duration
+            self.compiles += 1
+
+    def _on_event(self, event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+
+class Deployment:
+    """The in-process blob cluster (tests/test_blob_e2e.py's shape) with
+    every codec caller pinned to the device engine."""
+
+    def __init__(self, workdir: str, sizes: Sizes):
+        from cubefs_tpu.blob.access import (AccessConfig, AccessHandler,
+                                            NodePool)
+        from cubefs_tpu.blob.blobnode import BlobNode
+        from cubefs_tpu.blob.clustermgr import ClusterMgr
+        from cubefs_tpu.blob.mq import MessageQueue
+        from cubefs_tpu.blob.scheduler import Scheduler
+        from cubefs_tpu.blob.worker import RepairWorker
+        from cubefs_tpu.codec.service import CodecService
+        from cubefs_tpu.utils import rpc
+
+        self.cm = ClusterMgr()
+        self.cm_client = rpc.Client(self.cm)
+        self.pool = NodePool()
+        self.nodes: dict[str, BlobNode] = {}
+        for n in range(sizes.nodes):
+            addr = f"node{n}"
+            node = BlobNode(
+                node_id=n,
+                disk_paths=[os.path.join(workdir, f"n{n}d{d}")
+                            for d in range(sizes.disks_per_node)],
+                cm_client=self.cm_client, addr=addr)
+            node.register()
+            node.send_heartbeat()
+            self.pool.bind(addr, node)
+            self.nodes[addr] = node
+        self.repair_q = MessageQueue()
+        self.delete_q = MessageQueue()
+        cfg = AccessConfig(engine="tpu")
+        if sizes.blob_size is not None:
+            cfg.blob_size = sizes.blob_size
+        self.access = AccessHandler(
+            self.cm_client, self.pool, cfg,
+            repair_queue=self.repair_q, delete_queue=self.delete_q)
+        self.sched = Scheduler(self.cm, repair_queue=self.repair_q,
+                               delete_queue=self.delete_q,
+                               node_pool=self.pool)
+        self.worker = RepairWorker(rpc.Client(self.sched), self.cm_client,
+                                   self.pool, engine="tpu")
+        self.sidecar = rpc.RpcServer(
+            rpc.expose(CodecService(engine="tpu")), service="codec").start()
+
+    def stop(self) -> None:
+        self.sidecar.stop()
+        self.access._pool.shutdown(wait=True)
+        for node in self.nodes.values():
+            node.stop()
+
+    def unit_call(self, unit, method: str, bid: int | None = None):
+        args = {"disk_id": unit.disk_id, "chunk_id": unit.chunk_id}
+        if bid is not None:
+            args["bid"] = bid
+        return self.pool.get(unit.node_addr).call(method, args)
+
+
+# ---------------------------------------------------------------- phases
+
+def phase_build() -> dict:
+    """Compile the native runtime here, from runtime/src — never serve
+    from a .so that rode along in the copy."""
+    from cubefs_tpu.runtime import build as rt_build
+
+    t0 = time.time()
+    so = rt_build.build()
+    if os.path.getmtime(so) < t0 - 1:
+        raise RuntimeError(f"{so} was not rebuilt by this run")
+    rt_build.load()
+    return {"ok": True, "build_wall_s": round(time.time() - t0, 2)}
+
+
+def phase_device(device_checks: bool) -> dict:
+    import jax
+
+    from cubefs_tpu import ops
+
+    devs = ops.require_tpu() if device_checks else jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    print(f"platform={dev['platform']} device_kind={dev['kind']} "
+          f"count={dev['count']} jax={jax.__version__} "
+          f"libtpu={importlib.metadata.version('libtpu')} "
+          f"compile_cache={ops.COMPILE_CACHE_DIR}", flush=True)
+    return dev
+
+
+def phase_put(dep: Deployment, sizes: Sizes) -> tuple[dict, dict]:
+    """PUT every size class from the client threads; returns the phase
+    record and {(kind, idx): (size, Location)}."""
+    from cubefs_tpu.codec.codemode import CodeMode
+
+    classes = [  # (kind, label, forced codemode, expected, count, bytes)
+        (0, "large", None, CodeMode.EC12P4, *sizes.large),
+        (1, "mid", None, CodeMode.EC6P6, *sizes.mid),
+        (2, "small", None, CodeMode.EC3P3, *sizes.small),
+        (3, "lrc", CodeMode.EC6P10L2, CodeMode.EC6P10L2, 1,
+         sizes.special_bytes),
+        (4, "msr", CodeMode.EC6P6MSR, CodeMode.EC6P6MSR, 1,
+         sizes.special_bytes),
+    ]
+    objects: dict = {}
+    rec: dict = {"ok": True}
+
+    def put_one(job):
+        kind, idx, size, mode = job
+        return (kind, idx), (size, dep.access.put(
+            payload(kind, idx, size), codemode=mode))
+
+    with ThreadPoolExecutor(sizes.put_threads) as clients:
+        for kind, label, forced, expected, count, size in classes:
+            t0 = time.perf_counter()
+            done = list(clients.map(
+                put_one, [(kind, i, size, forced) for i in range(count)]))
+            for key, (sz, loc) in done:
+                if loc.codemode != int(expected) or loc.size != sz:
+                    raise RuntimeError(
+                        f"{label} object {key}: stored as codemode "
+                        f"{loc.codemode} size {loc.size}, want "
+                        f"{expected.name} size {sz}")
+                objects[key] = (sz, loc)
+            rec[label] = {"codemode": expected.name, "objects": count,
+                          "bytes": count * size,
+                          "smoke_wall_s": round(
+                              time.perf_counter() - t0, 3)}
+            log(f"PUT {label}: {count} x {size} B as {expected.name} in "
+                f"{rec[label]['smoke_wall_s']} s (smoke wall time)")
+    return rec, objects
+
+
+def phase_get(dep: Deployment, sizes: Sizes, objects: dict,
+              keys=None) -> dict:
+    """GET objects back and compare bytes with the seeded payload."""
+    keys = sorted(objects) if keys is None else list(keys)
+
+    def get_one(key):
+        size, loc = objects[key]
+        if dep.access.get(loc) != payload(key[0], key[1], size):
+            raise RuntimeError(f"GET {key}: bytes differ from what was PUT")
+        return size
+
+    t0 = time.perf_counter()
+    with ThreadPoolExecutor(sizes.put_threads) as clients:
+        total = sum(clients.map(get_one, keys))
+    return {"ok": True, "objects": len(keys), "bytes": total,
+            "smoke_wall_s": round(time.perf_counter() - t0, 3)}
+
+
+def reference_stripe(t, blob: bytes, shard_size: int) -> np.ndarray:
+    """The full (total, S) stripe of one blob by the plain reference:
+    NumpyEngine (table-driven GF(2^8)) over the codemode's geometry —
+    no batcher, no XOR programs, no device."""
+    from cubefs_tpu.codec.engine import get_engine
+    from cubefs_tpu.ops import msr
+
+    ref = get_engine("numpy")
+    stripe = np.zeros((t.total, shard_size), dtype=np.uint8)
+    buf = np.frombuffer(blob, dtype=np.uint8)
+    stripe.reshape(-1)[:buf.size] = buf
+    data = stripe[:t.n]
+    if t.is_msr():
+        sub = data.reshape(t.n * t.alpha, shard_size // t.alpha)
+        stripe[t.n:] = ref.matrix_apply(
+            msr.encode_rows(t.n, t.n + t.m, t.d), sub
+        ).reshape(t.m, shard_size)
+        return stripe
+    stripe[t.n:t.n + t.m] = ref.encode_parity(data, t.m)
+    for az in range(t.az_count if t.l else 0):
+        idx, ln, lm = t.local_stripe_in_az(az)
+        stripe[idx[ln:]] = ref.encode_parity(stripe[idx[:ln]], lm)
+    return stripe
+
+
+def phase_reference(dep: Deployment, sizes: Sizes, objects: dict) -> dict:
+    """A sample of stored stripes from every codemode, shard by shard,
+    against the numpy reference; every shard's stored CRC against zlib."""
+    from cubefs_tpu.codec import codemode as cm
+
+    checked: dict[str, int] = {}
+    shards = 0
+    for kind in sorted({k for k, _ in objects}):
+        size, loc = objects[(kind, 0)]
+        data = payload(kind, 0, size)
+        t = cm.tactic(loc.codemode)
+        enc = dep.access._encoder(loc.codemode)
+        sl = loc.slices[0]
+        vol = dep.cm.get_volume(sl.vid)
+        for k in range(min(sizes.ref_stripes, sl.count)):
+            blob = data[k * sl.blob_size:(k + 1) * sl.blob_size]
+            want = reference_stripe(t, blob, enc.shard_size(len(blob)))
+            for u in vol.units:
+                meta, got = dep.unit_call(u, "get_shard", sl.min_bid + k)
+                if got != want[u.index].tobytes():
+                    raise RuntimeError(
+                        f"{cm.CodeMode(loc.codemode).name} bid "
+                        f"{sl.min_bid + k} shard {u.index}: stored bytes "
+                        f"differ from the numpy reference")
+                if zlib.crc32(got) != meta["crc"]:
+                    raise RuntimeError(
+                        f"bid {sl.min_bid + k} shard {u.index}: stored "
+                        f"crc {meta['crc']} != zlib")
+                shards += 1
+            name = cm.CodeMode(loc.codemode).name
+            checked[name] = checked.get(name, 0) + 1
+    return {"ok": True, "stripes": checked, "shards": shards}
+
+
+def _capture_disk(dep: Deployment, disk_id: int) -> dict:
+    """{(vid, unit_index): {bid: shard bytes}} for every unit on a disk."""
+    held = {}
+    for vid, index in dep.cm.volumes_on_disk(disk_id):
+        u = dep.cm.get_volume(vid).units[index]
+        meta, _ = dep.unit_call(u, "list_chunk")
+        held[(vid, index)] = {
+            bid: dep.unit_call(u, "get_shard", bid)[1]
+            for bid, _, _ in meta["shards"]}
+    return held
+
+
+def phase_break_repair(dep: Deployment, sizes: Sizes,
+                       objects: dict) -> dict:
+    """Break the disk under data shard 0 of an EC12P4, the LRC and the
+    MSR volume (one disk where they share it, else one after another):
+    degraded GET, scheduler -> worker repair, rebuilt shards compared
+    with the copies captured before the break, healthy GET after."""
+    from cubefs_tpu.blob.types import DiskStatus
+    from cubefs_tpu.utils import metrics
+
+    targets = [(0, 0), (3, 0), (4, 0)]  # large, lrc, msr
+    fallbacks0 = dict(metrics.repair_msr_fallbacks.samples())
+    rounds = []
+    bytes_rebuilt = 0
+    pending = list(targets)
+    while pending:
+        # the disk under shard 0 of the first pending target, plus any
+        # other pending target with a DATA shard on that same disk
+        vid0 = objects[pending[0]][1].slices[0].vid
+        disk = dep.cm.get_volume(vid0).units[0].disk_id
+        hit = []
+        for key in pending:
+            loc = objects[key][1]
+            t = dep.access._encoder(loc.codemode).t
+            vol = dep.cm.get_volume(loc.slices[0].vid)
+            if any(u.disk_id == disk and u.index < t.n for u in vol.units):
+                hit.append(key)
+        pending = [k for k in pending if k not in hit]
+
+        t0 = time.perf_counter()
+        held = _capture_disk(dep, disk)
+        node = next(n for n in dep.nodes.values() if disk in n.disk_ids)
+        node.break_disk(disk)
+
+        recon0 = sum(v for _, v in metrics.reconstruct_reads.samples())
+        phase_get(dep, sizes, objects, hit)
+        degraded = sum(
+            v for _, v in metrics.reconstruct_reads.samples()) - recon0
+        if degraded < len(hit):
+            raise RuntimeError(
+                f"disk {disk}: {len(hit)} degraded GETs but only "
+                f"{degraded} reconstruct reads counted")
+
+        n_tasks = dep.sched.mark_disk_broken(disk)
+        if n_tasks != len(held):
+            raise RuntimeError(f"disk {disk}: {len(held)} units held, "
+                               f"{n_tasks} repair tasks queued")
+        for _ in range(n_tasks * (dep.sched.MAX_ATTEMPTS + 1)):
+            if not dep.worker.run_once():
+                break
+        if dep.worker.failed:
+            errs = sorted({t.get("last_error", "") for t in
+                           dep.sched.tasks.values() if t.get("last_error")})
+            raise RuntimeError(f"disk {disk}: {dep.worker.failed} repair "
+                               f"task runs failed: {errs[:3]}")
+        if dep.cm.disks[disk].status != DiskStatus.REPAIRED:
+            raise RuntimeError(f"disk {disk} not REPAIRED after the drain")
+
+        for (vid, index), shards in held.items():
+            u = dep.cm.get_volume(vid).units[index]
+            if u.disk_id == disk:
+                raise RuntimeError(f"vid {vid} unit {index} still on "
+                                   f"broken disk {disk}")
+            for bid, want in shards.items():
+                if dep.unit_call(u, "get_shard", bid)[1] != want:
+                    raise RuntimeError(
+                        f"vid {vid} unit {index} bid {bid}: rebuilt shard "
+                        f"differs from the copy taken before the break")
+                bytes_rebuilt += len(want)
+
+        # healthy again: no unit left on the broken disk (above) and the
+        # bytes come back. (Not "zero reconstruct reads": a hedged GET
+        # may legitimately decode from parity when a data read is slow.)
+        phase_get(dep, sizes, objects, hit)
+        rounds.append({"disk": disk, "units": len(held),
+                       "targets": [list(k) for k in hit],
+                       "smoke_wall_s": round(time.perf_counter() - t0, 3)})
+        log(f"disk {disk}: {len(held)} units rebuilt bit-identical, "
+            f"targets {hit}, {rounds[-1]['smoke_wall_s']} s "
+            f"(smoke wall time)")
+    fallbacks = {k: v for k, v in metrics.repair_msr_fallbacks.samples()
+                 if v != fallbacks0.get(k, 0)}
+    if fallbacks:
+        raise RuntimeError(f"MSR sub-shard repair fell back to the "
+                           f"conventional decode: {fallbacks}")
+    return {"ok": True, "rounds": rounds, "bytes_rebuilt": bytes_rebuilt,
+            "repair_codec_legs": {
+                k[0]: v for k, v in metrics.repair_codec_leg.samples()}}
+
+
+def phase_sidecar(dep: Deployment, sizes: Sizes) -> dict:
+    """BASELINE.json configs 2-4 over the sidecar's RPC socket, then the
+    fused Pallas CRC kernel once at the same shape."""
+    from cubefs_tpu.codec.engine import get_engine
+    from cubefs_tpu.ops import pallas_crc
+    from cubefs_tpu.utils import rpc
+
+    cli = rpc.Client(dep.sidecar.addr)
+    n, m, s = 12, 4, sizes.sidecar_shard
+    rng = np.random.default_rng([SEED, 9])
+    data = rng.integers(0, 256, (1, n, s), dtype=np.uint8)
+    geom = {"n": n, "m": m, "shard_size": s, "batch": 1}
+    t0 = time.perf_counter()
+
+    meta, raw = cli.call("encode", geom, data.tobytes(), timeout=600)
+    parity = np.frombuffer(raw, dtype=np.uint8).reshape(meta["shape"])
+    if not np.array_equal(parity, get_engine("numpy").encode_parity(data, m)):
+        raise RuntimeError("sidecar encode differs from the numpy reference")
+    stripe = np.concatenate([data, parity], axis=1)
+
+    bad = [1, 7]
+    present = [i for i in range(n + m) if i not in bad]
+    meta, raw = cli.call(
+        "reconstruct",
+        {"n": n, "total": n + m, "present": present, "wanted": bad,
+         "shard_size": s, "batch": 1},
+        np.ascontiguousarray(stripe[:, present[:n]]).tobytes(), timeout=600)
+    rec = np.frombuffer(raw, dtype=np.uint8).reshape(meta["shape"])
+    if not np.array_equal(rec, stripe[:, bad]):
+        raise RuntimeError("sidecar reconstruct did not return the lost "
+                           "shards")
+
+    torn = stripe.copy()
+    torn[0, n, 0] ^= 1
+    meta, _ = cli.call("verify", dict(geom, batch=2),
+                       np.concatenate([stripe, torn]).tobytes(), timeout=600)
+    if meta["ok"] != [True, False]:
+        raise RuntimeError(f"sidecar verify said {meta['ok']} for one good "
+                           f"and one torn stripe")
+
+    blocks = rng.integers(0, 256, (sizes.crc_blocks, sizes.crc_block_len),
+                          dtype=np.uint8)
+    want = np.array([zlib.crc32(b.tobytes()) for b in blocks], dtype="<u4")
+    meta, raw = cli.call("crc32", {"block_len": sizes.crc_block_len},
+                         blocks.tobytes(), timeout=600)
+    if not np.array_equal(np.frombuffer(raw, dtype="<u4"), want):
+        raise RuntimeError("sidecar crc32 differs from zlib")
+
+    if not np.array_equal(
+            np.asarray(pallas_crc.crc32_blocks_pallas(blocks)), want):
+        raise RuntimeError("pallas_crc.crc32_blocks_pallas differs from zlib")
+    for tb in sizes.crc_tiles:
+        if not pallas_crc.verify_tile(sizes.crc_block_len, 1024, tb):
+            raise RuntimeError(f"pallas_crc.verify_tile refused "
+                               f"tile_blocks={tb}")
+    return {"ok": True, "rs_bytes": int(stripe.nbytes),
+            "crc_bytes": int(blocks.nbytes),
+            "crc_tiles_verified": list(sizes.crc_tiles),
+            "smoke_wall_s": round(time.perf_counter() - t0, 3)}
+
+
+def parity_rows(n: int, m: int) -> np.ndarray:
+    from cubefs_tpu.ops import gf256
+
+    return np.ascontiguousarray(gf256.parity_matrix(n, m), dtype=np.uint8)
+
+
+def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
+    """Right answers are not enough: show where they were computed."""
+    from cubefs_tpu.codec import batcher, engine
+    from cubefs_tpu.ops import rs_kernel
+    from cubefs_tpu.utils import metrics
+
+    if engine._dead_engines:
+        raise RuntimeError(f"codec engines quarantined during the run: "
+                           f"{sorted(engine._dead_engines)} (cause logged "
+                           f"by cubefs.codec)")
+    if rs_kernel.pallas_refusals:
+        raise RuntimeError(f"Pallas gate refused matrices: "
+                           f"{rs_kernel.pallas_refusals}")
+    steps = {f"{k[0]}/{k[1]}": v
+             for k, v in metrics.codec_batch_steps.samples()}
+    device_steps = sum(v for k, v in metrics.codec_batch_steps.samples()
+                       if k[1] == "tpu")
+    if not device_steps:
+        raise RuntimeError(f"no codec step was served by the device "
+                           f"engine: {steps}")
+    rec = {"ok": True, "steps_by_op_engine": steps,
+           "pallas_gate_refusals": 0, "engines_quarantined": 0}
+
+    # every geometry the batcher ever drained for the device engine
+    large, fused = 0, 0
+    for key, q in batcher.DEFAULT._queues.items():
+        if key[1] != "tpu":
+            continue
+        s = int(key[4])
+        coeff = q.coeff if key[0] == "apply" else parity_rows(
+            int(key[2]), int(key[3]))
+        if not rs_kernel._pallas_profitable(s):
+            continue
+        large += 1
+        if device_checks and n_devices == 1:
+            if not rs_kernel.serves_fused(coeff, s):
+                raise RuntimeError(
+                    f"large-class geometry {key[0]} {coeff.shape} S={s} "
+                    f"is served by the jnp path, not the fused kernel")
+            fused += 1
+    rec["large_class_geometries"] = large
+    rec["served_by_fused_kernel"] = fused
+    if device_checks and n_devices == 1 and not large:
+        raise RuntimeError("no large-class geometry reached the batcher")
+
+    dp = {k[0]: v for k, v in metrics.codec_batch_dp_steps.samples()}
+    rec["dp_steps_by_devices_holding_input"] = dp
+    if device_checks and n_devices > 1 and not dp.get(str(n_devices)):
+        raise RuntimeError(
+            f"{n_devices} devices visible but no drained step placed its "
+            f"input on all of them: dp steps {dp}")
+    return rec
+
+
+# ------------------------------------------------------------------ run
+
+def run(sizes: Sizes, workdir: str, device_checks: bool) -> dict:
+    """All phases in order; raises at the first miss. ``device_checks``
+    False (CPU test suite) skips only what needs the chip's machine:
+    require_tpu, the forced native rebuild and the fused-kernel / dp
+    assertions."""
+    phases: dict = {}
+    t_start = time.perf_counter()
+    if device_checks:
+        phases["build"] = phase_build()
+    device = phase_device(device_checks)
+    clock = CompileClock()
+    before = tree_digest(HERE)
+
+    os.makedirs(workdir, exist_ok=True)
+    dep = Deployment(workdir, sizes)
+    try:
+        phases["put"], objects = phase_put(dep, sizes)
+        phases["get"] = phase_get(dep, sizes, objects)
+        phases["reference"] = phase_reference(dep, sizes, objects)
+        phases["break_repair"] = phase_break_repair(dep, sizes, objects)
+        phases["sidecar"] = phase_sidecar(dep, sizes)
+        phases["device_proof"] = phase_device_proof(
+            device["count"], device_checks)
+    finally:
+        clock.close()
+        dep.stop()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+    after = tree_digest(HERE)
+    if after != before:
+        changed = sorted(k for k in before.keys() | after.keys()
+                         if before.get(k) != after.get(k))
+        raise RuntimeError(f"the run changed tracked files: {changed[:10]}")
+    phases["checkout_clean"] = {"ok": True, "files": len(after)}
+
+    return {
+        "ok": all(p["ok"] for p in phases.values()),
+        "device": device,
+        "seed": SEED,
+        "phases": phases,
+        "bytes_put": sum(v["bytes"] for v in phases["put"].values()
+                         if isinstance(v, dict)),
+        "bytes_rebuilt": phases["break_repair"]["bytes_rebuilt"],
+        "compile": {"seconds": round(clock.seconds, 2),
+                    "compiles": clock.compiles,
+                    "persistent_cache_hits": clock.cache_hits,
+                    "persistent_cache_misses": clock.cache_misses,
+                    "cold": clock.cache_hits == 0},
+        "smoke_wall_s": round(time.perf_counter() - t_start, 2),
+        "claim": None,
+    }
+
+
+def result_line(summary: dict) -> str:
+    """What the driver reads off the end of stdout: these keys and no
+    others."""
+    dev = summary["device"]
+    return json.dumps({"ok": bool(summary["ok"]),
+                       "device": {"platform": dev["platform"],
+                                  "kind": dev["kind"],
+                                  "count": dev["count"]}})
+
+
+def main() -> int:
+    logging.basicConfig(
+        level=logging.INFO, stream=sys.stderr,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    os.makedirs(OUT_DIR, exist_ok=True)
+    summary = run(FULL, os.path.join(OUT_DIR, "disks"), device_checks=True)
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    print(json.dumps(summary), flush=True)
+    print(result_line(summary), flush=True)
+    return 0 if summary["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

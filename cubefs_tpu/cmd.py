@@ -47,6 +47,19 @@ def _serve(routes, cfg, audit=None):
     return srv
 
 
+def _claim_codec_device(role: str) -> None:
+    """Initialise the JAX backend at boot, not inside the first request,
+    and say which device this codec host got. With an explicit
+    JAX_PLATFORMS (deploy.cluster sets one for the chip owner) a chip
+    that is missing or held by another process raises here and the
+    role dies with JAX's message instead of serving from CPU."""
+    import jax
+
+    devs = jax.devices()
+    print(f"[{role}] codec device: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}", flush=True)
+
+
 def _heartbeat_loop(fn, interval=3.0):
     def loop():
         while True:
@@ -265,6 +278,7 @@ def run_role(cfg: dict):
         from .blob.access import AccessConfig, AccessHandler
         from .blob.mq import MessageQueue, QueueProducer
 
+        _claim_codec_device(role)
         q_dir = cfg.get("queue_dir")
         mq_members = cfg.get("mq_members")  # replicated bus (Kafka role)
         if mq_members:
@@ -289,6 +303,7 @@ def run_role(cfg: dict):
     if role == "codec":
         from .codec.service import CodecService
 
+        _claim_codec_device(role)
         svc = CodecService(engine=cfg.get("ec_engine"))
         return _serve(rpc.expose(svc), cfg), svc
 

@@ -2,7 +2,8 @@
 device-sized steps.
 
 The encode kernel sustains its headline throughput only at large batch
-dimensions (BENCH_r05 `encode_1024stripes_gibs`), but the blob plane
+dimensions (`encode_1024stripes_gibs` in
+artifacts/BENCH_tpu_r03_early.json), but the blob plane
 batches only *within* one PUT — concurrent PUTs and repair legs each
 dispatch their own tiny device step, feeding the accelerator at request
 granularity. This module is the admission layer in between: every
@@ -43,6 +44,7 @@ byte for byte (asserted in tests/test_codec_batch.py).
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -51,8 +53,10 @@ import numpy as np
 
 from ..utils import metrics
 from ..utils import trace as tracelib
-from .engine import (Engine, _call_with_fallback, engine_for, get_engine,
+from .engine import (Engine, _dispatch, engine_for, get_engine,
                      last_dispatch, resolve_leg)
+
+_log = logging.getLogger("cubefs.codec")
 
 
 class CodecAdmissionError(Exception):
@@ -432,66 +436,74 @@ class BatchCodec:
             # the COALESCED size, so concurrent tiny submissions ride
             # the engine measured best for the batch they became
             name = engine_for(int(arr.nbytes)).name
-        # stamp metrics with the leg the XOR door resolves to, so the
-        # per-engine step counters distinguish numpy from numpy-xor
         name = resolve_leg(name)
         if op == "encode":
             m = int(key[3])
-            dp_out = self._maybe_dp(name, None, arr, m)
-            if dp_out is not None:
-                out = dp_out
-            else:
-                out = _call_with_fallback(name, "encode_parity", arr, m)
+            out = self._maybe_dp(name, None, arr, m)
+            if out is None:
+                out, name = _dispatch(name, "encode_parity", arr, m)
         else:
-            dp_out = self._maybe_dp(name, coeff, arr, None)
-            if dp_out is not None:
-                out = dp_out
-            else:
-                out = _call_with_fallback(name, "matrix_apply", coeff, arr)
+            out = self._maybe_dp(name, coeff, arr, None)
+            if out is None:
+                out, name = _dispatch(name, "matrix_apply", coeff, arr)
+        # stamped AFTER dispatch with the leg that served the step (XOR
+        # door and device-loss fallback resolved): a quarantined device
+        # engine must not keep counting as 'tpu'
         metrics.codec_batch_steps.inc(op=op, engine=name)
         return out
 
     def _maybe_dp(self, name: str, coeff: np.ndarray | None,
                   arr: np.ndarray, n_parity: int | None
                   ) -> np.ndarray | None:
-        """Shard a drained step dp-wise over the visible devices (the
-        MULTICHIP_r06 dryrun recipe: batch axis split 1/n per device,
-        bit-identical). Returns None when not profitable/applicable."""
+        """Shard a drained step dp-wise over the visible devices (batch
+        axis split 1/n per device, bit-identical). Returns None when not
+        profitable/applicable."""
         if not self.dp_enabled or name not in ("tpu", "tpu-pallas"):
             return None
         if int(arr.nbytes) < self.dp_min_bytes or arr.shape[0] < 2:
             return None
-        try:
-            import jax
+        import jax
 
-            devs = jax.devices()
-            if len(devs) < 2:
-                return None
+        devs = jax.devices()
+        if len(devs) < 2:
+            return None
+        try:
             if coeff is None:
                 from ..ops import gf256
 
                 coeff = gf256.parity_matrix(int(arr.shape[1]),
                                             int(n_parity))
             dp = min(len(devs), int(arr.shape[0]))
-            fn = self._dp_fn(coeff, int(arr.shape[1]), dp)
+            fn, sharding = self._dp_fn(coeff, int(arr.shape[1]), dp)
             b = int(arr.shape[0])
             pad = (-b) % dp
             if pad:
                 arr = np.concatenate(
                     [arr, np.zeros((pad,) + arr.shape[1:],
                                    dtype=np.uint8)], axis=0)
-            out = np.asarray(fn(arr))
-            metrics.codec_batch_dp_steps.inc(dp=dp)
+            x = jax.device_put(arr, sharding)
+            out = np.asarray(fn(x))
+            # dp label = devices that actually hold a slice of the
+            # step's input, not the width that was asked for
+            metrics.codec_batch_dp_steps.inc(
+                dp=len({s.device for s in x.addressable_shards}))
             return out[:b]
         except Exception:
-            # any mesh/compile hiccup degrades to the single-device
-            # engine path — never fail a step for a sharding miss
+            # a mesh/compile failure degrades to the single-device
+            # engine path — a step never fails for a sharding miss —
+            # but it is logged: a dp path that always fails would
+            # otherwise look like a working single-device server
+            _log.exception("dp-sharded codec step failed (%d stripes, "
+                           "%d devices); serving it single-device",
+                           int(arr.shape[0]), len(devs))
             return None
 
     def _dp_fn(self, coeff: np.ndarray, n_in: int, dp: int):
+        """(jitted sharded apply, input sharding) for one matrix on a
+        dp-wide mesh; built once per (matrix, dp)."""
         digest = (coeff.tobytes(), coeff.shape, n_in, dp)
-        fn = self._dp_fns.get(digest)
-        if fn is None:
+        hit = self._dp_fns.get(digest)
+        if hit is None:
             import jax
 
             from ..parallel import mesh as meshlib
@@ -503,9 +515,11 @@ class BatchCodec:
                     devices=jax.devices()[:dp],
                     dims={"dp": dp, "tp": 1, "sp": 1})
                 self._dp_meshes[dp] = mesh
-            fn = sharded_codec.gf_matrix_apply_sharded(mesh, coeff, n_in)
-            self._dp_fns[digest] = fn
-        return fn
+            hit = (jax.jit(sharded_codec.gf_matrix_apply_sharded(
+                       mesh, coeff, n_in)),
+                   meshlib.stripe_sharding(mesh))
+            self._dp_fns[digest] = hit
+        return hit
 
 
 class AdmittedEngine:

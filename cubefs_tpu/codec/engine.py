@@ -220,13 +220,11 @@ def _platform() -> str:
     """Device class this process can actually dispatch to. Stamped into
     the persisted crossover table: a table measured on a CPU-only dev
     box routes every size class to the native engine, which is exactly
-    wrong on a TPU-attached server."""
-    try:
-        from ..ops import pallas_gf
+    wrong on a TPU-attached server. A backend that fails to initialise
+    raises (pallas_gf.on_tpu) — it is never read as "cpu"."""
+    from ..ops import pallas_gf
 
-        return "tpu" if pallas_gf.on_tpu() else "cpu"
-    except Exception:
-        return "cpu"
+    return "tpu" if pallas_gf.on_tpu() else "cpu"
 
 
 def _policy_path() -> str:
@@ -335,15 +333,17 @@ def _load_policy() -> list:
         # process pins every size class to the host engine on the one
         # machine where the device path wins, and a tpu-measured table
         # on a cpu host routes small stripes to a device that is not
-        # there. Log it and re-measure lazily on first use. An
-        # unstamped (legacy) table is assumed cpu-measured.
+        # there. Log it and re-measure for this process only: a serving
+        # process never writes into the checkout (the file is refreshed
+        # by an explicit measure_crossover()). An unstamped (legacy)
+        # table is assumed cpu-measured.
         stamped = data.get("platform", "cpu")
         here = _platform()
         if stamped != here:
             _log.warning("stale crossover policy %s: measured on %r but "
-                         "this process dispatches to %r; re-measuring",
-                         _policy_path(), stamped, here)
-            return measure_crossover()
+                         "this process dispatches to %r; re-measuring "
+                         "in memory", _policy_path(), stamped, here)
+            return measure_crossover(save=False)
         try:
             table = data["table"]
             if not (isinstance(table, list) and table
@@ -439,13 +439,18 @@ def _fallback_for(name: str) -> str | None:
     return None
 
 
-def _call_with_fallback(name: str, method: str, *args):
-    """Run an engine method, degrading down the chain on device loss.
+def _dispatch(name: str, method: str, *args) -> tuple[object, str]:
+    """Run an engine method, degrading down the chain on device loss;
+    returns (result, name of the engine that served it).
     Only RuntimeError/OSError trigger fallback (XLA device loss
     surfaces as a RuntimeError subclass) — semantic errors like shape
     mismatches would fail identically on every engine and must not
-    quarantine one. Drilled-dead engines (CUBEFS_CODEC_DEAD) are
-    skipped before dispatch without being quarantined."""
+    quarantine one. A Mosaic compile error or an HBM RESOURCE_EXHAUSTED
+    is a RuntimeError too, so every quarantine is logged with the
+    exception that caused it: the degraded chain is the device-loss
+    guarantee, not a way to leave the device unseen. Drilled-dead
+    engines (CUBEFS_CODEC_DEAD) are skipped before dispatch without
+    being quarantined."""
     requested = name
     while True:
         name = resolve_leg(name)
@@ -461,13 +466,20 @@ def _call_with_fallback(name: str, method: str, *args):
             out = getattr(eng, method)(*args)
             last_dispatch.update(
                 method=method, requested=requested, served=name)
-            return out
+            return out, name
         except (RuntimeError, OSError):
             nxt = _fallback_for(name)
             if nxt is None:
                 raise
+            _log.exception(
+                "codec engine %r failed in %s; quarantined for the life "
+                "of this process, serving from %r", name, method, nxt)
             _dead_engines.add(name)
             name = nxt
+
+
+def _call_with_fallback(name: str, method: str, *args):
+    return _dispatch(name, method, *args)[0]
 
 
 def engine_for(nbytes: int) -> Engine:

@@ -31,8 +31,33 @@ import sys
 import time
 
 
+def codec_host(topo: dict) -> str | None:
+    """The one role of a topology that may initialise the accelerator:
+    the codec sidecar where configured, else access. A chip belongs to
+    one process at a time, and JAX hands a process that cannot get it
+    the CPU without a word — so the launcher decides, not boot order."""
+    if topo.get("codec"):
+        return "codec"
+    if topo.get("blobnodes") and topo.get("access", True):
+        return "access"
+    return None
+
+
+def role_env(role: str, owner: str | None, environ) -> dict:
+    """Explicit environment for one role process. The chip owner keeps
+    the operator's JAX_PLATFORMS, or asks for the TPU by name when none
+    is set: an explicit platform makes JAX fail at start-up instead of
+    choosing CPU. Every other role is pinned to CPU."""
+    env = dict(environ)
+    if role == owner:
+        env["JAX_PLATFORMS"] = environ.get("JAX_PLATFORMS") or "tpu"
+    else:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 class Proc:
-    def __init__(self, role: str, cfg: dict, workdir: str):
+    def __init__(self, role: str, cfg: dict, workdir: str, env: dict):
         self.role = role
         path = os.path.join(workdir, f"{cfg.get('name', role)}.json")
         with open(path, "w") as f:
@@ -41,7 +66,7 @@ class Proc:
         self.log = open(self.log_path, "w")
         self.p = subprocess.Popen(
             [sys.executable, "-m", "cubefs_tpu.cmd", "-c", path],
-            stdout=self.log, stderr=subprocess.STDOUT,
+            stdout=self.log, stderr=subprocess.STDOUT, env=env,
         )
         self.addr: str | None = None
 
@@ -70,7 +95,8 @@ class Cluster:
 
     def _spawn(self, role: str, cfg: dict) -> str:
         cfg["role"] = role
-        p = Proc(role, cfg, self.workdir)
+        p = Proc(role, cfg, self.workdir,
+                 role_env(role, codec_host(self.topo), os.environ))
         self.procs.append(p)
         addr = p.wait_addr()
         self.state["roles"].setdefault(role, []).append(addr)

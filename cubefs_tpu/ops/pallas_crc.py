@@ -2,10 +2,8 @@
 
 The jnp path (crc32_kernel.linear_crc_bits) materializes the 8x bit
 expansion of every chunk in HBM before the (bits @ W) dot — on TPU that
-makes batched CRC traffic-bound at ~9x the payload (measured 1.5 GB/s
-on the judged 10k x 128KiB config, vs 52 GiB/s for the fused GF repair
-kernel). This kernel fuses unpack -> dot per VMEM tile, exactly the
-pallas_gf.py recipe:
+makes batched CRC traffic-bound at ~9x the payload. This kernel fuses
+unpack -> dot per VMEM tile, exactly the pallas_gf.py recipe:
 
     HBM uint8 tile (TB blocks, L chunk bytes) -> VMEM
       -> unpack to plane-major bits (TB, 8L) (VPU shifts)
@@ -68,11 +66,7 @@ def _parts_fn(chunk_len: int, tile_blocks: int, interpret: bool):
         r = chunks.shape[0]
         kwargs = {}
         if not interpret:
-            # renamed TPUCompilerParams -> CompilerParams across jax
-            # releases; accept either
-            params_cls = getattr(pltpu, "CompilerParams", None) or \
-                pltpu.TPUCompilerParams
-            kwargs["compiler_params"] = params_cls(
+            kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             )
         return pl.pallas_call(

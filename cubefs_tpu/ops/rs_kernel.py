@@ -19,6 +19,8 @@ reference byte-for-byte.
 from __future__ import annotations
 
 import functools
+import hashlib
+import logging
 import os
 
 import jax
@@ -28,12 +30,13 @@ import numpy as np
 from . import bitlin, gf256, msr, progcache
 
 _BITS = (1 << np.arange(8)).astype(np.int32)
+_log = logging.getLogger("cubefs.codec")
 
 
 def _use_pallas() -> bool:
-    """On real TPU the fused plane-major Pallas kernel is ~2.5x the jnp
-    bit-matmul (no 8x bit tensor in HBM); CUBEFS_NO_PALLAS=1 forces the
-    jnp path (debugging / A-B measurement)."""
+    """On real TPU the fused plane-major Pallas kernel avoids the 8x bit
+    tensor in HBM; CUBEFS_NO_PALLAS=1 forces the jnp path (debugging /
+    A-B measurement)."""
     if os.environ.get("CUBEFS_NO_PALLAS"):
         return False
     from . import pallas_gf
@@ -51,6 +54,13 @@ def _pallas_profitable(s: int) -> bool:
     return s % tile == 0 or s >= 4 * tile
 
 
+# Matrices the gate refused this process: (rows, cols, sha256[:12] of
+# the coefficients) -> cause. A refused matrix is served by the exact
+# jnp path for the life of the process; chip_smoke.py fails the run
+# when this is non-empty.
+pallas_refusals: dict[tuple[int, int, str], str] = {}
+
+
 @functools.lru_cache(maxsize=None)
 def _pallas_verified(coeff_bytes: bytes, rows: int, cols: int) -> bool:
     """Once-per-process bit-identity gate for the production dispatch:
@@ -59,24 +69,39 @@ def _pallas_verified(coeff_bytes: bytes, rows: int, cols: int) -> bool:
     Mosaic has silently miscompiled this kernel at some tile sizes —
     unlike repair (whose extras integrity leg fails loudly), encode has
     no downstream check, so wrong parity would only surface at
-    reconstruct time, after the data shards are gone."""
-    import sys
+    reconstruct time, after the data shards are gone.
 
+    Every refusal — a mismatch, or the gate itself raising (a Mosaic
+    compile error lands here) — is logged with its cause and recorded
+    in ``pallas_refusals``; the matrix then rides the jnp path."""
     from . import pallas_gf
 
     coeff = np.frombuffer(coeff_bytes, dtype=np.uint8).reshape(rows, cols)
+    key = (rows, cols, hashlib.sha256(coeff_bytes).hexdigest()[:12])
     try:
         ok = pallas_gf.verify_tile(coeff, pallas_gf.DEFAULT_TILE)
     except Exception as e:
-        print(f"rs_kernel: pallas gate errored ({e}); using jnp path",
-              file=sys.stderr)
+        _log.exception("pallas gate raised for matrix %s at tile=%d; "
+                       "serving it from the jnp path", key,
+                       pallas_gf.DEFAULT_TILE)
+        pallas_refusals[key] = f"gate raised {type(e).__name__}: {e}"
         return False
     if not ok:
-        print(
-            "rs_kernel: pallas kernel MISCOMPILES for this matrix at "
-            f"tile={pallas_gf.DEFAULT_TILE}; using jnp path",
-            file=sys.stderr)
+        _log.error("pallas kernel MISCOMPILES for matrix %s at tile=%d; "
+                   "serving it from the jnp path", key,
+                   pallas_gf.DEFAULT_TILE)
+        pallas_refusals[key] = (
+            f"mismatch vs jnp path at tile={pallas_gf.DEFAULT_TILE}")
     return ok
+
+
+def serves_fused(coeff: np.ndarray, s: int) -> bool:
+    """The one dispatch decision: does the fused Pallas kernel serve
+    this coefficient matrix at shard size ``s``? (TPU backend, pad
+    waste bounded, matrix blessed by the gate.)"""
+    return (_use_pallas() and _pallas_profitable(s)
+            and _pallas_verified(coeff.tobytes(), coeff.shape[0],
+                                 coeff.shape[1]))
 
 
 def unpack_bits(x: jax.Array) -> jax.Array:
@@ -139,13 +164,12 @@ def _encode_fn(n: int, m: int):
 def encode_parity(data: jax.Array, n_parity: int) -> jax.Array:
     """data: (..., N, S) uint8 -> parity (..., M, S) uint8."""
     n = int(data.shape[-2])
-    if _use_pallas() and _pallas_profitable(int(data.shape[-1])):
-        coeff = np.ascontiguousarray(
-            gf256.parity_matrix(n, n_parity), dtype=np.uint8)
-        if _pallas_verified(coeff.tobytes(), coeff.shape[0], coeff.shape[1]):
-            from . import pallas_gf
+    coeff = np.ascontiguousarray(
+        gf256.parity_matrix(n, n_parity), dtype=np.uint8)
+    if serves_fused(coeff, int(data.shape[-1])):
+        from . import pallas_gf
 
-            return pallas_gf.gf_matrix_apply_pallas(coeff, data)
+        return pallas_gf.gf_matrix_apply_pallas(coeff, data)
     return _encode_fn(n, n_parity)(data)
 
 
@@ -170,11 +194,7 @@ def gf_matrix_apply(coeff: np.ndarray, shards: jax.Array) -> jax.Array:
     compiles once and is cached.
     """
     coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
-    if (
-        _use_pallas()
-        and _pallas_profitable(int(shards.shape[-1]))
-        and _pallas_verified(coeff.tobytes(), coeff.shape[0], coeff.shape[1])
-    ):
+    if serves_fused(coeff, int(shards.shape[-1])):
         from . import pallas_gf
 
         return pallas_gf.gf_matrix_apply_pallas(coeff, shards)

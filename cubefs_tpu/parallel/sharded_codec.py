@@ -28,11 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):  # jax >= 0.8 canonical API
-    shard_map = jax.shard_map
-else:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
 from ..ops import bitlin, crc32_kernel, gf256, rs_kernel
 
 
@@ -55,7 +50,7 @@ def gf_matrix_apply_sharded(
         w_local = jax.lax.dynamic_slice_in_dim(w_all, idx * cols_per, cols_per, 1)
         return rs_kernel.gf_apply_bits(w_local, shards_local, psum_axis="tp")
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp", "tp", "sp"),),
@@ -96,7 +91,7 @@ def crc32_sharded(mesh: Mesh, seg_len_total: int, chunk_len: int = 512) -> calla
         total = jax.lax.psum(contrib, "sp") & 1  # XOR across devices
         return crc32_kernel.pack_crc_bits(total ^ jnp.asarray(const_bits, jnp.int32))
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp", "sp"),),
