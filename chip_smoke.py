@@ -452,8 +452,9 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
         raise RuntimeError(f"MSR sub-shard repair fell back to the "
                            f"conventional decode: {fallbacks}")
     return {"ok": True, "rounds": rounds, "bytes_rebuilt": bytes_rebuilt,
-            "repair_codec_legs": {
-                k[0]: v for k, v in metrics.repair_codec_leg.samples()}}
+            "repair_decode_legs": {
+                engine: v for (op, engine), v
+                in metrics.codec_batch_steps.samples() if op == "apply"}}
 
 
 def phase_sidecar(dep: Deployment, sizes: Sizes) -> dict:

@@ -78,10 +78,6 @@ class AccessHandler:
         self._pool = ThreadPoolExecutor(max_workers=self.cfg.max_workers)
         self._encoders: dict[int, object] = {}
         self._lock = lockwitness.make_lock("AccessHandler._lock")
-        # phase timestamps of the most recent put() on this handler
-        # (encode_admitted / alloc_done / encode_done / quorum_done),
-        # observable by tests asserting the encode overlaps allocation
-        self.last_put_timeline: dict = {}
 
     def _submit(self, fn, *args):
         # carry the request's trace context into pool workers, else the
@@ -118,21 +114,26 @@ class AccessHandler:
         enc = self._encoder(mode)
         t = enc.t
 
-        blob_size = self.cfg.blob_size
-        blobs = [data[i : i + blob_size] for i in range(0, len(data), blob_size)]
-
         # ---- async encode admission, then allocation ----
         # Admit the parity encode FIRST: the batched device step (which
         # also coalesces with concurrent PUTs/repairs of the same
         # geometry, codec/batcher.py) runs while this request does its
         # allocation round-trips, instead of starting after them.
-        shard_size = enc.shard_size(len(blobs[0]))
-        stripes = np.zeros((len(blobs), t.total, shard_size), dtype=np.uint8)
-        for i, blob in enumerate(blobs):
-            buf = np.frombuffer(blob, dtype=np.uint8)
-            stripes[i].reshape(-1)[: buf.size] = buf
-        timeline = {"encode_admitted": time.monotonic()}
-        pending = enc.encode_async(stripes)
+        with tracelib.stage("stripe_fill"):
+            blob_size = self.cfg.blob_size
+            blobs = [data[i : i + blob_size]
+                     for i in range(0, len(data), blob_size)]
+            shard_size = enc.shard_size(len(blobs[0]))
+            stripes = np.zeros((len(blobs), t.total, shard_size),
+                               dtype=np.uint8)
+            for i, blob in enumerate(blobs):
+                buf = np.frombuffer(blob, dtype=np.uint8)
+                stripes[i].reshape(-1)[: buf.size] = buf
+        # the contiguous copy of the data rows, then the enqueue (an
+        # engine without an admission surface encodes inline here)
+        with tracelib.stage("encode_submit"):
+            encode_admitted = time.monotonic()
+            pending = enc.encode_async(stripes)
 
         with tracelib.stage("bid_alloc"):
             if self.proxy is not None:  # alloc cache: no per-put cm trip
@@ -149,19 +150,15 @@ class AccessHandler:
                     "alloc_bids", {"count": len(blobs),
                                    "op_id": uuid.uuid4().hex})
                 min_bid = meta["start"]
-        timeline["alloc_done"] = time.monotonic()
-        timeline["encode_resolved_before_wait"] = pending.resolved
         # the stage is the RESIDUAL admission wait left on the critical
         # path after overlapping allocation; admitted->done wall time
         # rides as a tag on the stage span
         with tracelib.stage("encode_admission") as st:
             pending.wait()
-            timeline["encode_done"] = time.monotonic()
             if getattr(st, "span", None) is not None:
                 st.span.set_tag(
                     "encode_total_ms",
-                    round((timeline["encode_done"]
-                           - timeline["encode_admitted"]) * 1000, 3))
+                    round((time.monotonic() - encode_admitted) * 1000, 3))
 
         # ---- quorum writes ----
         quorum = self.cfg.put_quorum_override or t.put_quorum
@@ -182,8 +179,6 @@ class AccessHandler:
                     ok_per_bid[bid] += 1
                 else:
                     fails.append((bid, idx))
-        timeline["quorum_done"] = time.monotonic()
-        self.last_put_timeline = timeline
         for bid, n_ok in ok_per_bid.items():
             if n_ok < quorum:
                 if self.proxy is not None:
@@ -201,13 +196,15 @@ class AccessHandler:
                     {"type": "shard_repair", "vid": vol.vid, "bid": bid, "bad_index": idx}
                 )
 
+        with tracelib.stage("location_crc"):
+            crc = zlib.crc32(data)
         return Location(
             cluster_id=1,
             codemode=mode,
             size=len(data),
             slices=[Slice(min_bid=min_bid, vid=vol.vid, count=len(blobs),
                           blob_size=blob_size)],
-            crc=zlib.crc32(data),
+            crc=crc,
         )
 
     def _write_shard(self, vol: VolumeInfo, unit, bid: int, shard: np.ndarray):

@@ -18,9 +18,14 @@ import numpy as np
 
 from ..codec.batcher import admit
 from ..utils import metrics, rpc
+from ..utils import trace as tracelib
 from ..utils.diskhealth import DiskHealthTracker
 from .chunkstore import (ChunkStore, ChunkStoreError, CrcMismatchError,
                          ShardNotFoundError, verified_get_shard)
+
+
+_PUT_SECONDS = metrics.blobnode_shard_io.bind(op="put")
+_GET_SECONDS = metrics.blobnode_shard_io.bind(op="get")
 
 
 class BlobNode:
@@ -148,7 +153,10 @@ class BlobNode:
         t0 = time.monotonic()
         try:
             crc = store.put_shard(chunk_id, bid, data)
-            self.health.record_io(disk_id, time.monotonic() - t0)
+            dt = time.monotonic() - t0
+            self.health.record_io(disk_id, dt)
+            if tracelib.current() is not None:  # a traced request
+                _PUT_SECONDS.observe(dt)
         except (OSError, ChunkStoreError):
             self.health.record_io(disk_id, time.monotonic() - t0, ok=False)
             raise
@@ -163,7 +171,10 @@ class BlobNode:
                 store, chunk_id, bid,
                 node_addr=self.addr or str(self.node_id),
                 disk_id=disk_id, source=source)
-            self.health.record_io(disk_id, time.monotonic() - t0)
+            dt = time.monotonic() - t0
+            self.health.record_io(disk_id, dt)
+            if tracelib.current() is not None:
+                _GET_SECONDS.observe(dt)
             return out
         except CrcMismatchError:
             raise  # data integrity, not disk death: 409 path upstream

@@ -22,7 +22,7 @@ import numpy as np
 
 from ..ops import rs_kernel
 from ..codec import codemode as cm
-from ..codec.batcher import admit, last_dispatch
+from ..codec.batcher import admit
 from ..utils import metrics, rpc
 from ..utils import trace as tracelib
 from . import topology
@@ -210,6 +210,10 @@ class RepairWorker:
         with tracelib.stage("decode"):
             self._decode_groups(t, by_key, n_solve, total_code, bad_sub,
                                 writes)
+        self._write_back(task, dest, writes)
+
+    def _write_back(self, task: dict, dest,
+                    writes: list[tuple[int, bytes]]) -> None:
         with tracelib.stage("writeback"):
             for bid, shard in writes:
                 dest.call(
@@ -218,6 +222,8 @@ class RepairWorker:
                      "chunk_id": task["dest_chunk"], "bid": bid},
                     shard,
                 )
+                if tracelib.enabled():
+                    metrics.repair_bytes_rebuilt.inc(len(shard))
 
     def _decode_groups(self, t, by_key, n_solve, total_code, bad_sub,
                        writes) -> None:
@@ -250,11 +256,12 @@ class RepairWorker:
             out_pos = wanted_out.index(bad_sub)
             for start in range(0, len(group), self.batch_stripes):
                 chunk = group[start : start + self.batch_stripes]
-                batch = np.stack([
-                    np.stack([np.frombuffer(s, dtype=np.uint8)
-                              for s in shards[:n_solve]])
-                    for _, shards in chunk
-                ])  # (B, n_solve, size)
+                with tracelib.stage("decode_stack"):
+                    batch = np.stack([
+                        np.stack([np.frombuffer(s, dtype=np.uint8)
+                                  for s in shards[:n_solve]])
+                        for _, shards in chunk
+                    ])  # (B, n_solve, size)
                 if t.is_msr() and bad_sub < total_code:
                     if size % t.alpha:
                         raise RuntimeError(
@@ -266,20 +273,18 @@ class RepairWorker:
                         len(chunk), len(wanted_out), size)
                 else:
                     recovered = self.codec.matrix_apply(rows, batch)
-                # which leg actually decoded (post-fallback, post-door):
-                # the degraded-mode evidence the XOR_AB drill reads back
-                metrics.repair_codec_leg.inc(
-                    leg=last_dispatch.get("served") or "unknown")
-                for (bid, shards), rec in zip(chunk, recovered):
-                    if len(subs) > n_solve:
-                        expect = np.frombuffer(shards[n_solve], dtype=np.uint8)
-                        if not np.array_equal(rec[verify_pos], expect):
-                            raise RuntimeError(
-                                f"bid {bid}: reconstruction disagrees with "
-                                f"extra survivor {subs[n_solve]} — refusing "
-                                f"writeback (crc-conflict role)"
-                            )
-                    writes.append((bid, rec[out_pos].tobytes()))
+                with tracelib.stage("decode_verify"):
+                    for (bid, shards), rec in zip(chunk, recovered):
+                        if len(subs) > n_solve:
+                            expect = np.frombuffer(shards[n_solve],
+                                                   dtype=np.uint8)
+                            if not np.array_equal(rec[verify_pos], expect):
+                                raise RuntimeError(
+                                    f"bid {bid}: reconstruction disagrees "
+                                    f"with extra survivor {subs[n_solve]} — "
+                                    f"refusing writeback (crc-conflict role)"
+                                )
+                        writes.append((bid, rec[out_pos].tobytes()))
 
     def _execute_msr(self, task: dict, vol: VolumeInfo, t: cm.Tactic,
                      bad: int, bids: list[int], dest) -> None:
@@ -355,15 +360,14 @@ class RepairWorker:
             for beta, group in groups.items():
                 for start in range(0, len(group), self.batch_stripes):
                     chunk = group[start:start + self.batch_stripes]
-                    batch = np.stack([
-                        np.stack([np.frombuffer(per_bid[b][h],
-                                                dtype=np.uint8)
-                                  for h in helpers])
-                        for b in chunk
-                    ])  # (B, d, beta)
+                    with tracelib.stage("decode_stack"):
+                        batch = np.stack([
+                            np.stack([np.frombuffer(per_bid[b][h],
+                                                    dtype=np.uint8)
+                                      for h in helpers])
+                            for b in chunk
+                        ])  # (B, d, beta)
                     out = self.codec.matrix_apply(rows, batch)
-                    metrics.repair_codec_leg.inc(
-                        leg=last_dispatch.get("served") or "unknown")
                     for i, b in enumerate(chunk):
                         if extra is not None:
                             expect = np.frombuffer(
@@ -377,14 +381,7 @@ class RepairWorker:
                                     f"helper {extra}'s symbol")
                         writes.append(
                             (b, out[i, :alpha].reshape(-1).tobytes()))
-        with tracelib.stage("writeback"):
-            for bid, shard in writes:
-                dest.call(
-                    "put_shard",
-                    {"disk_id": task["dest_disk"],
-                     "chunk_id": task["dest_chunk"], "bid": bid},
-                    shard,
-                )
+        self._write_back(task, dest, writes)
 
     def _execute_shard_swap(self, task: dict) -> None:
         """shard_repair / shard_migrate execution (shard_disk_repairer

@@ -206,11 +206,15 @@ def _codec_view(text: str) -> dict:
             for fam in fams}
         view["program_cache"]["entries"] = total(
             "cubefs_codec_program_cache_entries")
-    legs = sorted({lb.get("leg") for n, lb, _ in series
-                   if n == "cubefs_repair_codec_leg_total"} - {None})
+    # decode dispatches (repair and degraded reads) by the leg that
+    # served them, post-fallback and post-XOR-door
+    legs = sorted({lb.get("engine") for n, lb, _ in series
+                   if n == "cubefs_codec_batch_steps_total"
+                   and lb.get("op") == "apply"} - {None})
     if legs:
         view["repair_decode_by_leg"] = {
-            leg: total("cubefs_repair_codec_leg_total", leg=leg)
+            leg: total("cubefs_codec_batch_steps_total", op="apply",
+                       engine=leg)
             for leg in legs}
     return view
 

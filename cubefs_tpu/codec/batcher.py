@@ -54,7 +54,7 @@ import numpy as np
 from ..utils import metrics
 from ..utils import trace as tracelib
 from .engine import (Engine, _dispatch, engine_for, get_engine,
-                     last_dispatch, resolve_leg)
+                     resolve_leg)
 
 _log = logging.getLogger("cubefs.codec")
 
@@ -198,7 +198,7 @@ class BatchCodec:
         concurrent submission of the same (N, M, S, engine)."""
         key, coeff, arr = self._prep_encode(engine, data, n_parity)
         if not self.enabled:  # A/B door: the unbatched control path
-            return self._engine_call(key, coeff, arr)
+            return self._engine_call(key, coeff, arr)[0]
         return self._enqueue(key, coeff, arr, timeout).result(timeout)
 
     def submit_apply(self, engine: str | None, coeff: np.ndarray,
@@ -208,7 +208,7 @@ class BatchCodec:
         with concurrent submissions sharing the identical matrix."""
         key, coeff, arr = self._prep_apply(engine, coeff, shards)
         if not self.enabled:
-            return self._engine_call(key, coeff, arr)
+            return self._engine_call(key, coeff, arr)[0]
         return self._enqueue(key, coeff, arr, timeout).result(timeout)
 
     def submit_encode_async(self, engine: str | None, data: np.ndarray,
@@ -254,7 +254,7 @@ class BatchCodec:
         """Disabled-door async submit: execute now, return resolved."""
         fut = CodecFuture(self, key, arr)
         try:
-            fut.resolve(self._engine_call(key, coeff, arr), None)
+            fut.resolve(self._engine_call(key, coeff, arr)[0], None)
         except BaseException as e:
             fut.resolve(None, e)
         return fut
@@ -393,6 +393,7 @@ class BatchCodec:
     def _one_step(self, key: tuple, coeff: np.ndarray | None,
                   step: list[CodecFuture]) -> None:
         op = key[0]
+        gather_t0 = time.perf_counter()
         arr = (step[0].arr if len(step) == 1
                else np.concatenate([s.arr for s in step], axis=0))
         n_stripes = int(arr.shape[0])
@@ -408,13 +409,18 @@ class BatchCodec:
         span.set_tag("stripes", n_stripes)
         with span:
             try:
-                out = self._engine_call(key, coeff, arr)
+                out, served = self._engine_call(key, coeff, arr)
             except BaseException as e:  # fan the step's failure back
                 for sub in step:
                     sub.resolve(None, e)
                 return
+            span.set_tag("engine", served)
         tracelib.observe_stage("codec_step", span.path,
                                time.perf_counter() - wait_now)
+        if tracelib.enabled():
+            metrics.codec_engine_phase.observe(
+                wait_now - gather_t0 if len(step) > 1 else 0.0,
+                engine=served, op=op, phase="gather")
         metrics.codec_batch_stripes.observe(n_stripes, op=op)
         off = 0
         for sub in step:  # resolve inlined: this is the hottest loop
@@ -428,7 +434,8 @@ class BatchCodec:
 
     # ---------------- device step ----------------
     def _engine_call(self, key: tuple, coeff: np.ndarray | None,
-                     arr: np.ndarray) -> np.ndarray:
+                     arr: np.ndarray) -> tuple[np.ndarray, str]:
+        """(the step's output, the engine that served it)."""
         op, label = key[0], key[1]
         name = label or os.environ.get("CUBEFS_TPU_EC_ENGINE", "tpu")
         if name == "auto":
@@ -450,7 +457,7 @@ class BatchCodec:
         # door and device-loss fallback resolved): a quarantined device
         # engine must not keep counting as 'tpu'
         metrics.codec_batch_steps.inc(op=op, engine=name)
-        return out
+        return out, name
 
     def _maybe_dp(self, name: str, coeff: np.ndarray | None,
                   arr: np.ndarray, n_parity: int | None

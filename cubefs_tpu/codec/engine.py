@@ -26,11 +26,14 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 from typing import Callable, Protocol
 
 import numpy as np
 
 from ..ops import gf256, rs_kernel, xorprog
+from ..utils import metrics
+from ..utils import trace as tracelib
 
 _log = logging.getLogger("cubefs.codec")
 
@@ -70,14 +73,61 @@ class NumpyEngine:
         return self.matrix_apply(gf256.parity_matrix(data.shape[-2], n_parity), data)
 
 
+_PHASE_SPANS = {p: f"{tracelib.PROFILE_PREFIX}codec.{p}"
+                for p in ("h2d", "launch", "wait", "d2h")}
+# A phased call waits twice where the bare call does not, and every
+# wait hands the GIL to another thread: with every step phased a step of
+# small PUTs took 9.1 ms instead of 6.6, 240 times a second (PERF.md
+# section 6, PR 26). So an engine phases at most one call in this many
+# seconds — nearly every step of large PUTs and repairs, one in ~60 of
+# small PUTs.
+PHASE_EVERY_S = 0.25
+
+
+def device_call(eng, op: str, program, host_in: np.ndarray) -> np.ndarray:
+    """One call of device engine `eng`, host array in, host array out.
+    At most once in PHASE_EVERY_S it is taken as its four steps: `h2d`
+    (device_put until the input is on the device), `launch`
+    (`program(x)`: the Python dispatch, until it returns its
+    not-yet-ready result), `wait` (until the result is ready), `d2h`
+    (np.asarray) — each one sample of
+    cubefs_codec_engine_phase_seconds{engine,op,phase} and one
+    `cubefs:codec.<phase>` profiler annotation. Every other call, and
+    every call with CUBEFS_TRACE=0, is the bare call."""
+    now = time.perf_counter()
+    if not tracelib.enabled() or now < getattr(eng, "_phase_due", 0.0):
+        return np.asarray(program(host_in))
+    eng._phase_due = now + PHASE_EVERY_S
+    import jax
+
+    def phase(name, fn, *args):
+        with tracelib.annotation(_PHASE_SPANS[name]):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                metrics.codec_engine_phase.observe(
+                    time.perf_counter() - t0, engine=eng.name, op=op,
+                    phase=name)
+
+    x = phase("h2d", lambda: jax.block_until_ready(jax.device_put(host_in)))
+    y = phase("launch", program, x)
+    phase("wait", jax.block_until_ready, y)
+    return phase("d2h", np.asarray, y)
+
+
 class JaxEngine:
     name = "tpu"
 
     def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray) -> np.ndarray:
-        return np.asarray(rs_kernel.gf_matrix_apply(coeff, np.asarray(shards)))
+        return device_call(
+            self, "apply",
+            lambda x: rs_kernel.gf_matrix_apply(coeff, x), np.asarray(shards))
 
     def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
-        return np.asarray(rs_kernel.encode_parity(np.asarray(data), n_parity))
+        return device_call(
+            self, "encode",
+            lambda x: rs_kernel.encode_parity(x, n_parity), np.asarray(data))
 
 
 class CppEngine:
@@ -377,13 +427,6 @@ _XOR_UP = {"numpy": "numpy-xor"}
 # Door closed: any routed xor leg drops back to its naive base.
 _XOR_BASE = {"numpy-xor": "numpy", "cpp-xor": "cpp"}
 
-# Last routed dispatch (best-effort, process-wide): which leg a
-# _call_with_fallback actually served vs what was requested — the
-# repair path's evidence that degraded-mode math ran where the policy
-# and the XOR door say it did.
-last_dispatch: dict = {"method": None, "requested": None, "served": None}
-
-
 def _xor_enabled() -> bool:
     """The CUBEFS_CODEC_XOR A/B door (default ON; =0 reverts routed
     host dispatches to the naive table legs). Read per call so a drill
@@ -451,7 +494,6 @@ def _dispatch(name: str, method: str, *args) -> tuple[object, str]:
     guarantee, not a way to leave the device unseen. Drilled-dead
     engines (CUBEFS_CODEC_DEAD) are skipped before dispatch without
     being quarantined."""
-    requested = name
     while True:
         name = resolve_leg(name)
         if name in _drilled_dead():
@@ -463,10 +505,7 @@ def _dispatch(name: str, method: str, *args) -> tuple[object, str]:
             continue
         eng = get_engine(name)
         try:
-            out = getattr(eng, method)(*args)
-            last_dispatch.update(
-                method=method, requested=requested, served=name)
-            return out, name
+            return getattr(eng, method)(*args), name
         except (RuntimeError, OSError):
             nxt = _fallback_for(name)
             if nxt is None:

@@ -102,6 +102,7 @@ def _apply_fn(coeff_bytes: bytes, rows: int, cols: int, tile: int,
             out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_apply",
             **kwargs,
         )(w, shards)
 
@@ -131,13 +132,17 @@ def gf_matrix_apply_pallas(coeff: np.ndarray, shards, tile: int = DEFAULT_TILE,
     *lead, c, s = shards.shape
     pad = (-s) % tile
     if pad:
-        shards = jnp.pad(shards, [*([(0, 0)] * len(lead)), (0, 0), (0, pad)])
-    flat = shards.reshape(-1, c, s + pad)
+        with jax.named_scope("gf256.pad"):
+            shards = jnp.pad(
+                shards, [*([(0, 0)] * len(lead)), (0, 0), (0, pad)])
+    with jax.named_scope("gf256.relayout"):
+        flat = shards.reshape(-1, c, s + pad)
     fn = _apply_fn(coeff.tobytes(), coeff.shape[0], coeff.shape[1], tile,
                    bool(interpret))
     outs = jax.vmap(fn)(flat)
-    out = outs.reshape(*lead, coeff.shape[0], s + pad)
-    return out[..., :s] if pad else out
+    with jax.named_scope("gf256.unpad"):
+        out = outs.reshape(*lead, coeff.shape[0], s + pad)
+        return out[..., :s] if pad else out
 
 
 def verify_tile(coeff: np.ndarray, tile: int, seed: int = 0) -> bool:
@@ -174,24 +179,32 @@ class PallasEngine:
     name = "tpu-pallas"
 
     def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray) -> np.ndarray:
+        return self._apply("apply", coeff, shards)
+
+    def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
+        from . import gf256
+
+        return self._apply(
+            "encode", gf256.parity_matrix(data.shape[-2], n_parity), data)
+
+    def _apply(self, op: str, coeff: np.ndarray, shards: np.ndarray
+               ) -> np.ndarray:
         # same miscompile gate as the rs_kernel dispatch: even when the
         # operator forces this engine, a matrix Mosaic miscompiles must
         # fall back to the exact jnp path rather than write bad parity
+        from ..codec.engine import device_call
         from . import rs_kernel
 
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         if on_tpu() and not rs_kernel._pallas_verified(
             coeff.tobytes(), coeff.shape[0], coeff.shape[1]
         ):
-            fn = rs_kernel._matrix_apply_fn(
+            program = rs_kernel._matrix_apply_fn(
                 coeff.tobytes(), coeff.shape[0], coeff.shape[1])
-            return np.asarray(fn(np.asarray(shards)))
-        return np.asarray(gf_matrix_apply_pallas(coeff, np.asarray(shards)))
-
-    def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
-        from . import gf256
-
-        return self.matrix_apply(gf256.parity_matrix(data.shape[-2], n_parity), data)
+        else:
+            def program(x):
+                return gf_matrix_apply_pallas(coeff, x)
+        return device_call(self, op, program, np.asarray(shards))
 
 
 def register() -> None:

@@ -132,18 +132,21 @@ def gf_apply_bits(
     across devices BEFORE the mod-2, which is exact (parity of a sum ==
     XOR of parities).
     """
-    x = unpack_bits(shards)
-    y = jax.lax.dot_general(
-        w_bits,
-        x,
-        ((( 1,), (x.ndim - 2,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # (8M, ..., S)
-    if x.ndim > 2:
-        y = jnp.moveaxis(y, 0, -2)
-    if psum_axis is not None:
-        y = jax.lax.psum(y, psum_axis)
-    return pack_bits(y & 1)
+    with jax.named_scope("gf256.bits.unpack"):
+        x = unpack_bits(shards)
+    with jax.named_scope("gf256.bits.dot"):
+        y = jax.lax.dot_general(
+            w_bits,
+            x,
+            ((( 1,), (x.ndim - 2,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )  # (8M, ..., S)
+        if x.ndim > 2:
+            y = jnp.moveaxis(y, 0, -2)
+        if psum_axis is not None:
+            y = jax.lax.psum(y, psum_axis)
+    with jax.named_scope("gf256.bits.pack"):
+        return pack_bits(y & 1)
 
 
 def _as_const(bits: np.ndarray) -> jax.Array:

@@ -324,6 +324,14 @@ def run_repair_ab(stripes: int = 96, k: int = 6, m: int = 6, d: int = 11,
     }
 
 
+def _apply_steps_by_engine() -> dict[str, float]:
+    """cubefs_codec_batch_steps_total{op="apply"} by the engine that
+    served the step (post-fallback, post-XOR-door)."""
+    return {engine: v
+            for (op, engine), v in metrics.codec_batch_steps.samples()
+            if op == "apply"}
+
+
 def run_fallback_ab(rounds: int = 3, stripes: int = 8,
                     shard_ec: int = 1 << 18, shard_msr: int = 49152,
                     seed: int = 0x19AB, wait_ms: float = 0.25) -> dict:
@@ -341,8 +349,7 @@ def run_fallback_ab(rounds: int = 3, stripes: int = 8,
     ABBA-ordered alternating rounds, per-leg medians, bit-identity
     across both door positions AND against the gf_matmul golden,
     reproducible schedule digests, and the served-leg evidence from
-    engine.last_dispatch."""
-    from ..codec import engine as eng
+    cubefs_codec_batch_steps_total{op="apply",engine}."""
     from ..ops import gf256, msr, xorprog
 
     k1, m1 = 6, 3
@@ -395,11 +402,15 @@ def run_fallback_ab(rounds: int = 3, stripes: int = 8,
             os.environ["CUBEFS_CODEC_XOR"] = "1" if use_xor else "0"
             leg = "xor" if use_xor else "naive"
             for lbl, coeff, data in workloads:
+                before = _apply_steps_by_engine()
                 t0 = time.perf_counter()
                 out = codec.submit_apply("tpu", coeff, data)
                 walls[lbl][leg].append(time.perf_counter() - t0)
                 outs[lbl][leg] = out
-                served[f"{lbl}:{leg}"] = eng.last_dispatch["served"]
+                # this drill is the process's only codec caller
+                served[f"{lbl}:{leg}"] = "+".join(sorted(
+                    e for e, v in _apply_steps_by_engine().items()
+                    if v > before.get(e, 0)))
     finally:
         for key, val in (("CUBEFS_CODEC_DEAD", saved_dead),
                          ("CUBEFS_CODEC_XOR", saved_door)):

@@ -11,6 +11,7 @@ config too).
 from __future__ import annotations
 
 import bisect
+import itertools
 import threading
 import time
 
@@ -38,6 +39,17 @@ class Counter(_Metric):
     def value(self, **labels) -> float:
         with self._lock:
             return self._series.get(self._key(labels), 0.0)
+
+    def bind(self, **labels):
+        """`inc` of one label set with the label lookup done once, for
+        call sites that run several times a request."""
+        k, series, lock = self._key(labels), self._series, self._lock
+
+        def inc(value: float = 1.0) -> None:
+            with lock:
+                series[k] = series.get(k, 0.0) + value
+
+        return inc
 
     def samples(self):
         with self._lock:
@@ -72,8 +84,20 @@ class Histogram(_Metric):
             s["count"] += 1
             s["sum"] += value
             i = bisect.bisect_left(self.BUCKETS, value)
-            for j in range(i, len(self.BUCKETS)):
-                s["buckets"][j] += 1
+            if i < len(self.BUCKETS):
+                s["buckets"][i] += 1
+
+    def bind(self, **labels) -> "_BoundSeries":
+        """The series of one label set, resolved once: `observe(value)`
+        on it skips the per-call label lookup — for call sites that run
+        several times a request."""
+        k = self._key(labels)
+        with self._lock:
+            s = self._series.get(k)
+            if s is None:
+                s = {"count": 0, "sum": 0.0, "buckets": [0] * len(self.BUCKETS)}
+                self._series[k] = s
+        return _BoundSeries(self, s)
 
     def observe_many(self, values, **labels) -> None:
         """Record a burst of samples under one lock acquisition — for
@@ -91,8 +115,8 @@ class Histogram(_Metric):
                 s["count"] += 1
                 s["sum"] += value
                 i = bisect.bisect_left(self.BUCKETS, value)
-                for j in range(i, len(self.BUCKETS)):
-                    s["buckets"][j] += 1
+                if i < len(self.BUCKETS):
+                    s["buckets"][i] += 1
 
     def time(self, **labels):
         metric = self
@@ -108,9 +132,33 @@ class Histogram(_Metric):
         return _Timer()
 
     def samples(self):
+        """[(label values, {"count", "sum", "buckets"})], the buckets
+        cumulative as the exposition format has them (a series keeps
+        one count a bucket, so an observation touches one)."""
         with self._lock:
-            return [(k, dict(v, buckets=list(v["buckets"])))
+            return [(k, dict(v, buckets=list(
+                        itertools.accumulate(v["buckets"]))))
                     for k, v in self._series.items()]
+
+
+class _BoundSeries:
+    """Histogram.bind(): one series and its histogram's lock."""
+    __slots__ = ("_series", "_buckets", "_bounds", "_lock")
+
+    def __init__(self, hist: Histogram, series: dict):
+        self._series = series
+        self._buckets = series["buckets"]
+        self._bounds = hist.BUCKETS
+        self._lock = hist._lock
+
+    def observe(self, value: float) -> None:
+        i = bisect.bisect_left(self._bounds, value)
+        with self._lock:
+            s = self._series
+            s["count"] += 1
+            s["sum"] += value
+            if i < len(self._buckets):
+                self._buckets[i] += 1
 
 
 class Registry:
@@ -300,6 +348,17 @@ codec_batch_errors = DEFAULT.counter(
 codec_batch_dp_steps = DEFAULT.counter(
     "cubefs_codec_batch_dp_steps_total",
     "device steps sharded dp-wise across the mesh", ("dp",))
+# where one drained step's host time goes: `gather` (the batcher's
+# concatenate; 0 for a single-submission step, so the mean is per step),
+# then inside a device engine's call `h2d`, `launch` (Python dispatch
+# until the not-yet-ready result is returned), `wait` (until it is
+# ready), `d2h` — of the calls the engine takes apart, at most one in
+# engine.PHASE_EVERY_S seconds
+codec_engine_phase = DEFAULT.histogram(
+    "cubefs_codec_engine_phase_seconds",
+    "host seconds per phase of one drained codec step",
+    ("engine", "op", "phase"),
+    buckets=(0.00005, 0.0002, 0.001, 0.005, 0.02, 0.1, 0.5, 2))
 
 # shared compiled-program cache (ops/progcache.py): one process-wide
 # capped LRU behind the msr product-matrix rows, the jitted rs_kernel
@@ -315,15 +374,6 @@ codec_program_cache_entries = DEFAULT.gauge(
     "cubefs_codec_program_cache_entries",
     "entries resident in the shared compiled-program cache")
 
-# degraded-mode codec legs (codec/engine.py): which engine actually
-# served repair decode math after the fallback chain and the
-# CUBEFS_CODEC_XOR door resolved — the drill artifact's proof that
-# repairs ran where the A/B says they did.
-repair_codec_leg = DEFAULT.counter(
-    "cubefs_repair_codec_leg_total",
-    "repair decode dispatches by the engine leg that served them "
-    "(post-fallback, post-XOR-door)", ("leg",))
-
 # repair-bandwidth observability (blob/worker.py): what a single-shard
 # repair actually pulls over the network, split by failure-domain scope
 # — the numbers the MSR sub-shard protocol (CUBEFS_CODEC_MSR) exists to
@@ -333,6 +383,9 @@ repair_bytes_pulled = DEFAULT.counter(
     "bytes downloaded from survivors by repair (full shards on the "
     "conventional path, beta-sized helper symbols on the MSR path)",
     ("scope",))  # az_local | cross_az
+repair_bytes_rebuilt = DEFAULT.counter(
+    "cubefs_repair_bytes_rebuilt_total",
+    "rebuilt shard bytes whose write-back the destination acknowledged")
 repair_subshard_reads = DEFAULT.counter(
     "cubefs_repair_subshard_reads_total",
     "beta-sized helper symbols served through read_subshard (one per "
@@ -341,6 +394,14 @@ repair_msr_fallbacks = DEFAULT.counter(
     "cubefs_repair_msr_fallback_total",
     "MSR repairs that fell back to the conventional k-shard decode",
     ("reason",))
+
+# shard I/O as the blobnode itself sees it (blob/blobnode.py: store
+# call + CRC verify, the `dt` disk health already takes) — against the
+# node client's view, the difference is transport and the caller's loop
+blobnode_shard_io = DEFAULT.histogram(
+    "cubefs_blobnode_shard_io_seconds",
+    "seconds inside BlobNode.put_shard / get_shard", ("op",),
+    buckets=(0.00005, 0.0002, 0.001, 0.005, 0.02, 0.1, 0.5, 2))
 
 # end-to-end request observability (utils/trace.py + utils/slo.py):
 # one shared per-stage histogram across every instrumented hot path,

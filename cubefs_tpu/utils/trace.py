@@ -24,6 +24,12 @@ substrate:
 - roots slower than `CUBEFS_SLOW_MS` capture their reconstructed span
   tree to a rotating JSONL beside the audit log (slow-request
   forensics), and feed the SLO tracker in `utils/slo.py`.
+- every entered span is also a `jax.profiler.TraceAnnotation`
+  (`cubefs:<operation>`, `cubefs:<path>/<stage>` for a stage), so a
+  profile of a live process shows the program's stages on the device
+  trace's own clock. While no profiler session runs that is a TraceMe
+  which records nothing; in a process that never loaded JAX (fs-plane
+  tools) nothing is bound at all.
 
 Determinism: spans never touch `time.time()` / module-global `random`
 directly — timestamps come from an injectable Clock (the
@@ -33,11 +39,13 @@ directly — timestamps come from an injectable Clock (the
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import heapq
 import json
 import os
 import random
+import sys
 import threading
 from typing import NamedTuple
 
@@ -55,7 +63,7 @@ _tenant: contextvars.ContextVar[str] = contextvars.ContextVar(
 )
 
 _collector_lock = threading.Lock()
-# trace_id -> {"root_start": float, "seq": int, "spans": [dict]}; dict
+# trace_id -> {"root_start": float, "seq": int, "spans": [Span]}; dict
 # insertion order doubles as arrival order for eviction tie-breaks.
 _traces: dict[str, dict] = {}
 _span_total = 0
@@ -149,6 +157,43 @@ def _sample_decision() -> bool:
         return _ids.random() < rate
 
 
+# ------------------------------------------------ profiler annotations
+
+PROFILE_PREFIX = "cubefs:"
+_trace_me = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+_NO_ANNOTATION = contextlib.nullcontext()
+_profile_names: dict[tuple[str, str], str] = {}
+
+
+def _profile_name(operation: str, path: str) -> str:
+    """`cubefs:<operation>`, or `cubefs:<path>/<stage>` for a
+    `stage:<stage>` span; built once per (operation, path)."""
+    key = (operation, path)
+    name = _profile_names.get(key)
+    if name is None:
+        name = (f"{PROFILE_PREFIX}{path}/{operation[6:]}"
+                if operation.startswith("stage:")
+                else PROFILE_PREFIX + operation)
+        if len(_profile_names) < 4096:  # operations are code, not data
+            _profile_names[key] = name
+    return name
+
+
+def annotation(name: str):
+    """Context manager that puts `name` into a running profiler
+    session's trace. Does nothing in a process that has not imported
+    JAX: the span layer never imports it for them."""
+    global _trace_me
+    cls = _trace_me
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation as cls
+
+        _trace_me = cls
+    return cls(name)
+
+
 # ---------------------------------------------------------------- spans
 
 class SpanRef(NamedTuple):
@@ -179,16 +224,22 @@ class Span:
         self.logs: list[tuple[float, str]] = []
         self.follows: list[dict] = []
         self._token = None
+        self._profile_as: str | None = None  # stage(): its own path's name
+        self._annotation = _NO_ANNOTATION
 
     # ---- lifecycle ----
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        self._annotation = annotation(
+            self._profile_as or _profile_name(self.operation, self.path))
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc is not None:
             self.set_tag("error", f"{type(exc).__name__}: {exc}")
         self.finish()
+        self._annotation.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -200,8 +251,8 @@ class Span:
         if self.parent_id is None and self.path:
             # end-to-end sample: the "total" pseudo-stage is what the
             # SLO tracker windows its quantiles and burn rates over
-            metrics.request_stage_seconds.observe(
-                self.duration(), path=self.path, stage="total")
+            _stage_seconds(self.path, "total").observe(
+                self.finish_ts - self.start)
         if not self.sampled:
             return
         _collect(self)
@@ -397,35 +448,63 @@ def capture() -> SpanRef | None:
 
 # ---------------------------------------------------------------- stages
 
+_stage_series: dict[tuple[str, str], object] = {}
+
+
+def _stage_seconds(path: str, stage: str):
+    """cubefs_request_stage_seconds{path,stage}, resolved once: (path,
+    stage) pairs are code, and a request observes several."""
+    series = _stage_series.get((path, stage))
+    if series is None:
+        series = _stage_series[(path, stage)] = \
+            metrics.request_stage_seconds.bind(path=path, stage=stage)
+    return series
+
+
 class _StageTimer:
     """Context manager behind stage(): a child span + one observation
-    of cubefs_request_stage_seconds{path,stage}."""
-    __slots__ = ("name", "path", "span", "t0")
+    of cubefs_request_stage_seconds{path,stage} — the span's own
+    duration, or, where there is no request span to be a child of, a
+    timed profiler annotation."""
+    __slots__ = ("name", "path", "span", "t0", "_annotation")
 
     def __init__(self, name: str, path: str | None):
         self.name = name
         self.path = path
         self.span = None
         self.t0 = 0.0
+        self._annotation = _NO_ANNOTATION
 
     def __enter__(self):
         parent = _current.get()
         if self.path is None:
             self.path = parent.path if parent is not None else ""
+        operation = f"stage:{self.name}"
+        profile_as = _profile_name(operation, self.path)
         if parent is not None:
-            self.span = start_span(f"stage:{self.name}")
-            self.span.set_tag("stage", self.name)
-            self.span.__enter__()
-        self.t0 = _clock.now()
+            span = self.span = Span(
+                operation, parent.trace_id, parent.span_id,
+                sampled=parent.sampled, path=parent.path,
+                tenant=parent.tenant)
+            span.tags["stage"] = self.name
+            span._profile_as = profile_as
+            span.__enter__()
+        else:
+            self._annotation = annotation(profile_as)
+            self._annotation.__enter__()
+            self.t0 = _clock.now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = _clock.now() - self.t0
+        span = self.span
+        if span is not None:
+            span.__exit__(exc_type, exc, tb)
+            dt = span.finish_ts - span.start
+        else:
+            dt = _clock.now() - self.t0
+            self._annotation.__exit__(exc_type, exc, tb)
         if self.path:
-            metrics.request_stage_seconds.observe(
-                dt, path=self.path, stage=self.name)
-        if self.span is not None:
-            self.span.__exit__(exc_type, exc, tb)
+            _stage_seconds(self.path, self.name).observe(dt)
         return None
 
 
@@ -451,9 +530,10 @@ def stage(name: str, path: str | None = None):
     which serves submitters it cannot see). No-ops entirely when the
     CUBEFS_TRACE door is closed or no path can be resolved.
     """
-    if not enabled():
-        return _NOOP_STAGE
-    if path is None and _current.get() is None:
+    # under a request span the door was open when the request began (a
+    # NOOP span never becomes current): read the environment only for a
+    # stage that stands alone
+    if _current.get() is None and (path is None or not enabled()):
         return _NOOP_STAGE
     return _StageTimer(name, path)
 
@@ -479,9 +559,13 @@ def _heap_key(t: dict) -> float:
     return rs if rs is not None else float("inf")
 
 
+_count_span = metrics.trace_spans_total.bind()
+
+
 def _collect(span: Span) -> None:
     global _span_total, _arrival_seq
-    d = span.to_dict()
+    # the finished Span itself is kept and turned into a dict by whoever
+    # reads the store: most traces are evicted unread
     with _collector_lock:
         t = _traces.get(span.trace_id)
         if t is None:
@@ -490,7 +574,7 @@ def _collect(span: Span) -> None:
             _traces[span.trace_id] = t
             heapq.heappush(_evict_heap,
                            (float("inf"), _arrival_seq, span.trace_id))
-        t["spans"].append(d)
+        t["spans"].append(span)
         if span.parent_id is None:
             rs = t["root_start"]
             t["root_start"] = span.start if rs is None else min(rs, span.start)
@@ -500,7 +584,7 @@ def _collect(span: Span) -> None:
                 heapq.heappush(_evict_heap,
                                (t["root_start"], t["seq"], span.trace_id))
         _span_total += 1
-        metrics.trace_spans_total.inc()
+        _count_span()
         # evict WHOLE traces, oldest-root-first, so a reconstructed
         # tree is never torn by dropping only its early spans
         while _span_total > MAX_KEPT and len(_traces) > 1 and _evict_heap:
@@ -516,8 +600,10 @@ def finished_spans(trace_id: str | None = None) -> list[dict]:
     with _collector_lock:
         if trace_id:
             t = _traces.get(trace_id)
-            return list(t["spans"]) if t else []
-        return [s for t in _traces.values() for s in t["spans"]]
+            spans = list(t["spans"]) if t else []
+        else:
+            spans = [s for t in _traces.values() for s in t["spans"]]
+    return [s.to_dict() for s in spans]
 
 
 def reset_collector() -> None:

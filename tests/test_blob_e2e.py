@@ -577,14 +577,24 @@ def test_scheduler_checkpoints_into_cm_kv(tmp_path):
 
 def test_put_admits_encode_before_alloc(cluster, rng):
     """The PUT path admits the parity encode to the codec batcher
-    BEFORE its allocation round-trips and the encode future resolves
-    before quorum commit — observable through last_put_timeline."""
+    BEFORE its allocation round-trips and the encode resolves before
+    quorum commit — observable through the PUT's stage spans."""
+    from cubefs_tpu.utils import trace as tracelib
+
+    tracelib.reset_collector()
     data = payload(rng, 200_000)
     loc = cluster.access.put(data, codemode=cmode.CodeMode.EC6P3)
-    tl = cluster.access.last_put_timeline
-    assert (tl["encode_admitted"] <= tl["alloc_done"]
-            <= tl["encode_done"] <= tl["quorum_done"])
-    assert "encode_resolved_before_wait" in tl
+    root = next(s for s in tracelib.finished_spans()
+                if s["op"] == "access.put")
+    st = {s["tags"]["stage"]: s
+          for s in tracelib.finished_spans(root["trace_id"])
+          if s["parent_id"] == root["span_id"]}
+    end = lambda s: s["start"] + s["duration"]
+    assert (st["encode_submit"]["start"] <= st["bid_alloc"]["start"]
+            <= end(st["bid_alloc"]) <= end(st["encode_admission"])
+            <= st["quorum_write"]["start"])
+    assert st["encode_admission"]["tags"]["encode_total_ms"] >= \
+        st["encode_admission"]["duration"] * 1000
     assert cluster.access.get(loc) == data
 
 

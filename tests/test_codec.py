@@ -7,6 +7,7 @@ import pytest
 
 from cubefs_tpu.codec import codemode as cm
 from cubefs_tpu.codec.encoder import CodecConfig, ECError, LrcEncoder, new_encoder
+from cubefs_tpu.codec.engine import get_engine
 
 EC_MODES = [
     m
@@ -354,14 +355,14 @@ def test_chaos_drill_full_fallback_chain_both_door_positions(monkeypatch):
     monkeypatch.setenv("CUBEFS_CODEC_DEAD", "tpu-pallas,tpu,cpp,cpp-xor")
 
     monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
-    out_on = eng._call_with_fallback("tpu", "matrix_apply", rows, recv)
-    assert eng.last_dispatch["served"] == "numpy-xor"
+    out_on, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
+    assert served == "numpy-xor"
     assert np.array_equal(out_on, gold)
     digest1 = xorprog.program_for(rows).schedule_digest
 
     monkeypatch.setenv("CUBEFS_CODEC_XOR", "0")
-    out_off = eng._call_with_fallback("tpu", "matrix_apply", rows, recv)
-    assert eng.last_dispatch["served"] == "numpy"
+    out_off, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
+    assert served == "numpy"
     assert np.array_equal(out_off, out_on)  # byte-identical across door
 
     monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
@@ -428,3 +429,165 @@ def test_lrc_local_reconstruct_edge_cases(rng):
     assert np.array_equal(enc.reconstruct(local, []), golden)  # no-op
     with pytest.raises(ECError):
         enc.reconstruct(local.copy(), [0, 1])  # > local parity budget
+
+
+# ---------------- the engine call from inside (PR 26) ----------------
+
+PHASES = ("h2d", "launch", "wait", "d2h")
+
+
+def _phase_counts(engine):
+    from cubefs_tpu.utils import metrics
+
+    return {(k[1], k[2]): s["count"]
+            for k, s in metrics.codec_engine_phase.samples()
+            if k[0] == engine}
+
+
+@pytest.fixture
+def every_call_phased(monkeypatch):
+    """An engine takes apart at most one call in PHASE_EVERY_S seconds;
+    these tests count samples, so every call."""
+    from cubefs_tpu.codec import engine
+
+    monkeypatch.setattr(engine, "PHASE_EVERY_S", 0.0)
+    for name in ("tpu", "tpu-pallas"):
+        monkeypatch.setattr(get_engine(name), "_phase_due", 0.0,
+                            raising=False)
+
+
+def _grew(before, after):
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
+def _device_engine_call(name, op, rng):
+    from cubefs_tpu.ops import gf256
+
+    eng = get_engine(name)
+    data = rng.integers(0, 256, (3, 6, 4096), dtype=np.uint8)
+    if op == "encode":
+        return (eng.encode_parity(data, 3),
+                get_engine("numpy").encode_parity(data, 3))
+    rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:2]
+    return (eng.matrix_apply(rows, data),
+            get_engine("numpy").matrix_apply(rows, data))
+
+
+@pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
+@pytest.mark.parametrize("op", ["encode", "apply"])
+def test_device_engine_call_observes_its_four_phases_once(
+        name, op, rng, monkeypatch, every_call_phased):
+    monkeypatch.delenv("CUBEFS_TRACE", raising=False)
+    before = _phase_counts(name)
+    got, want = _device_engine_call(name, op, rng)
+    assert np.array_equal(got, want)  # bit-identical to the table path
+    assert _grew(before, _phase_counts(name)) == {(op, p): 1 for p in PHASES}
+
+
+@pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
+def test_trace_door_off_is_the_bare_engine_call(name, rng, monkeypatch,
+                                                every_call_phased):
+    """CUBEFS_TRACE=0: no phase sample, no extra wait — the same bytes."""
+    got_on, want = _device_engine_call(name, "encode", rng)
+    monkeypatch.setenv("CUBEFS_TRACE", "0")
+    before = _phase_counts(name)
+    got_off, _ = _device_engine_call(
+        name, "encode", np.random.default_rng(0xC0DEC))
+    assert _phase_counts(name) == before
+    assert np.array_equal(got_off, got_on) and np.array_equal(got_off, want)
+
+
+def test_coalesced_step_observes_one_gather_and_names_its_engine(
+        rng, every_call_phased):
+    """A step of several submissions observes ONE `gather` (the
+    concatenate) beside its engine phases, and its `codec_step` span is
+    tagged with the engine that served it."""
+    from cubefs_tpu.codec.batcher import BatchCodec
+    from cubefs_tpu.utils import trace as tracelib
+
+    bc = BatchCodec(enabled=True)
+    datas = [rng.integers(0, 256, (1, 6, 2048), dtype=np.uint8)
+             for _ in range(3)]
+    bc.submit_encode("tpu", datas[0], 3)  # compiles the 1-stripe program
+    before = _phase_counts("tpu")
+    tracelib.reset_collector()
+    with tracelib.path_span("blob.put", "test.put"):
+        futs = [bc.submit_encode_async("tpu", d, 3) for d in datas]
+        outs = [f.result() for f in futs]  # first collector drains all 3
+    for d, out in zip(datas, outs):
+        assert np.array_equal(out, get_engine("numpy").encode_parity(d, 3))
+    assert _grew(before, _phase_counts("tpu")) == {
+        ("encode", p): 1 for p in PHASES + ("gather",)}
+    steps = [s for s in tracelib.finished_spans()
+             if s["op"] == "stage:codec_step"]
+    assert len(steps) == 1
+    assert steps[0]["tags"]["engine"] == "tpu"
+    assert steps[0]["tags"]["stripes"] == 3
+
+
+def test_an_engine_takes_apart_one_call_in_an_interval(rng, monkeypatch):
+    """What a phased call costs is bounded per second, not per step: the
+    next call inside PHASE_EVERY_S is the bare call, with the same bytes."""
+    from cubefs_tpu.codec import engine
+
+    eng = get_engine("tpu")
+    monkeypatch.setattr(engine, "PHASE_EVERY_S", 3600.0)
+    monkeypatch.setattr(eng, "_phase_due", 0.0, raising=False)
+    data = rng.integers(0, 256, (2, 6, 1024), dtype=np.uint8)
+    before = _phase_counts("tpu")
+    first = eng.encode_parity(data, 3)
+    assert _grew(before, _phase_counts("tpu")) == {
+        ("encode", p): 1 for p in PHASES}
+    second = eng.encode_parity(data, 3)
+    assert _grew(before, _phase_counts("tpu")) == {
+        ("encode", p): 1 for p in PHASES}
+    assert np.array_equal(first, second)
+
+
+@pytest.mark.parametrize("path,scopes", [
+    ("fused", ("gf256.pad", "gf256.relayout", "gf256.unpad",
+               "gf256_apply")),
+    ("jnp", ("gf256.bits.unpack", "gf256.bits.dot", "gf256.bits.pack")),
+])
+def test_device_work_carries_the_names_the_program_chose(path, scopes, rng):
+    """The lowered text of both kernels names its parts: what a device
+    trace shows for an op is the scope it was traced under."""
+    import jax
+
+    from cubefs_tpu.ops import gf256, pallas_gf, rs_kernel
+
+    coeff = np.ascontiguousarray(gf256.parity_matrix(6, 3), dtype=np.uint8)
+    # two leading axes and S=1000 under a tile of 512: the fused path
+    # pads, flattens the lead, runs the kernel and slices back
+    x = rng.integers(0, 256, (2, 2, 6, 1000), dtype=np.uint8)
+    if path == "fused":
+        fn = jax.jit(lambda a: pallas_gf.gf_matrix_apply_pallas(
+            coeff, a, tile=512, interpret=True))
+    else:
+        fn = rs_kernel._matrix_apply_fn(coeff.tobytes(), 3, 6)
+    text = fn.lower(x).as_text(debug_info=True)
+    for scope in scopes:
+        assert scope in text, scope
+    assert np.array_equal(np.asarray(fn(x)),
+                          get_engine("numpy").matrix_apply(coeff, x))
+
+
+def test_cli_codec_view_reads_decode_legs_from_the_step_counter(rng):
+    """`cubefs-cli metrics codec` names the legs that served decode
+    steps from cubefs_codec_batch_steps_total{op="apply",engine} — the
+    count the batcher stamps after dispatch, not a process-wide dict."""
+    from cubefs_tpu import cli
+    from cubefs_tpu.codec.batcher import BatchCodec
+    from cubefs_tpu.ops import gf256
+    from cubefs_tpu.utils import metrics
+
+    def legs():
+        return cli._codec_view(metrics.DEFAULT.render_text()).get(
+            "repair_decode_by_leg", {})
+
+    before = legs()
+    rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:1]
+    BatchCodec(enabled=True).submit_apply(
+        "numpy", rows, rng.integers(0, 256, (2, 6, 512), dtype=np.uint8))
+    assert _grew(before, legs()) == {"numpy-xor": 1}  # the XOR door's leg

@@ -29,6 +29,7 @@ from cubefs_tpu.utils import metrics, rpc, slo
 from cubefs_tpu.utils import trace as tracelib
 from cubefs_tpu.utils.retry import MONOTONIC, FakeClock
 
+from test_blob_e2e import Cluster
 from test_fs_e2e import FsCluster
 
 
@@ -380,3 +381,221 @@ def test_slow_roots_capture_tree_to_jsonl(tmp_path, monkeypatch):
         log, tracelib._slow_log = tracelib._slow_log, None
         if log is not None:
             log.close()
+
+
+# ------------------------------------- the served codec path, from inside
+
+PUT_STAGES = ("stripe_fill", "encode_submit", "bid_alloc",
+              "encode_admission", "quorum_write", "location_crc")
+
+
+def _tpu_cluster(tmp_path, blob_size=1 << 20):
+    """test_blob_e2e's in-process cluster with every codec caller on the
+    device engine (JaxEngine, on the CPU backend here) through the
+    process-wide batcher — the topology of the benchmark's deployments."""
+    c = Cluster(tmp_path)
+    c.access = AccessHandler(c.cm_client, c.pool,
+                             AccessConfig(blob_size=blob_size, engine="tpu"))
+    c.worker = RepairWorker(rpc.Client(c.sched), c.cm_client, c.pool,
+                            engine="tpu")
+    return c
+
+
+def _stage_sums(path):
+    return {k[1]: s["sum"]
+            for k, s in metrics.request_stage_seconds.samples()
+            if k[0] == path}
+
+
+def _delta(before, after):
+    return {k: v - before.get(k, 0.0) for k, v in after.items()
+            if v != before.get(k, 0.0)}
+
+
+def test_put_is_covered_by_six_disjoint_stages(tmp_path, rng):
+    """Every part of `_put` that takes time is inside a stage: the six
+    run in sequence, so their sum is at most `total`, and at 4 MiB (the
+    copies and the CRC are real work there) at least 80% of it."""
+    c = _tpu_cluster(tmp_path)
+    data = rng.integers(0, 256, 4 << 20, dtype=np.uint8).tobytes()
+    c.access.put(data, codemode=cmode.CodeMode.EC6P3)  # compiles
+    before = _stage_sums("blob.put")
+    tracelib.reset_collector()
+    loc = c.access.put(data, codemode=cmode.CodeMode.EC6P3)
+    d = _delta(before, _stage_sums("blob.put"))
+    assert set(PUT_STAGES) <= set(d), sorted(d)
+    staged = sum(d[s] for s in PUT_STAGES)
+    assert 0.8 * d["total"] <= staged <= d["total"], d
+    # in sequence: each stage span starts after the one before ended
+    root = next(s for s in tracelib.finished_spans()
+                if s["op"] == "access.put")
+    by_stage = {s["tags"].get("stage"): s
+                for s in tracelib.finished_spans(root["trace_id"])
+                if s["parent_id"] == root["span_id"]}
+    order = [by_stage[s] for s in PUT_STAGES]
+    for a, b in zip(order, order[1:]):
+        assert a["start"] + a["duration"] <= b["start"]
+    assert "encode_total_ms" in by_stage["encode_admission"]["tags"]
+    assert c.access.get(loc) == data
+
+
+def test_blobnode_times_one_put_and_one_get(tmp_path):
+    """The blobnode's own view of a shard call (store + CRC verify), for
+    the calls of a traced request: the door is the request's, read from
+    the context, not from the environment once a shard."""
+    def counts():
+        return {k[0]: s["count"]
+                for k, s in metrics.blobnode_shard_io.samples()}
+
+    node = BlobNode(node_id=0, disk_paths=[])
+    node.attach_local(3, str(tmp_path / "d0"))
+    before = counts()
+    with tracelib.path_span("blob.put", "test.put"):
+        node.put_shard(3, 7, 1, b"x" * 4096)
+        assert node.get_shard(3, 7, 1)[0] == b"x" * 4096
+    assert _delta(before, counts()) == {"put": 1, "get": 1}
+    node.put_shard(3, 7, 2, b"y" * 4096)  # no request span: not timed
+    assert _delta(before, counts()) == {"put": 1, "get": 1}
+
+
+def test_repair_counts_the_bytes_it_wrote_back(tmp_path, rng):
+    """`cubefs_repair_bytes_rebuilt_total` rises by exactly the bytes
+    the destination acknowledged, and `decode` is split into
+    `decode_stack` + the engine step + `decode_verify`."""
+    c = _tpu_cluster(tmp_path, blob_size=64 << 10)
+    data = rng.integers(0, 256, 150_000, dtype=np.uint8).tobytes()
+    loc = c.access.put(data, codemode=cmode.CodeMode.EC6P3)
+    victim = c.cm.get_volume(loc.slices[0].vid).units[1]
+    next(n for n in c.nodes
+         if n.addr == victim.node_addr).break_disk(victim.disk_id)
+    assert c.sched.mark_disk_broken(victim.disk_id) >= 1
+
+    written = []
+    real_call = rpc.Client.call
+
+    def tapped(self, method, args=None, body=b"", *a, **kw):
+        out = real_call(self, method, args, body, *a, **kw)
+        if method == "put_shard":
+            written.append(len(body))
+        return out
+
+    before = _stage_sums("blob.repair")
+    rebuilt0 = metrics.repair_bytes_rebuilt.value()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(rpc.Client, "call", tapped)
+        for _ in range(100):
+            if not c.worker.run_once():
+                break
+    assert written and c.worker.failed == 0
+    assert metrics.repair_bytes_rebuilt.value() - rebuilt0 == sum(written)
+    d = _delta(before, _stage_sums("blob.repair"))
+    assert {"decode", "decode_stack", "decode_verify"} <= set(d), sorted(d)
+    assert d["decode_stack"] + d["decode_verify"] <= d["decode"]
+    assert c.access.get(loc) == data
+
+
+def _host_events(trace_dir, prefix):
+    """{thread line: [(name, start_ns, end_ns)]} of the profile's host
+    plane, for the events whose name starts with `prefix`."""
+    import glob
+
+    import jax.profiler
+
+    path = sorted(glob.glob(
+        f"{trace_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith(prefix)]
+            if evs:
+                out.setdefault(line.name, []).extend(evs)
+    return out
+
+
+def test_a_profile_shows_the_programs_spans_on_its_own_clock(tmp_path, rng):
+    """While a profiler session runs, path spans, stages and the engine
+    phases are in the profile's host plane, each stage inside its path
+    span — one clock with the device trace, no second span store."""
+    import jax.profiler
+
+    from cubefs_tpu.codec import engine
+
+    c = _tpu_cluster(tmp_path)
+    data = rng.integers(0, 256, 300_000, dtype=np.uint8).tobytes()
+    c.access.put(data, codemode=cmode.CodeMode.EC6P3)  # compiles
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        # an engine takes apart one call in PHASE_EVERY_S: this one
+        engine.get_engine("tpu")._phase_due = 0.0
+        c.access.put(data, codemode=cmode.CodeMode.EC6P3)
+    finally:
+        jax.profiler.stop_trace()
+    by_thread = _host_events(str(tmp_path / "prof"),
+                             tracelib.PROFILE_PREFIX)
+    names = {n for evs in by_thread.values() for n, _, _ in evs}
+    assert {"cubefs:access.put", "cubefs:blob.put/quorum_write",
+            "cubefs:blob.put/stripe_fill", "cubefs:blob.put/codec_step",
+            "cubefs:codec.h2d", "cubefs:codec.wait"} <= names, sorted(names)
+    # the PUT ran on this thread: its stages lie inside its path span
+    mine = next(evs for evs in by_thread.values()
+                if any(n == "cubefs:access.put" for n, _, _ in evs))
+    _, lo, hi = next(e for e in mine if e[0] == "cubefs:access.put")
+    stages = [e for e in mine if e[0].startswith("cubefs:blob.put/")]
+    assert len(stages) >= len(PUT_STAGES)
+    assert all(lo <= s and e <= hi for _, s, e in stages)
+    h2d = next(e for e in mine if e[0] == "cubefs:codec.h2d")
+    step = next(e for e in mine if e[0] == "cubefs:blob.put/codec_step")
+    assert step[1] <= h2d[1] and h2d[2] <= step[2]
+
+
+def test_span_layer_never_imports_jax_for_a_process_without_it():
+    """fs-plane tools trace without the codec: the annotation binds only
+    once JAX is already in the process."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from cubefs_tpu.utils import trace\n"
+            "with trace.path_span('meta.write', 'client.mkdir'):\n"
+            "    with trace.stage('raft_propose'):\n"
+            "        pass\n"
+            "with trace.stage('group_fsync', path='meta.write'):\n"
+            "    pass\n"
+            "assert trace.annotation('cubefs:x') is trace._NO_ANNOTATION\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "assert len(trace.finished_spans()) == 2\n")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def test_bound_series_and_one_count_a_bucket_read_back_cumulative():
+    """An observation touches one bucket and a bound series skips the
+    label lookup; what is read back — samples(), the exposition text —
+    is cumulative as before, identical for both ways in."""
+    reg = metrics.Registry()
+    h = reg.histogram("t_seconds", "t", ("op",), buckets=(0.001, 0.01, 0.1))
+    fast = h.bind(op="put")
+    for v in (0.0005, 0.005, 0.005, 0.05, 7.0):  # 7.0: above every bound
+        h.observe(v, op="get")
+        fast.observe(v)
+    got = dict(h.samples())
+    assert got[("put",)] == got[("get",)]
+    assert got[("put",)]["buckets"] == [1, 3, 4]
+    assert got[("put",)]["count"] == 5
+    assert got[("put",)]["sum"] == pytest.approx(7.0605)
+    text = reg.render_text()
+    assert 't_seconds_bucket{op="put",le="0.01"} 3' in text
+    assert 't_seconds_bucket{op="put",le="+Inf"} 5' in text
+    c = reg.counter("t_total", "t", ("kind",))
+    inc = c.bind(kind="x")
+    inc()
+    inc(2)
+    c.inc(kind="x")
+    assert c.value(kind="x") == 4
