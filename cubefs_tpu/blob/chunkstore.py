@@ -63,19 +63,24 @@ class ChunkStore:
             raise ChunkStoreError(self._err())
         return crc.value
 
-    def get_shard(self, chunk_id: int, bid: int, max_size: int = 16 << 20) -> tuple[bytes, int]:
-        buf = ctypes.create_string_buffer(max_size)
+    def get_shard(self, chunk_id: int, bid: int) -> tuple[bytes, int]:
         crc = ctypes.c_uint32()
-        rc = self._lib.cs_get_shard(
-            self._h, chunk_id, bid, buf, max_size, ctypes.byref(crc)
-        )
+        rc = -3
+        # -3: overwritten with a longer shard since the size was read;
+        # read that one, never a truncated payload
+        while rc == -3:
+            size = self._lib.cs_shard_size(self._h, chunk_id, bid)
+            if size < 0:
+                raise ShardNotFoundError(self._err())
+            buf = ctypes.create_string_buffer(size)
+            rc = self._lib.cs_get_shard(
+                self._h, chunk_id, bid, buf, size, ctypes.byref(crc)
+            )
         if rc == -2:
             raise CrcMismatchError(self._err())
-        if rc == -3:
-            raise ChunkStoreError(self._err())
         if rc < 0:
             raise ShardNotFoundError(self._err())
-        return buf.raw[: rc], crc.value
+        return ctypes.string_at(buf, rc), crc.value
 
     def delete_shard(self, chunk_id: int, bid: int) -> None:
         if self._lib.cs_delete_shard(self._h, chunk_id, bid) != 0:
@@ -113,8 +118,7 @@ class ChunkStore:
             raise ChunkStoreError(self._err())
 
 
-def verified_get_shard(store: ChunkStore, chunk_id: int, bid: int,
-                       max_size: int = 16 << 20, *,
+def verified_get_shard(store: ChunkStore, chunk_id: int, bid: int, *,
                        node_addr: str | None = None, disk_id: int = 0,
                        source: str = "read") -> tuple[bytes, int]:
     """The ONE sanctioned at-rest shard read outside this module (lint
@@ -136,7 +140,7 @@ def verified_get_shard(store: ChunkStore, chunk_id: int, bid: int,
                 raise CrcMismatchError(
                     f"shard {unit}: at-rest {kind}")
     try:
-        return store.get_shard(chunk_id, bid, max_size)
+        return store.get_shard(chunk_id, bid)
     except CrcMismatchError:
         metrics.integrity_corruptions_detected.inc(
             plane="blob", source=source)

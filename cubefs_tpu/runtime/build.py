@@ -73,6 +73,8 @@ def load() -> ctypes.CDLL:
                 c.c_void_p, c.c_uint64, c.c_uint64,
                 c.c_char_p, c.c_uint32, c.POINTER(c.c_uint32),
             ]
+            lib.cs_shard_size.restype = c.c_int64
+            lib.cs_shard_size.argtypes = [c.c_void_p, c.c_uint64, c.c_uint64]
             lib.cs_get_shard.restype = c.c_int64
             lib.cs_get_shard.argtypes = [
                 c.c_void_p, c.c_uint64, c.c_uint64,

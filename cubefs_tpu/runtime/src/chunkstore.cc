@@ -268,6 +268,22 @@ int cs_put_shard(void* h, uint64_t chunk_id, uint64_t bid, const uint8_t* buf,
   return 0;
 }
 
+// Returns the shard's size from the index, or -1 (missing): what a reader
+// sizes its buffer from. A put between this and cs_get_shard can change
+// it; a longer shard then answers -3 there and the reader asks again.
+int64_t cs_shard_size(void* h, uint64_t chunk_id, uint64_t bid) {
+  Store* s = (Store*)h;
+  Chunk* c = get_chunk(s, chunk_id, false);
+  if (!c) return -1;
+  std::lock_guard<std::mutex> g(c->mu);
+  auto it = c->shards.find(bid);
+  if (it == c->shards.end()) {
+    set_err(s, "shard not found");
+    return -1;
+  }
+  return (int64_t)it->second.size;
+}
+
 // Returns shard size, or -1 (missing) / -2 (crc mismatch) / -3 (short buf).
 int64_t cs_get_shard(void* h, uint64_t chunk_id, uint64_t bid, uint8_t* buf,
                      uint32_t buf_len, uint32_t* out_crc) {

@@ -17,6 +17,11 @@ def store(tmp_path):
         yield cs
 
 
+def _data_file(directory: str) -> str:
+    return next(os.path.join(directory, f) for f in os.listdir(directory)
+                if f.endswith(".data"))
+
+
 def test_put_get_roundtrip(store, rng):
     store.create_chunk(1)
     data = rng.integers(0, 256, 100_000).astype(np.uint8).tobytes()
@@ -86,15 +91,98 @@ def test_bitrot_detected(tmp_path):
     with chunkstore.ChunkStore(d) as cs:
         cs.create_chunk(1)
         cs.put_shard(1, 1, b"A" * 1024)
-    data_file = next(
-        os.path.join(d, f) for f in os.listdir(d) if f.endswith(".data")
-    )
+    data_file = _data_file(d)
     with open(data_file, "r+b") as f:
         f.seek(100)
         f.write(b"\x00")
     with chunkstore.ChunkStore(d) as cs:
         with pytest.raises(chunkstore.CrcMismatchError):
             cs.get_shard(1, 1)
+
+
+@pytest.mark.parametrize("size", [1, 2048, 699_051, 17 << 20])
+def test_roundtrip_any_shard_size(store, rng, size):
+    """A read is sized from the index, so no shard is too long to come
+    back: 17 MiB is over the 16 MiB buffer every read once allocated."""
+    store.create_chunk(1)
+    data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+    assert store.put_shard(1, 5, data) == zlib.crc32(data)
+    assert store.get_shard(1, 5) == (data, zlib.crc32(data))
+
+
+@pytest.fixture
+def native_gets(store, monkeypatch):
+    """Records the buffer length of every native cs_get_shard call."""
+    native = store._lib.cs_get_shard
+    lens: list[int] = []
+
+    def cs_get_shard(h, chunk_id, bid, buf, buf_len, out_crc):
+        lens.append(buf_len)
+        return native(h, chunk_id, bid, buf, buf_len, out_crc)
+
+    monkeypatch.setattr(store._lib, "cs_get_shard", cs_get_shard)
+    return lens
+
+
+def test_read_buffer_is_the_shards_size(store, native_gets):
+    store.create_chunk(1)
+    store.put_shard(1, 1, b"s" * 2048)
+    store.put_shard(1, 2, b"")
+    assert store.get_shard(1, 1)[0] == b"s" * 2048
+    assert store.get_shard(1, 2) == (b"", 0)
+    assert native_gets == [2048, 0]
+
+
+@pytest.mark.parametrize("raced", [False, True],
+                         ids=["before-the-read", "between-size-and-read"])
+def test_overwrite_with_longer_shard_reads_the_longer(
+        store, native_gets, monkeypatch, raced):
+    store.create_chunk(1)
+    store.put_shard(1, 7, b"short")
+    longer = b"a-longer-shard" * 100
+    if raced:  # the put lands after the reader has learnt the old size
+        native_size = store._lib.cs_shard_size
+        stale = [native_size(store._h, 1, 7)]
+        monkeypatch.setattr(
+            store._lib, "cs_shard_size",
+            lambda h, c, b: stale.pop() if stale else native_size(h, c, b))
+    store.put_shard(1, 7, longer)
+    assert store.get_shard(1, 7) == (longer, zlib.crc32(longer))
+    # the short buffer was refused by the native call (-3) and retried
+    assert native_gets == ([5, len(longer)] if raced else [len(longer)])
+
+
+def test_missing_bid_and_missing_chunk(store):
+    store.create_chunk(1)
+    store.put_shard(1, 1, b"x")
+    for chunk_id, bid in ((1, 2), (404, 1)):
+        with pytest.raises(chunkstore.ShardNotFoundError):
+            store.get_shard(chunk_id, bid)
+        with pytest.raises(chunkstore.ShardNotFoundError):
+            chunkstore.verified_get_shard(store, chunk_id, bid)
+
+
+def test_flipped_byte_counts_through_verified_get_shard(tmp_path, rng):
+    """The CRC comparison runs inside the native call on every read: a
+    byte flipped in the data file is a CrcMismatchError and one count
+    in cubefs_integrity_corruptions_detected_total."""
+    from cubefs_tpu.utils import metrics
+
+    d = str(tmp_path / "disk4")
+    data = rng.integers(0, 256, 699_051, dtype=np.uint8).tobytes()
+    with chunkstore.ChunkStore(d) as cs:
+        cs.create_chunk(1)
+        cs.put_shard(1, 1, data)
+        assert chunkstore.verified_get_shard(cs, 1, 1)[0] == data
+        with open(_data_file(d), "r+b") as f:
+            f.seek(345_678)
+            f.write(bytes([data[345_678] ^ 0x10]))
+        seen = metrics.integrity_corruptions_detected.value(
+            plane="blob", source="scrub")
+        with pytest.raises(chunkstore.CrcMismatchError):
+            chunkstore.verified_get_shard(cs, 1, 1, source="scrub")
+        assert metrics.integrity_corruptions_detected.value(
+            plane="blob", source="scrub") - seen == 1
 
 
 def test_native_crc_matches_zlib(rng):
