@@ -97,10 +97,10 @@ def main() -> None:
     reps = -(-n // len(rows))  # tile recovered rows back up to n inputs
     # forced-jnp baseline (bypasses the dispatch, so this leg stays an
     # independent A/B even though gf_matrix_apply routes to Pallas now)
-    jnp_apply = rs_kernel._matrix_apply_fn(
-        rows.tobytes(), rows.shape[0], rows.shape[1])
+    jnp_apply = rs_kernel._bits_fn(*rows.shape, (Br, n, S))
+    w_bits = rs_kernel.device_bits(rows, False)
     chain3 = jax.jit(
-        lambda a: jnp.tile(jnp_apply(a), (1, reps, 1))[:, :n, :]
+        lambda a: jnp.tile(jnp_apply(w_bits, a), (1, reps, 1))[:, :n, :]
     )
     dt = timed_slope(chain3, surv, k1=2, k2=34)
     repair_jnp_gibs = Br * n * S / dt / (1 << 30)

@@ -74,6 +74,7 @@ class Sizes:
     crc_blocks: int = 1024
     crc_block_len: int = 128 << 10
     crc_tiles: tuple[int, ...] = (128, 256, 512)
+    two_loss: tuple[int, int] = (8, 699051)  # (stripes, shard bytes) a step
 
 
 FULL = Sizes()
@@ -81,7 +82,7 @@ TINY = Sizes(blob_size=1 << 20, put_threads=2,
              large=(2, (4 << 20) + 4099), mid=(2, (256 << 10) + 1001),
              small=(3, 10_007), special_bytes=300_007, ref_stripes=1,
              sidecar_shard=8192, crc_blocks=8, crc_block_len=8192,
-             crc_tiles=(8,))
+             crc_tiles=(8,), two_loss=(2, 300))
 
 # what .gitignore lists: the only paths a run may create or change
 _IGNORED_DIRS = {".git", ".jax_cache", "chiprun_out", "__pycache__",
@@ -518,16 +519,64 @@ def phase_sidecar(dep: Deployment, sizes: Sizes) -> dict:
             "smoke_wall_s": round(time.perf_counter() - t0, 3)}
 
 
-def parity_rows(n: int, m: int) -> np.ndarray:
-    from cubefs_tpu.ops import gf256
+def phase_two_loss(dep: Deployment, sizes: Sizes, clock: CompileClock
+                   ) -> dict:
+    """Every repair matrix of EC12P4 with one or two units lost — the
+    lost unit and, where there is one, the other lost unit: 16 + 240 —
+    built by the worker's rule (``worker.solve_and_wanted``: survivors in
+    index order past both, the first 12 solve, the 13th is rebuilt
+    beside the lost one as the check), applied through ``worker.codec.matrix_apply`` to the
+    survivors of one reference stripe batch (cellbench/reference.py:
+    table GF(2^8), nothing of cubefs_tpu) and compared bit for bit with
+    that stripe's rows. One program serves them all: nothing compiles
+    after the first step."""
+    from cellbench import reference
+    from cubefs_tpu.blob.worker import solve_and_wanted
+    from cubefs_tpu.ops import rs_kernel
+    from cubefs_tpu.utils import metrics
 
-    return np.ascontiguousarray(gf256.parity_matrix(n, m), dtype=np.uint8)
+    n, m = 12, 4
+    b, s = sizes.two_loss
+    t0 = time.perf_counter()
+    rng = np.random.default_rng([SEED, 28])
+    stripes = np.zeros((b, n + m, s), dtype=np.uint8)
+    stripes[:, :n] = rng.integers(0, 256, (b, n, s), dtype=np.uint8)
+    for k in range(b):
+        stripes[k, n:] = reference.matmul(
+            reference.encode_matrix(n, n + m)[n:], stripes[k, :n])
+    misses = metrics.codec_matrix_cache.value(op="apply", result="miss")
+    after_first = None
+    distinct = set()
+    pairs = [(bad, other) for bad in range(n + m)
+             for other in [None] + [i for i in range(n + m) if i != bad]]
+    for bad, other in pairs:
+        solve, wanted = solve_and_wanted(
+            [i for i in range(n + m) if i not in (bad, other)][:n + 1], n, bad)
+        rows = rs_kernel.reconstruct_rows(n, n + m, solve, wanted)
+        distinct.add(rows.tobytes())
+        got = dep.worker.codec.matrix_apply(rows, stripes[:, solve])
+        if not np.array_equal(got, stripes[:, wanted]):
+            raise RuntimeError(
+                f"EC12P4 repair matrix (lost {bad}, also lost {other}) at "
+                f"({b}, {n}, {s}) differs from the reference stripe")
+        if after_first is None:
+            after_first = clock.compiles
+    if clock.compiles != after_first:
+        raise RuntimeError(
+            f"{clock.compiles - after_first} programs compiled after the "
+            f"first of {len(pairs)} repair matrices of one shape")
+    return {"ok": True, "matrices": len(pairs),
+            "distinct_matrices": len(distinct), "shape": [b, n, s],
+            "compiles_after_first_step": 0,
+            "matrix_cache_misses": int(metrics.codec_matrix_cache.value(
+                op="apply", result="miss") - misses),
+            "smoke_wall_s": round(time.perf_counter() - t0, 3)}
 
 
 def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
     """Right answers are not enough: show where they were computed."""
-    from cubefs_tpu.codec import batcher, engine
-    from cubefs_tpu.ops import rs_kernel
+    from cubefs_tpu.codec import engine
+    from cubefs_tpu.ops import progcache, rs_kernel
     from cubefs_tpu.utils import metrics
 
     if engine._dead_engines:
@@ -535,7 +584,7 @@ def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
                            f"{sorted(engine._dead_engines)} (cause logged "
                            f"by cubefs.codec)")
     if rs_kernel.pallas_refusals:
-        raise RuntimeError(f"Pallas gate refused matrices: "
+        raise RuntimeError(f"Pallas gate refused programs: "
                            f"{rs_kernel.pallas_refusals}")
     steps = {f"{k[0]}/{k[1]}": v
              for k, v in metrics.codec_batch_steps.samples()}
@@ -547,26 +596,21 @@ def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
     rec = {"ok": True, "steps_by_op_engine": steps,
            "pallas_gate_refusals": 0, "engines_quarantined": 0}
 
-    # every geometry the batcher ever drained for the device engine
-    large, fused = 0, 0
-    for key, q in batcher.DEFAULT._queues.items():
-        if key[1] != "tpu":
-            continue
-        s = int(key[4])
-        coeff = q.coeff if key[0] == "apply" else parity_rows(
-            int(key[2]), int(key[3]))
-        if not rs_kernel._pallas_profitable(s):
-            continue
-        large += 1
-        if device_checks and n_devices == 1:
-            if not rs_kernel.serves_fused(coeff, s):
-                raise RuntimeError(
-                    f"large-class geometry {key[0]} {coeff.shape} S={s} "
-                    f"is served by the jnp path, not the fused kernel")
-            fused += 1
-    rec["large_class_geometries"] = large
+    # every program the device engines built for a served step, (B, C, S)
+    # in: a large-class shape has to be a fused program's (the gate's
+    # own programs are (C, tile) and a refusal has raised above)
+    fused = sum(1 for _, _, _, shape, _, _ in
+                progcache.SHARED.keys("pallas_gf") if len(shape) == 3)
+    by_jnp = [(rows, cols, shape) for _, rows, cols, shape in
+              progcache.SHARED.keys("rs_jit")
+              if len(shape) == 3 and rs_kernel._pallas_profitable(shape[-1])]
+    if device_checks and n_devices == 1 and by_jnp:
+        raise RuntimeError(
+            f"large-class shapes (rows, cols, input) {by_jnp} are served "
+            f"by the jnp path, not the fused kernel")
+    rec["large_class_geometries"] = fused + len(by_jnp)
     rec["served_by_fused_kernel"] = fused
-    if device_checks and n_devices == 1 and not large:
+    if device_checks and n_devices == 1 and not fused:
         raise RuntimeError("no large-class geometry reached the batcher")
 
     dp = {k[0]: v for k, v in metrics.codec_batch_dp_steps.samples()}
@@ -601,6 +645,7 @@ def run(sizes: Sizes, workdir: str, device_checks: bool) -> dict:
         phases["reference"] = phase_reference(dep, sizes, objects)
         phases["break_repair"] = phase_break_repair(dep, sizes, objects)
         phases["sidecar"] = phase_sidecar(dep, sizes)
+        phases["two_loss"] = phase_two_loss(dep, sizes, clock)
         phases["device_proof"] = phase_device_proof(
             device["count"], device_checks)
     finally:

@@ -47,6 +47,18 @@ class MsrFallback(Exception):
         super().__init__(detail or reason)
 
 
+def solve_and_wanted(subs: list[int], n_solve: int, bad_sub: int
+                     ) -> tuple[list[int], list[int]]:
+    """(solving survivors, rows to rebuild) for the survivors actually
+    read, ascending, lost units already skipped: the first `n_solve`
+    solve; where one more was read it is rebuilt beside the lost unit
+    and compared with what was read — the pre-writeback check."""
+    wanted = [bad_sub]
+    if len(subs) > n_solve:
+        wanted = sorted({bad_sub, subs[n_solve]})
+    return list(subs[:n_solve]), wanted
+
+
 class RepairWorker:
     def __init__(self, scheduler_client: rpc.Client, cm_client: rpc.Client,
                  node_pool, engine: str | None = "auto",
@@ -188,11 +200,17 @@ class RepairWorker:
                 # shard read failures mid-task are fine.
                 want = min(n_solve + 1, len(read_set))
                 by_key: dict[tuple, list] = defaultdict(list)
+                # units found on a disk that does not serve (a second
+                # lost disk: a two-loss stripe) are skipped for the rest
+                # of the task — survivors in index order past both lost
+                # units, the first n solve, the next one checks
+                lost: set[int] = set()
                 try:
                     for bid in bids:
                         subs, shards = self._read_survivors(
                             vol, read_set, code_pos, bid, need=n_solve,
-                            want=want, failed_az=vol.units[bad].az)
+                            want=want, failed_az=vol.units[bad].az,
+                            lost=lost)
                         by_key[(len(shards[0]), tuple(subs))].append(
                             (bid, shards))
                 except RuntimeError:
@@ -228,10 +246,8 @@ class RepairWorker:
     def _decode_groups(self, t, by_key, n_solve, total_code, bad_sub,
                        writes) -> None:
         for (size, subs), group in by_key.items():
-            solve_subs = list(subs[:n_solve])
-            wanted_out = [bad_sub]
-            if len(subs) > n_solve:  # reconstruct bad + the extra survivor
-                wanted_out = sorted({bad_sub, subs[n_solve]})
+            solve_subs, wanted_out = solve_and_wanted(subs, n_solve, bad_sub)
+            if len(subs) > n_solve:  # reconstructed bad + the extra survivor
                 verify_pos = wanted_out.index(subs[n_solve])
             if bad_sub >= total_code:
                 # global fallback for a LOCAL PARITY unit: its row lives
@@ -431,23 +447,31 @@ class RepairWorker:
     def _read_survivors(
         self, vol: VolumeInfo, read_set: list[int], code_pos: dict[int, int],
         bid: int, need: int, want: int | None = None, failed_az: str = "",
+        *, lost: set[int],
     ) -> tuple[list[int], list[bytes]]:
         """Read up to `want` survivors for bid (at least `need`, which is
         fatal to miss; the extras enable pre-writeback verification).
-        Returns (code-space indices actually read, payloads), ascending."""
+        Returns (code-space indices actually read, payloads), ascending.
+        A unit whose disk answers 503 (broken, not serving) is added to
+        `lost` (the caller's set, one a task), and the units in `lost`
+        are not asked."""
         want = want or need
         subs: list[int] = []
         shards: list[bytes] = []
         for idx in read_set:
             if len(shards) == want:
                 break
+            if idx in lost:
+                continue
             u = vol.units[idx]
             try:
                 _, payload = self.nodes.get(u.node_addr).call(
                     "get_shard",
                     {"disk_id": u.disk_id, "chunk_id": u.chunk_id, "bid": bid},
                 )
-            except rpc.RpcError:
+            except rpc.RpcError as e:
+                if e.code == 503:
+                    lost.add(idx)
                 continue
             metrics.repair_bytes_pulled.inc(
                 len(payload),

@@ -51,6 +51,7 @@ import time
 
 import numpy as np
 
+from ..ops import progcache
 from ..utils import metrics
 from ..utils import trace as tracelib
 from .engine import (Engine, _dispatch, engine_for, get_engine,
@@ -133,7 +134,10 @@ class CodecFuture:
 
 
 class _GeometryQueue:
-    """Pending submissions for one (op, engine, geometry) key."""
+    """Pending submissions for one (op, engine, geometry) key. An apply
+    key carries its matrix (a step has one matrix), and a worker meets
+    hundreds of survivor sets: the queue lives in the map only while it
+    holds submissions or a drain is in flight (``_drain`` drops it)."""
 
     __slots__ = ("subs", "busy", "coeff")
 
@@ -188,7 +192,6 @@ class BatchCodec:
         self._queues: dict[tuple, _GeometryQueue] = {}
         self._pending = 0  # stripes parked across all queues
         self._n_busy = 0  # queues with a drain in flight
-        self._dp_fns: dict[tuple, object] = {}  # (digest, n_in, dp) ->
         self._dp_meshes: dict[int, object] = {}
 
     # ---------------- public submit surface ----------------
@@ -319,6 +322,10 @@ class BatchCodec:
                     if not batch:
                         q.busy = False
                         self._n_busy -= 1
+                        # empty and idle: the next submission of this
+                        # key makes a new one (under this same lock)
+                        if self._queues.get(key) is q:
+                            del self._queues[key]
                         self._cond.notify_all()
                         return
                     q.subs = []
@@ -480,8 +487,12 @@ class BatchCodec:
 
                 coeff = gf256.parity_matrix(int(arr.shape[1]),
                                             int(n_parity))
+            from ..ops import rs_kernel
+
+            coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
             dp = min(len(devs), int(arr.shape[0]))
-            fn, sharding = self._dp_fn(coeff, int(arr.shape[1]), dp)
+            fn, sharding = self._dp_fn(
+                coeff.shape[0], int(arr.shape[1]), dp)
             b = int(arr.shape[0])
             pad = (-b) % dp
             if pad:
@@ -489,7 +500,8 @@ class BatchCodec:
                     [arr, np.zeros((pad,) + arr.shape[1:],
                                    dtype=np.uint8)], axis=0)
             x = jax.device_put(arr, sharding)
-            out = np.asarray(fn(x))
+            out = np.asarray(fn(rs_kernel.device_bits(
+                coeff, False, "encode" if n_parity else "apply"), x))
             # dp label = devices that actually hold a slice of the
             # step's input, not the width that was asked for
             metrics.codec_batch_dp_steps.inc(
@@ -505,12 +517,12 @@ class BatchCodec:
                            int(arr.shape[0]), len(devs))
             return None
 
-    def _dp_fn(self, coeff: np.ndarray, n_in: int, dp: int):
-        """(jitted sharded apply, input sharding) for one matrix on a
-        dp-wide mesh; built once per (matrix, dp)."""
-        digest = (coeff.tobytes(), coeff.shape, n_in, dp)
-        hit = self._dp_fns.get(digest)
-        if hit is None:
+    def _dp_fn(self, rows: int, n_in: int, dp: int):
+        """(jitted sharded apply, input sharding) for one matrix shape
+        on a dp-wide mesh: the bit matrix is the program's operand, so
+        it is built once per (rows, n_in, dp) — in the shared capped
+        program cache — and serves every matrix of that shape."""
+        def build():
             import jax
 
             from ..parallel import mesh as meshlib
@@ -522,11 +534,12 @@ class BatchCodec:
                     devices=jax.devices()[:dp],
                     dims={"dp": dp, "tp": 1, "sp": 1})
                 self._dp_meshes[dp] = mesh
-            hit = (jax.jit(sharded_codec.gf_matrix_apply_sharded(
-                       mesh, coeff, n_in)),
-                   meshlib.stripe_sharding(mesh))
-            self._dp_fns[digest] = hit
-        return hit
+            metrics.codec_programs.inc(kernel="bits")
+            return (jax.jit(sharded_codec.gf_apply_sharded(mesh, n_in)),
+                    meshlib.stripe_sharding(mesh))
+
+        return progcache.SHARED.get_or_build(
+            "dp_jit", (rows, n_in, dp), build)
 
 
 class AdmittedEngine:

@@ -181,8 +181,16 @@ class Encoder:
         if len(present) < n:
             raise ECError(f"unrecoverable: only {len(present)} of {n} shards")
         rows = rs_kernel.reconstruct_rows(n, total, present, wanted)
+        if len(wanted) < n:
+            # one decode shape per geometry, (n, n, S), whatever is
+            # missing: the device engines compile that program with the
+            # geometry's encode (engine.ready_decode), so a survivor
+            # set nobody has seen costs a matrix upload, not a compile.
+            # The zero rows' outputs are dropped.
+            rows = np.concatenate(
+                [rows, np.zeros((n - len(wanted), n), dtype=np.uint8)])
         rec = self.engine.matrix_apply(rows, shards[..., present[:n], :])
-        shards[..., wanted, :] = rec
+        shards[..., wanted, :] = rec[..., :len(wanted), :]
         return shards
 
     def split(self, data: bytes | np.ndarray) -> np.ndarray:

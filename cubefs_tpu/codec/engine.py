@@ -31,7 +31,7 @@ from typing import Callable, Protocol
 
 import numpy as np
 
-from ..ops import gf256, rs_kernel, xorprog
+from ..ops import gf256, progcache, rs_kernel, xorprog
 from ..utils import metrics
 from ..utils import trace as tracelib
 
@@ -74,7 +74,7 @@ class NumpyEngine:
 
 
 _PHASE_SPANS = {p: f"{tracelib.PROFILE_PREFIX}codec.{p}"
-                for p in ("h2d", "launch", "wait", "d2h")}
+                for p in ("matrix", "h2d", "launch", "wait", "d2h")}
 # A phased call waits twice where the bare call does not, and every
 # wait hands the GIL to another thread: with every step phased a step of
 # small PUTs took 9.1 ms instead of 6.6, 240 times a second (PERF.md
@@ -84,11 +84,15 @@ _PHASE_SPANS = {p: f"{tracelib.PROFILE_PREFIX}codec.{p}"
 PHASE_EVERY_S = 0.25
 
 
-def device_call(eng, op: str, program, host_in: np.ndarray) -> np.ndarray:
-    """One call of device engine `eng`, host array in, host array out.
-    At most once in PHASE_EVERY_S it is taken as its four steps: `h2d`
-    (device_put until the input is on the device), `launch`
-    (`program(x)`: the Python dispatch, until it returns its
+def device_call(eng, op: str, matrix, program, host_in: np.ndarray
+                ) -> np.ndarray:
+    """One call of device engine `eng`: ``program(matrix(), host_in)``,
+    host array in, host array out. ``matrix()`` hands over the step's
+    bit matrix, device-resident (rs_kernel.device_bits: a cache lookup,
+    or one bit expansion + one upload for a matrix not seen before).
+    At most once in PHASE_EVERY_S the call is taken as its five steps:
+    `matrix`, `h2d` (device_put until the input is on the device),
+    `launch` (`program(w, x)`: the Python dispatch, until it returns its
     not-yet-ready result), `wait` (until the result is ready), `d2h`
     (np.asarray) — each one sample of
     cubefs_codec_engine_phase_seconds{engine,op,phase} and one
@@ -96,7 +100,7 @@ def device_call(eng, op: str, program, host_in: np.ndarray) -> np.ndarray:
     every call with CUBEFS_TRACE=0, is the bare call."""
     now = time.perf_counter()
     if not tracelib.enabled() or now < getattr(eng, "_phase_due", 0.0):
-        return np.asarray(program(host_in))
+        return np.asarray(program(matrix(), host_in))
     eng._phase_due = now + PHASE_EVERY_S
     import jax
 
@@ -110,24 +114,54 @@ def device_call(eng, op: str, program, host_in: np.ndarray) -> np.ndarray:
                     time.perf_counter() - t0, engine=eng.name, op=op,
                     phase=name)
 
+    w = phase("matrix", matrix)
     x = phase("h2d", lambda: jax.block_until_ready(jax.device_put(host_in)))
-    y = phase("launch", program, x)
+    y = phase("launch", program, w, x)
     phase("wait", jax.block_until_ready, y)
     return phase("d2h", np.asarray, y)
+
+
+def ready_decode(eng, n: int, s: int) -> None:
+    """Called by a device engine after an encode of geometry (n data
+    shards of s bytes): the first time, one zero stripe goes through the
+    engine's own decode shape — n rows solved from n survivors,
+    (1, n, s), what codec/encoder.py's reconstruct always asks for — so
+    its program is compiled (and its Pallas gate paid) with the encode's
+    and a hedged or degraded GET never compiles inside a request. Where
+    n == m that is the encode's own program and nothing is built."""
+    def build() -> bool:
+        coeff = np.eye(n, dtype=np.uint8)
+        planes, program = eng._plan(coeff, (1, n, s))
+        np.asarray(program(rs_kernel.device_bits(coeff, planes),
+                           np.zeros((1, n, s), dtype=np.uint8)))
+        return True
+
+    progcache.SHARED.get_or_build("decode_ready", (eng.name, n, s), build)
 
 
 class JaxEngine:
     name = "tpu"
 
     def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray) -> np.ndarray:
-        return device_call(
-            self, "apply",
-            lambda x: rs_kernel.gf_matrix_apply(coeff, x), np.asarray(shards))
+        return self._apply("apply", coeff, shards)
 
     def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
+        data = np.asarray(data)
+        n, s = int(data.shape[-2]), int(data.shape[-1])
+        out = self._apply("encode", gf256.parity_matrix(n, n_parity), data)
+        ready_decode(self, n, s)
+        return out
+
+    _plan = staticmethod(rs_kernel.plan)
+
+    def _apply(self, op: str, coeff: np.ndarray, shards: np.ndarray
+               ) -> np.ndarray:
+        coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
+        shards = np.asarray(shards)
+        planes, program = self._plan(coeff, shards.shape)
         return device_call(
-            self, "encode",
-            lambda x: rs_kernel.encode_parity(x, n_parity), np.asarray(data))
+            self, op, lambda: rs_kernel.device_bits(coeff, planes, op),
+            program, shards)
 
 
 class CppEngine:

@@ -15,6 +15,8 @@ bit k of byte b.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from . import gf256
@@ -27,15 +29,21 @@ def coeff_bitmatrix(c: int) -> np.ndarray:
     return ((cols[None, :] >> np.arange(8)[:, None]) & 1).astype(np.int8)
 
 
+@functools.cache
+def _coeff_bitmatrices() -> np.ndarray:
+    """(256, 8, 8): L_c for every coefficient, built once."""
+    return np.stack([coeff_bitmatrix(c) for c in range(256)])
+
+
 def gf_matrix_to_bits(m: np.ndarray) -> np.ndarray:
-    """Expand an (R, C) GF(2^8) matrix into its (8R, 8C) GF(2) form."""
+    """Expand an (R, C) GF(2^8) matrix into its (8R, 8C) GF(2) form
+    (one table gather: a survivor set seen for the first time pays this
+    inside a request)."""
     m = np.asarray(m, dtype=np.uint8)
     r, c = m.shape
-    out = np.zeros((8 * r, 8 * c), dtype=np.int8)
-    for i in range(r):
-        for j in range(c):
-            out[8 * i : 8 * i + 8, 8 * j : 8 * j + 8] = coeff_bitmatrix(int(m[i, j]))
-    return out
+    blocks = _coeff_bitmatrices()[m]  # (R, C, 8, 8)
+    return np.ascontiguousarray(
+        blocks.transpose(0, 2, 1, 3)).reshape(8 * r, 8 * c)
 
 
 def bitmajor_perm(n_bytes: int) -> np.ndarray:

@@ -9,7 +9,11 @@ per VMEM tile:
         -> (8M, 8N) @ (8N, T) int8 dot (MXU) -> & 1 -> pack -> (M, T)
 
 so HBM sees only payload-in + parity-out. The coefficient bit-matrix is
-tiny (<= 288x288) and stays resident in VMEM across the grid.
+tiny (<= 288x288), goes in as an OPERAND and stays resident in VMEM
+across the grid: one program per shape serves every coefficient matrix
+(encode rows, every survivor set's decode rows, LRC and MSR rows), so a
+survivor set nobody warmed costs a lookup or a 83 KB upload, never a
+compile.
 
 Bit-identical to the jnp path by construction (same exact integer math);
 tests compare both on every codemode (interpret mode off-TPU).
@@ -17,7 +21,6 @@ tests compare both on every codemode (interpret mode off-TPU).
 
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -26,7 +29,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import bitlin
+from ..codec.engine import JaxEngine, register_engine
+from ..utils import metrics
+from . import progcache
 
 # Bytes of shard per grid step. VMEM per step ~ (C + 8C + 4*8R + R) * T
 # for C input shards and R output rows: at T=32KiB and RS(12+4) repair
@@ -47,7 +52,8 @@ def _kernel(w_ref, x_ref, o_ref):
     # of byte-row b. The per-byte interleave (row b*8+k) forces Mosaic
     # into sublane shuffles that dominated the kernel (17 -> 58 GiB/s on
     # the judged shape when switched); the coefficient matrix is
-    # permuted to match at trace time (bitlin.w_to_bitmajor), so the
+    # permuted to match on the host, once per matrix
+    # (rs_kernel.device_bits -> bitlin.w_to_bitmajor), so the
     # math is unchanged.
     x = x_ref[:].astype(jnp.int32)  # (N, T) bytes
     n, t = x.shape
@@ -67,46 +73,69 @@ def _kernel(w_ref, x_ref, o_ref):
     o_ref[:] = acc.astype(jnp.uint8)
 
 
-@functools.lru_cache(maxsize=None)
-def _apply_fn(coeff_bytes: bytes, rows: int, cols: int, tile: int,
+@progcache.cached("pallas_gf")
+def _apply_fn(rows: int, cols: int, shape: tuple, tile: int,
               interpret: bool):
-    coeff = np.frombuffer(coeff_bytes, dtype=np.uint8).reshape(rows, cols)
-    # keep numpy in the closure: converting here would capture a tracer
-    # when the first call happens inside an outer jit trace (the cached
-    # closure would then leak it into later traces)
-    w_np = bitlin.w_to_bitmajor(bitlin.gf_matrix_to_bits(coeff), rows, cols)
+    """The one program for this shape: ``run(w, shards)`` with the
+    (8R, 8C) plane-major bit matrix as an operand, so what Mosaic
+    compiles depends on (rows, cols, shape, tile) and never on the
+    coefficients — all 209 two-loss repair matrices of EC12P4 run the
+    same executables. Pad to the tile, flatten the leading axes into
+    the grid, run the kernel, slice back: the pad and the slice are
+    dispatched on their own, as they were when the matrix was a
+    constant — fused into one jit with the kernel XLA chose a slower
+    pad and slice on the chip (PERF.md section 6, PR 28)."""
+    *lead, c, s = shape
+    pad = (-s) % tile
+    kwargs = {}
+    if not interpret:
+        # every grid step writes a disjoint output tile: let Mosaic
+        # schedule them in any order / overlapping DMA
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        )
+    kernel = pl.pallas_call(
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, s + pad), jnp.uint8),
+        grid=((s + pad) // tile,),
+        in_specs=[
+            pl.BlockSpec((8 * rows, 8 * cols), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((c, tile), lambda i: (0, i),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i),
+                               memory_space=pltpu.VMEM),
+        interpret=interpret,
+        name="gf256_apply",
+        **kwargs,
+    )
 
+    # jitted on its own, then vmapped: vmap of the bare pallas_call
+    # would rename the custom call (`vmap_gf256_apply_`), and the
+    # device trace knows the kernel by `gf256_apply`
     @jax.jit
-    def apply(shards: jax.Array) -> jax.Array:
-        """(N, S) uint8 -> (R, S) uint8; S must be a tile multiple."""
-        w = jnp.asarray(w_np, dtype=jnp.int8)
-        n, s = shards.shape
-        grid = (s // tile,)
-        kwargs = {}
-        if not interpret:
-            # every grid step writes a disjoint output tile: let Mosaic
-            # schedule them in any order / overlapping DMA
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel",)
-            )
-        return pl.pallas_call(
-            _kernel,
-            out_shape=jax.ShapeDtypeStruct((rows, s), jnp.uint8),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((8 * rows, 8 * cols), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((n, tile), lambda i: (0, i),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-            interpret=interpret,
-            name="gf256_apply",
-            **kwargs,
-        )(w, shards)
+    def apply(w: jax.Array, shards: jax.Array) -> jax.Array:
+        """((8R, 8C) int8, (C, S) uint8) -> (R, S) uint8."""
+        return kernel(w, shards)
 
-    return apply
+    def run(w: jax.Array, shards) -> jax.Array:
+        # a host array goes up as it is: jnp.pad of a numpy array would
+        # compile a convert_element_type of its own on first sight
+        shards = jnp.asarray(shards)
+        if pad:
+            with jax.named_scope("gf256.pad"):
+                shards = jnp.pad(
+                    shards, [*([(0, 0)] * len(lead)), (0, 0), (0, pad)])
+        with jax.named_scope("gf256.relayout"):
+            flat = shards.reshape(-1, c, s + pad)
+        outs = jax.vmap(apply, in_axes=(None, 0))(w, flat)
+        with jax.named_scope("gf256.unpad"):
+            out = outs.reshape(*lead, rows, s + pad)
+            return out[..., :s] if pad else out
+
+    metrics.codec_programs.inc(kernel="gf256_apply")
+    return run
 
 
 def on_tpu() -> bool:
@@ -123,34 +152,28 @@ def gf_matrix_apply_pallas(coeff: np.ndarray, shards, tile: int = DEFAULT_TILE,
     interpret=None compiles for the chip on a TPU backend and uses the
     Pallas interpreter on any other (slow; correctness tests only).
     S is zero-padded to the tile size — exact for GF codes (parity of
-    zero bytes is zero) and sliced back before returning.
+    zero bytes is zero) and sliced back before returning. The matrix
+    comes from rs_kernel's device-resident cache and goes in as an
+    operand.
     """
+    from . import rs_kernel
+
     coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
     if interpret is None:
         interpret = not on_tpu()
     shards = jnp.asarray(shards)
-    *lead, c, s = shards.shape
-    pad = (-s) % tile
-    if pad:
-        with jax.named_scope("gf256.pad"):
-            shards = jnp.pad(
-                shards, [*([(0, 0)] * len(lead)), (0, 0), (0, pad)])
-    with jax.named_scope("gf256.relayout"):
-        flat = shards.reshape(-1, c, s + pad)
-    fn = _apply_fn(coeff.tobytes(), coeff.shape[0], coeff.shape[1], tile,
-                   bool(interpret))
-    outs = jax.vmap(fn)(flat)
-    with jax.named_scope("gf256.unpad"):
-        out = outs.reshape(*lead, coeff.shape[0], s + pad)
-        return out[..., :s] if pad else out
+    fn = _apply_fn(coeff.shape[0], coeff.shape[1], tuple(shards.shape),
+                   tile, bool(interpret))
+    return fn(rs_kernel.device_bits(coeff, True), shards)
 
 
 def verify_tile(coeff: np.ndarray, tile: int, seed: int = 0) -> bool:
-    """On-device bit-identity gate for one tile size: runs the fused
-    kernel on one random tile and compares (on device) against the jnp
-    bit-matmul path. MUST pass before an autotuner (or the production
-    dispatch in rs_kernel) may use this tile — Mosaic has miscompiled
-    large tiles silently.
+    """On-device bit-identity check of one (rows, cols, tile) program on
+    one matrix: runs the fused kernel on one random tile and compares
+    (on device) against the jnp bit-matmul path. MUST pass before an
+    autotuner may use this tile, and — for several matrices, see
+    rs_kernel._pallas_verified — before the production dispatch may use
+    the program: Mosaic has miscompiled large tiles silently.
 
     The golden deliberately bypasses rs_kernel.gf_matrix_apply: that
     entry point dispatches back to THIS kernel on TPU, which would make
@@ -162,55 +185,40 @@ def verify_tile(coeff: np.ndarray, tile: int, seed: int = 0) -> bool:
     coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
     rng = np.random.default_rng(seed)
     # the gate may fire lazily from inside an outer jit trace (first
-    # dispatch for a matrix); ensure_compile_time_eval keeps this
+    # dispatch of a program); ensure_compile_time_eval keeps this
     # concrete computation out of that trace
     with jax.ensure_compile_time_eval():
         x = jnp.asarray(
             rng.integers(0, 256, (coeff.shape[1], tile), dtype=np.uint8))
         got = gf_matrix_apply_pallas(coeff, x, tile=tile)
-        want = rs_kernel._matrix_apply_fn(
-            coeff.tobytes(), coeff.shape[0], coeff.shape[1])(x)
+        want = rs_kernel._bits_fn(*coeff.shape, tuple(x.shape))(
+            rs_kernel.device_bits(coeff, False), x)
         return bool(jax.device_get(_jnp.array_equal(got, want)))
 
 
-class PallasEngine:
-    """codec engine backed by the fused kernel (--ec-engine=tpu-pallas)."""
+class PallasEngine(JaxEngine):
+    """codec engine backed by the fused kernel (--ec-engine=tpu-pallas):
+    JaxEngine with the fused program at every shard size."""
 
     name = "tpu-pallas"
 
-    def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray) -> np.ndarray:
-        return self._apply("apply", coeff, shards)
-
-    def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
-        from . import gf256
-
-        return self._apply(
-            "encode", gf256.parity_matrix(data.shape[-2], n_parity), data)
-
-    def _apply(self, op: str, coeff: np.ndarray, shards: np.ndarray
-               ) -> np.ndarray:
+    @staticmethod
+    def _plan(coeff: np.ndarray, shape: tuple) -> tuple[bool, object]:
         # same miscompile gate as the rs_kernel dispatch: even when the
-        # operator forces this engine, a matrix Mosaic miscompiles must
+        # operator forces this engine, a program Mosaic miscompiles must
         # fall back to the exact jnp path rather than write bad parity
-        from ..codec.engine import device_call
         from . import rs_kernel
 
-        coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
+        shape = tuple(shape)
         if on_tpu() and not rs_kernel._pallas_verified(
-            coeff.tobytes(), coeff.shape[0], coeff.shape[1]
-        ):
-            program = rs_kernel._matrix_apply_fn(
-                coeff.tobytes(), coeff.shape[0], coeff.shape[1])
-        else:
-            def program(x):
-                return gf_matrix_apply_pallas(coeff, x)
-        return device_call(self, op, program, np.asarray(shards))
+                *coeff.shape, DEFAULT_TILE, coeff):
+            return False, rs_kernel._bits_fn(*coeff.shape, shape)
+        return True, _apply_fn(*coeff.shape, shape, DEFAULT_TILE,
+                               not on_tpu())
 
 
 def register() -> None:
-    from ..codec import engine
-
-    engine.register_engine("tpu-pallas", PallasEngine)
+    register_engine("tpu-pallas", PallasEngine)
 
 
 register()

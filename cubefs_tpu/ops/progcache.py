@@ -1,10 +1,12 @@
 """Shared capped LRU for compiled codec kernels and programs.
 
-ops/msr.py, ops/rs_kernel.py and ops/xorprog.py all compile per-matrix
-artifacts — product-matrix rows, jitted bit-matmul closures, scheduled
-XOR programs — that used to live in unbounded functools.lru_cache maps.
-A long-lived repair worker that touches many geometries (every distinct
-survivor set is a distinct decode matrix) grows those maps forever.
+ops/msr.py and ops/xorprog.py compile per-matrix artifacts —
+product-matrix rows, scheduled XOR programs — and ops/rs_kernel.py,
+ops/pallas_gf.py and the batcher's dp path keep one jitted GF apply
+program per shape (the matrix is their operand); all of these used to
+live in unbounded maps. A long-lived repair worker that touches many
+geometries (every distinct survivor set is a distinct decode matrix,
+every object size a distinct shape) grows such maps forever.
 This module is the single bound: one process-wide LRU shared by every
 kernel family, keyed ``(family, key)``, capacity
 ``CUBEFS_CODEC_PROGCACHE_CAP`` entries (default 256), instrumented as
@@ -76,6 +78,12 @@ class ProgramCache:
         value = build()
         self.put(family, key, value)
         return value
+
+    def keys(self, family: str) -> list:
+        """Keys resident for one family (a `cached` function's are its
+        qualified name followed by its arguments)."""
+        with self._lock:
+            return [k for f, k in self._entries if f == family]
 
     def __len__(self) -> int:
         with self._lock:

@@ -8,7 +8,12 @@ for why this is exact and gather-free).
 
 Shapes: shards are (..., B, S) uint8 — leading batch dims (stripes), B
 shards of S bytes. The GF coefficient matrix is tiny ((M, N) with
-M, N <= 36) and is baked into the compiled kernel as a constant.
+M, N <= 36) and goes to the device as an OPERAND: its (8M, 8N) bit form
+is expanded and uploaded once per matrix behind a bounded
+device-resident cache (``device_bits``), and a program is keyed by its
+shapes alone — one executable serves the encode rows and every
+survivor set's decode rows of a geometry, so a matrix nobody warmed
+costs no compile.
 
 Bit-identical guarantee: every step (bit unpack, 0/1 int matmul, mod-2,
 bit pack) is exact integer arithmetic; combined with the same encode
@@ -18,15 +23,17 @@ reference byte-for-byte.
 
 from __future__ import annotations
 
-import functools
-import hashlib
+import collections
 import logging
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import metrics
+from ..utils import trace as tracelib
 from . import bitlin, gf256, msr, progcache
 
 _BITS = (1 << np.arange(8)).astype(np.int32)
@@ -54,54 +61,146 @@ def _pallas_profitable(s: int) -> bool:
     return s % tile == 0 or s >= 4 * tile
 
 
-# Matrices the gate refused this process: (rows, cols, sha256[:12] of
-# the coefficients) -> cause. A refused matrix is served by the exact
-# jnp path for the life of the process; chip_smoke.py fails the run
-# when this is non-empty.
-pallas_refusals: dict[tuple[int, int, str], str] = {}
+# Programs the gate refused this process: (rows, cols, tile) -> cause.
+# A refused program's shapes are served by the exact jnp path for the
+# life of the process; chip_smoke.py fails the run when this is
+# non-empty.
+pallas_refusals: dict[tuple[int, int, int], str] = {}
+
+# (rows, cols, tile) -> blessed? One entry per program the process has
+# asked about: bounded by the shapes there are (rows, cols <= 36), not
+# by the matrices.
+_gate: dict[tuple[int, int, int], bool] = {}
+_gate_lock = threading.Lock()
+GATE_MATRICES = 3  # seeded random coefficient matrices per program
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_verified(coeff_bytes: bytes, rows: int, cols: int) -> bool:
-    """Once-per-process bit-identity gate for the production dispatch:
-    the fused kernel must match the jnp path on-device for this exact
-    coefficient matrix at DEFAULT_TILE before it may serve real data.
-    Mosaic has silently miscompiled this kernel at some tile sizes —
-    unlike repair (whose extras integrity leg fails loudly), encode has
-    no downstream check, so wrong parity would only surface at
-    reconstruct time, after the data shards are gone.
+def _pallas_verified(rows: int, cols: int, tile: int,
+                     coeff: np.ndarray | None = None) -> bool:
+    """Once-per-process bit-identity gate for the production dispatch,
+    per PROGRAM: with the bit matrix an operand, what Mosaic compiles
+    depends on (rows, cols, tile) and not on the coefficients, so the
+    fused kernel must match the jnp path on-device for GATE_MATRICES
+    seeded random coefficient matrices and for the first real one that
+    asks (``coeff``) before the program may serve real data. Mosaic has
+    silently miscompiled this kernel at some tile sizes — unlike repair
+    (whose extras integrity leg fails loudly), encode has no downstream
+    check, so wrong parity would only surface at reconstruct time,
+    after the data shards are gone.
 
     Every refusal — a mismatch, or the gate itself raising (a Mosaic
     compile error lands here) — is logged with its cause and recorded
-    in ``pallas_refusals``; the matrix then rides the jnp path."""
-    from . import pallas_gf
-
-    coeff = np.frombuffer(coeff_bytes, dtype=np.uint8).reshape(rows, cols)
-    key = (rows, cols, hashlib.sha256(coeff_bytes).hexdigest()[:12])
-    try:
-        ok = pallas_gf.verify_tile(coeff, pallas_gf.DEFAULT_TILE)
-    except Exception as e:
-        _log.exception("pallas gate raised for matrix %s at tile=%d; "
-                       "serving it from the jnp path", key,
-                       pallas_gf.DEFAULT_TILE)
-        pallas_refusals[key] = f"gate raised {type(e).__name__}: {e}"
-        return False
-    if not ok:
-        _log.error("pallas kernel MISCOMPILES for matrix %s at tile=%d; "
-                   "serving it from the jnp path", key,
-                   pallas_gf.DEFAULT_TILE)
-        pallas_refusals[key] = (
-            f"mismatch vs jnp path at tile={pallas_gf.DEFAULT_TILE}")
+    in ``pallas_refusals``; the program's shapes then ride the jnp
+    path."""
+    key = (rows, cols, tile)
+    ok = _gate.get(key)
+    if ok is None:
+        with _gate_lock:
+            ok = _gate.get(key)
+            if ok is None:
+                ok = _gate[key] = _run_gate(key, coeff)
     return ok
 
 
+def _run_gate(key: tuple[int, int, int], coeff: np.ndarray | None) -> bool:
+    from . import pallas_gf
+
+    rows, cols, tile = key
+    tries = [np.random.default_rng([rows, cols, tile, i]).integers(
+        0, 256, (rows, cols), dtype=np.uint8) for i in range(GATE_MATRICES)]
+    if coeff is not None:
+        tries.append(coeff)
+    cause = None
+    try:
+        for i, m in enumerate(tries):
+            if not pallas_gf.verify_tile(m, tile, seed=i):
+                cause = f"mismatch vs jnp path at tile={tile}"
+                _log.error("pallas kernel MISCOMPILES for program %s "
+                           "(matrix %d of the gate's %d); serving its "
+                           "shapes from the jnp path", key, i, len(tries))
+                break
+    except Exception as e:
+        _log.exception("pallas gate raised for program %s; serving its "
+                       "shapes from the jnp path", key)
+        cause = f"gate raised {type(e).__name__}: {e}"
+    if cause is not None:
+        pallas_refusals[key] = cause
+    metrics.codec_pallas_gate.inc(
+        result="blessed" if cause is None else "refused")
+    return cause is None
+
+
 def serves_fused(coeff: np.ndarray, s: int) -> bool:
-    """The one dispatch decision: does the fused Pallas kernel serve
-    this coefficient matrix at shard size ``s``? (TPU backend, pad
-    waste bounded, matrix blessed by the gate.)"""
-    return (_use_pallas() and _pallas_profitable(s)
-            and _pallas_verified(coeff.tobytes(), coeff.shape[0],
-                                 coeff.shape[1]))
+    """The one dispatch decision: does the fused Pallas kernel serve a
+    matrix of ``coeff``'s shape at shard size ``s``? (TPU backend, pad
+    waste bounded, program blessed by the gate.)"""
+    if not (_use_pallas() and _pallas_profitable(s)):
+        return False
+    from . import pallas_gf
+
+    return _pallas_verified(coeff.shape[0], coeff.shape[1],
+                            pallas_gf.DEFAULT_TILE, coeff)
+
+
+# ---------------- the matrix as an operand ------------------------------
+MATRIX_CACHE_CAP = 1024  # <= 83 KB each (288 x 288 int8), mostly ~1.5 KB
+
+
+class MatrixCache:
+    """Bounded LRU of device-resident bit matrices, keyed by layout and
+    coefficients. A hit costs a dict lookup and no transfer; a miss is
+    one bit expansion (bitlin) + one upload, counted per ``op`` in
+    cubefs_codec_matrix_cache_total. EC6P6 has 923 survivor sets and a
+    host that lives for weeks meets them all: past ``capacity`` the
+    least recently used matrix is dropped."""
+
+    def __init__(self, capacity: int = MATRIX_CACHE_CAP):
+        self.capacity = capacity
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self._lock = threading.Lock()
+
+    def get(self, coeff: np.ndarray, planes: bool, op: str) -> jax.Array:
+        key = (planes, coeff.shape, coeff.tobytes())
+        with self._lock:
+            w = self._entries.get(key)
+            if w is not None:
+                self._entries.move_to_end(key)
+        hit = w is not None
+        if not hit:
+            bits = bitlin.gf_matrix_to_bits(coeff)
+            if planes:
+                bits = bitlin.w_to_bitmajor(bits, *coeff.shape)
+            # a first lookup may happen inside an outer jit trace: keep
+            # the upload concrete, or the cache would hold a tracer
+            with jax.ensure_compile_time_eval():
+                w = jax.device_put(bits)
+            with self._lock:
+                self._entries[key] = w
+                while len(self._entries) > self.capacity:
+                    self._entries.popitem(last=False)
+        if tracelib.enabled():
+            metrics.codec_matrix_cache.inc(
+                op=op, result="hit" if hit else "miss")
+        return w
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._entries)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+
+matrices = MatrixCache()
+
+
+def device_bits(coeff: np.ndarray, planes: bool, op: str = "apply"
+                ) -> jax.Array:
+    """The (8R, 8C) int8 bit matrix of ``coeff`` on the device:
+    plane-major for the fused kernel (``planes``), byte-major for the
+    jnp bit-matmul."""
+    return matrices.get(coeff, planes, op)
 
 
 def unpack_bits(x: jax.Array) -> jax.Array:
@@ -149,60 +248,51 @@ def gf_apply_bits(
         return pack_bits(y & 1)
 
 
-def _as_const(bits: np.ndarray) -> jax.Array:
-    return jnp.asarray(bits, dtype=jnp.int8)
-
-
 @progcache.cached("rs_jit")
-def _encode_fn(n: int, m: int):
-    w = bitlin.gf_matrix_to_bits(gf256.parity_matrix(n, m))
+def _bits_fn(rows: int, cols: int, shape: tuple):
+    """The jnp bit-matmul program for this shape: ``apply(w, shards)``,
+    the byte-major (8R, 8C) bit matrix an operand."""
+    del rows, cols, shape  # the key: one cached program per shape
 
     @jax.jit
-    def encode(data: jax.Array) -> jax.Array:
-        return gf_apply_bits(_as_const(w), data)
+    def apply(w: jax.Array, shards: jax.Array) -> jax.Array:
+        return gf_apply_bits(w, shards)
 
-    return encode
+    metrics.codec_programs.inc(kernel="bits")
+    return apply
+
+
+def plan(coeff: np.ndarray, shape: tuple) -> tuple[bool, object]:
+    """(plane-major matrix?, program) that serve a matrix of ``coeff``'s
+    shape on shards of ``shape``: the fused kernel where ``serves_fused``
+    says so, else the jnp bit-matmul. ``program(w, shards)`` takes the
+    matrix from ``device_bits(coeff, planes)``."""
+    rows, cols = coeff.shape
+    shape = tuple(shape)
+    if serves_fused(coeff, shape[-1]):
+        from . import pallas_gf
+
+        return True, pallas_gf._apply_fn(rows, cols, shape,
+                                         pallas_gf.DEFAULT_TILE, False)
+    return False, _bits_fn(rows, cols, shape)
+
+
+def gf_matrix_apply(coeff: np.ndarray, shards: jax.Array,
+                    op: str = "apply") -> jax.Array:
+    """shards: (..., C, S) uint8, coeff: (R, C) GF(256) -> (..., R, S).
+
+    The one building block of encode (parity rows), reconstruct
+    (decode-matrix rows), LRC and MSR rows. The program is keyed by the
+    shapes; the matrix is its operand."""
+    coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
+    planes, program = plan(coeff, shards.shape)
+    return program(device_bits(coeff, planes, op), shards)
 
 
 def encode_parity(data: jax.Array, n_parity: int) -> jax.Array:
     """data: (..., N, S) uint8 -> parity (..., M, S) uint8."""
-    n = int(data.shape[-2])
-    coeff = np.ascontiguousarray(
-        gf256.parity_matrix(n, n_parity), dtype=np.uint8)
-    if serves_fused(coeff, int(data.shape[-1])):
-        from . import pallas_gf
-
-        return pallas_gf.gf_matrix_apply_pallas(coeff, data)
-    return _encode_fn(n, n_parity)(data)
-
-
-@progcache.cached("rs_jit")
-def _matrix_apply_fn(coeff_bytes: bytes, rows: int, cols: int):
-    coeff = np.frombuffer(coeff_bytes, dtype=np.uint8).reshape(rows, cols)
-    w = bitlin.gf_matrix_to_bits(coeff)
-
-    @jax.jit
-    def apply(shards: jax.Array) -> jax.Array:
-        return gf_apply_bits(_as_const(w), shards)
-
-    return apply
-
-
-def gf_matrix_apply(coeff: np.ndarray, shards: jax.Array) -> jax.Array:
-    """shards: (..., C, S) uint8, coeff: (R, C) GF(256) -> (..., R, S).
-
-    General building block for reconstruct (decode-matrix rows) and
-    verify (parity rows). The coefficient matrix is static per call site
-    (per codemode / per missing-shard pattern), so each distinct matrix
-    compiles once and is cached.
-    """
-    coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
-    if serves_fused(coeff, int(shards.shape[-1])):
-        from . import pallas_gf
-
-        return pallas_gf.gf_matrix_apply_pallas(coeff, shards)
-    fn = _matrix_apply_fn(coeff.tobytes(), coeff.shape[0], coeff.shape[1])
-    return fn(shards)
+    return gf_matrix_apply(
+        gf256.parity_matrix(int(data.shape[-2]), n_parity), data, "encode")
 
 
 def reconstruct_rows(

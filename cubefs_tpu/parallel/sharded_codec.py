@@ -31,31 +31,39 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..ops import bitlin, crc32_kernel, gf256, rs_kernel
 
 
-def gf_matrix_apply_sharded(
-    mesh: Mesh, coeff: np.ndarray, n_in: int
-) -> callable:
-    """Build a shard_map'd fn: (B, n_in, S) uint8 -> (B, R, S) uint8 with
-    input sharded (dp, tp, sp) and output (dp, None, sp) — every device
-    in a tp group holds the full result rows for its byte slice, like
-    every blobnode holding the full parity it must write."""
-    w = bitlin.gf_matrix_to_bits(np.ascontiguousarray(coeff, dtype=np.uint8))
+def gf_apply_sharded(mesh: Mesh, n_in: int) -> callable:
+    """Build a shard_map'd fn: ((8R, 8*n_in) int8 bit matrix, (B, n_in, S)
+    uint8) -> (B, R, S) uint8 with the shards sharded (dp, tp, sp), the
+    matrix replicated (an operand: one program serves every matrix of
+    its shape) and the output (dp, None, sp) — every device in a tp
+    group holds the full result rows for its byte slice, like every
+    blobnode holding the full parity it must write."""
     tp = mesh.shape["tp"]
     if n_in % tp:
         raise ValueError(f"shard axis {n_in} not divisible by tp={tp}")
     cols_per = 8 * (n_in // tp)
 
-    def body(shards_local: jax.Array) -> jax.Array:
+    def body(w_all: jax.Array, shards_local: jax.Array) -> jax.Array:
         idx = jax.lax.axis_index("tp")
-        w_all = jnp.asarray(w)  # (8R, 8*n_in)
         w_local = jax.lax.dynamic_slice_in_dim(w_all, idx * cols_per, cols_per, 1)
         return rs_kernel.gf_apply_bits(w_local, shards_local, psum_axis="tp")
 
     return jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(P("dp", "tp", "sp"),),
+        in_specs=(P(), P("dp", "tp", "sp")),
         out_specs=P("dp", None, "sp"),
     )
+
+
+def gf_matrix_apply_sharded(
+    mesh: Mesh, coeff: np.ndarray, n_in: int
+) -> callable:
+    """gf_apply_sharded with ``coeff``'s bit matrix bound:
+    (B, n_in, S) uint8 -> (B, R, S) uint8."""
+    w = bitlin.gf_matrix_to_bits(np.ascontiguousarray(coeff, dtype=np.uint8))
+    fn = gf_apply_sharded(mesh, n_in)
+    return lambda shards: fn(jnp.asarray(w), shards)
 
 
 def encode_sharded(mesh: Mesh, n_data: int, n_parity: int) -> callable:

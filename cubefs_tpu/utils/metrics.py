@@ -350,9 +350,11 @@ codec_batch_dp_steps = DEFAULT.counter(
     "device steps sharded dp-wise across the mesh", ("dp",))
 # where one drained step's host time goes: `gather` (the batcher's
 # concatenate; 0 for a single-submission step, so the mean is per step),
-# then inside a device engine's call `h2d`, `launch` (Python dispatch
-# until the not-yet-ready result is returned), `wait` (until it is
-# ready), `d2h` — of the calls the engine takes apart, at most one in
+# then inside a device engine's call `matrix` (the step's bit matrix
+# from the device-resident cache: a lookup, or on a miss one bit
+# expansion and one upload), `h2d`, `launch` (Python dispatch until the
+# not-yet-ready result is returned), `wait` (until it is ready), `d2h`
+# — of the calls the engine takes apart, at most one in
 # engine.PHASE_EVERY_S seconds
 codec_engine_phase = DEFAULT.histogram(
     "cubefs_codec_engine_phase_seconds",
@@ -373,6 +375,23 @@ codec_program_cache = DEFAULT.counter(
 codec_program_cache_entries = DEFAULT.gauge(
     "cubefs_codec_program_cache_entries",
     "entries resident in the shared compiled-program cache")
+# the GF apply takes its bit matrix as an operand (ops/rs_kernel.py):
+# one program per shape, the matrices behind a bounded device-resident
+# cache. A miss is one bit expansion + one upload = one matrix this
+# process had not seen (or had evicted); programs count what was built,
+# by kernel (`gf256_apply` fused, `bits` jnp); the gate counts the
+# Pallas programs it blessed or refused.
+codec_matrix_cache = DEFAULT.counter(
+    "cubefs_codec_matrix_cache_total",
+    "device-resident bit-matrix cache lookups (hit / miss)",
+    ("op", "result"))
+codec_programs = DEFAULT.counter(
+    "cubefs_codec_programs_total",
+    "GF apply programs built, one per shape", ("kernel",))
+codec_pallas_gate = DEFAULT.counter(
+    "cubefs_codec_pallas_gate_total",
+    "fused-kernel programs through the miscompile gate "
+    "(blessed / refused)", ("result",))
 
 # repair-bandwidth observability (blob/worker.py): what a single-shard
 # repair actually pulls over the network, split by failure-domain scope

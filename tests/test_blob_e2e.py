@@ -121,6 +121,57 @@ def test_disk_repair_end_to_end(cluster, rng):
     assert cluster.access.get(loc) == data
 
 
+@pytest.mark.parametrize("lost", [(0, 3), (2, 7), (6, 8)],
+                         ids=["data+data", "data+parity", "parity+parity"])
+def test_two_disks_lost_both_units_rebuilt_bit_identical(cluster, rng, lost):
+    """A second disk fails before the first is rebuilt: each of the two
+    tasks of the volume rebuilds one unit from survivors in index order
+    past BOTH lost units (the first n solve, the next one is the check
+    before write-back); a disk that does not serve is asked once a task,
+    not once a bid."""
+    data = payload(rng, 250_000)  # 4 blobs of 64 KiB
+    loc = cluster.access.put(data, codemode=cmode.CodeMode.EC6P3)
+    vid = loc.slices[0].vid
+    before = cluster.cm.get_volume(vid)
+    original, asked = {}, []
+    for idx in lost:
+        u = before.units[idx]
+        node = cluster.node_of(u.node_addr)
+        original[idx] = {
+            bid: node.get_shard(u.disk_id, u.chunk_id, bid)[0]
+            for bid, _, _ in node.list_chunk(u.disk_id, u.chunk_id)}
+        assert len(original[idx]) == 4
+        node.break_disk(u.disk_id)
+    broken = {before.units[i].disk_id for i in lost}
+    for node in cluster.nodes:  # count reads sent to the broken disks
+        real = node.get_shard
+
+        def get_shard(disk_id, chunk_id, bid, *a, _real=real, **k):
+            if disk_id in broken:
+                asked.append((disk_id, bid))
+            return _real(disk_id, chunk_id, bid, *a, **k)
+
+        node.get_shard = get_shard
+    queued = sum(cluster.sched.mark_disk_broken(d) for d in sorted(broken))
+    assert queued >= 2
+    cluster.drain_worker()
+    assert cluster.worker.failed == 0
+
+    after = cluster.cm.get_volume(vid)
+    for idx in lost:
+        unit = after.units[idx]
+        assert unit.disk_id not in broken
+        node = cluster.node_of(unit.node_addr)
+        for bid, blob in original[idx].items():
+            assert node.get_shard(unit.disk_id, unit.chunk_id, bid)[0] == blob
+    # the first task met the other lost unit once, not once per bid; the
+    # second found it rebuilt
+    assert 1 <= len(asked) <= 1 + len(lost), asked
+    assert all(cluster.cm.disks[d].status == DiskStatus.REPAIRED
+               for d in broken)
+    assert cluster.access.get(loc) == data
+
+
 def test_msr_disk_repair_pulls_subshards(cluster, rng):
     """EC4P4MSR repair goes down the sub-shard path: helper blobnodes
     serve beta-sized read_subshard combinations instead of full shards,

@@ -183,27 +183,112 @@ def test_step_counter_names_the_engine_that_served(monkeypatch, rng):
 
 @pytest.mark.parametrize("how", ["mismatch", "raise"])
 def test_gate_refusal_is_logged_and_readable(monkeypatch, caplog, how):
+    """The gate is per program (rows, cols, tile): a refusal — a
+    mismatch, or the gate itself raising — is logged with its cause,
+    recorded once in ``pallas_refusals`` under the program's key, and
+    holds for every matrix of that shape without running again."""
     from cubefs_tpu.ops import pallas_gf, rs_kernel
+    from cubefs_tpu.utils import metrics
 
-    def verify_tile(coeff, tile):
+    seen = []
+
+    def verify_tile(coeff, tile, seed=0):
+        seen.append(np.asarray(coeff).copy())
         if how == "raise":
             raise RuntimeError("Mosaic failed to compile")
         return False
 
     monkeypatch.setattr(pallas_gf, "verify_tile", verify_tile)
     monkeypatch.setattr(rs_kernel, "pallas_refusals", {})
-    coeff = np.arange(1, 13, dtype=np.uint8).reshape(2, 6) + (
-        7 if how == "raise" else 0)
+    monkeypatch.setattr(rs_kernel, "_gate", {})
+    coeff = np.arange(1, 13, dtype=np.uint8).reshape(2, 6)
+    refused = metrics.codec_pallas_gate.value(result="refused")
     with caplog.at_level(logging.ERROR, logger="cubefs.codec"):
-        ok = rs_kernel._pallas_verified(coeff.tobytes(), 2, 6)
+        ok = rs_kernel._pallas_verified(2, 6, 512, coeff)
     assert ok is False
     (key, cause), = rs_kernel.pallas_refusals.items()
-    assert key[:2] == (2, 6)
+    assert key == (2, 6, 512)
     assert ("Mosaic failed to compile" in cause) == (how == "raise")
     assert any(str(key) in r.getMessage() for r in caplog.records)
     if how == "raise":
         assert any(r.exc_info for r in caplog.records)
-    rs_kernel._pallas_verified.cache_clear()
+    assert metrics.codec_pallas_gate.value(result="refused") == refused + 1
+    # the first matrix the gate tries is a seeded random one, not the
+    # caller's: what is blessed or refused is the program
+    assert seen[0].shape == (2, 6) and not np.array_equal(seen[0], coeff)
+    n = len(seen)
+    assert rs_kernel._pallas_verified(2, 6, 512, coeff + 7) is False
+    assert len(seen) == n and len(rs_kernel.pallas_refusals) == 1
+
+
+def test_gate_tries_seeded_matrices_and_the_first_real_one(monkeypatch):
+    from cubefs_tpu.ops import pallas_gf, rs_kernel
+
+    seen = []
+
+    def verify_tile(coeff, tile, seed=0):
+        seen.append((np.asarray(coeff).copy(), tile, seed))
+        return True
+
+    monkeypatch.setattr(pallas_gf, "verify_tile", verify_tile)
+    monkeypatch.setattr(rs_kernel, "pallas_refusals", {})
+    monkeypatch.setattr(rs_kernel, "_gate", {})
+    real = np.arange(1, 25, dtype=np.uint8).reshape(2, 12)
+    assert rs_kernel._pallas_verified(2, 12, 256, real) is True
+    assert len(seen) == rs_kernel.GATE_MATRICES + 1
+    assert np.array_equal(seen[-1][0], real)
+    assert all(m.shape == (2, 12) and t == 256 for m, t, _ in seen)
+    assert len({m.tobytes() for m, _, _ in seen}) == len(seen)
+    assert not rs_kernel.pallas_refusals
+    # blessed once per process: no matrix of the shape runs it again,
+    # another tile is another program
+    assert rs_kernel._pallas_verified(2, 12, 256, real + 1) is True
+    assert len(seen) == rs_kernel.GATE_MATRICES + 1
+    assert rs_kernel._pallas_verified(2, 12, 512, real) is True
+    assert len(seen) == 2 * (rs_kernel.GATE_MATRICES + 1)
+
+
+@pytest.mark.parametrize("planted", [False, True])
+def test_a_mismatching_fused_program_is_refused_and_the_jnp_path_serves(
+        monkeypatch, caplog, rng, planted):
+    """The gate on the real kernel (Pallas interpreter here): a fused
+    program whose result differs in one bit from the jnp path's is
+    refused, logged, listed in ``pallas_refusals`` and its shapes are
+    served by the jnp program — bit-identical to the table engine; the
+    honest program is blessed and serves."""
+    from cubefs_tpu.codec.engine import get_engine
+    from cubefs_tpu.ops import gf256, pallas_gf, rs_kernel
+    from cubefs_tpu.utils import metrics
+
+    real_fn = pallas_gf._apply_fn
+
+    def apply_fn(rows, cols, shape, tile, interpret):
+        fn = real_fn(rows, cols, shape, tile, True)  # no Mosaic here
+        if not planted:
+            return fn
+        return lambda w, x: fn(w, x).at[..., 0, 0].add(1)
+
+    monkeypatch.setattr(pallas_gf, "_apply_fn", apply_fn)
+    monkeypatch.setattr(pallas_gf, "DEFAULT_TILE", 256)
+    monkeypatch.setattr(rs_kernel, "_use_pallas", lambda: True)
+    monkeypatch.setattr(rs_kernel, "pallas_refusals", {})
+    monkeypatch.setattr(rs_kernel, "_gate", {})
+    rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:2]
+    data = rng.integers(0, 256, (3, 6, 512), dtype=np.uint8)
+    built = {k: metrics.codec_programs.value(kernel=k)
+             for k in ("bits", "gf256_apply")}
+    with caplog.at_level(logging.ERROR, logger="cubefs.codec"):
+        got = get_engine("tpu").matrix_apply(rows, data)
+    assert np.array_equal(got, get_engine("numpy").matrix_apply(rows, data))
+    assert rs_kernel.serves_fused(rows, 512) is (not planted)
+    if planted:
+        assert list(rs_kernel.pallas_refusals) == [(2, 6, 256)]
+        assert "mismatch" in rs_kernel.pallas_refusals[(2, 6, 256)]
+        assert any("(2, 6, 256)" in r.getMessage() for r in caplog.records)
+        # the step itself ran the jnp program of its shape
+        assert metrics.codec_programs.value(kernel="bits") > built["bits"]
+    else:
+        assert not rs_kernel.pallas_refusals and not caplog.records
 
 
 def test_dp_failure_is_logged_not_silent(monkeypatch, caplog, rng):
@@ -250,8 +335,14 @@ def test_chip_smoke_phases_at_tiny_sizes(tmp_path, capsys):
     assert out["ok"] is True and out["claim"] is None
     assert list(out)[-1] == "claim"
     assert set(out["phases"]) == {"put", "get", "reference", "break_repair",
-                                  "sidecar", "device_proof",
+                                  "sidecar", "two_loss", "device_proof",
                                   "checkout_clean"}
+    assert out["phases"]["two_loss"]["matrices"] == 16 + 240
+    assert out["phases"]["two_loss"]["compiles_after_first_step"] == 0
+    # 256 (lost, also lost) pairs are 209 distinct matrices: a second
+    # loss past the 13th survivor changes nothing
+    assert out["phases"]["two_loss"]["distinct_matrices"] == 209
+    assert out["phases"]["two_loss"]["matrix_cache_misses"] <= 209
     assert all(p["ok"] for p in out["phases"].values())
     assert set(out["phases"]["reference"]["stripes"]) == {
         "EC12P4", "EC6P6", "EC3P3", "EC6P10L2", "EC6P6MSR"}
@@ -279,7 +370,7 @@ def test_chip_smoke_fails_loudly_when_the_device_was_bypassed(monkeypatch):
         chip_smoke.phase_device_proof(1, device_checks=True)
     monkeypatch.setattr(eng, "_dead_engines", set())
     monkeypatch.setattr(rs_kernel, "pallas_refusals",
-                        {(4, 12, "abc"): "mismatch"})
+                        {(4, 12, 32768): "mismatch"})
     with pytest.raises(RuntimeError, match="refused"):
         chip_smoke.phase_device_proof(1, device_checks=True)
 

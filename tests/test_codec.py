@@ -433,7 +433,7 @@ def test_lrc_local_reconstruct_edge_cases(rng):
 
 # ---------------- the engine call from inside (PR 26) ----------------
 
-PHASES = ("h2d", "launch", "wait", "d2h")
+PHASES = ("matrix", "h2d", "launch", "wait", "d2h")
 
 
 def _phase_counts(engine):
@@ -476,7 +476,7 @@ def _device_engine_call(name, op, rng):
 
 @pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
 @pytest.mark.parametrize("op", ["encode", "apply"])
-def test_device_engine_call_observes_its_four_phases_once(
+def test_device_engine_call_observes_its_five_phases_once(
         name, op, rng, monkeypatch, every_call_phased):
     monkeypatch.delenv("CUBEFS_TRACE", raising=False)
     before = _phase_counts(name)
@@ -565,7 +565,9 @@ def test_device_work_carries_the_names_the_program_chose(path, scopes, rng):
         fn = jax.jit(lambda a: pallas_gf.gf_matrix_apply_pallas(
             coeff, a, tile=512, interpret=True))
     else:
-        fn = rs_kernel._matrix_apply_fn(coeff.tobytes(), 3, 6)
+        w = rs_kernel.device_bits(coeff, False)
+        program = rs_kernel._bits_fn(3, 6, x.shape)
+        fn = jax.jit(lambda a: program(w, a))
     text = fn.lower(x).as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, scope

@@ -93,7 +93,12 @@ def test_shard_split_moves_range_and_survives_restart(tmp_path):
                         {"shard_id": 1, "child_id": 2})[0]
         split_key = meta["split_key"]
         assert meta["child_id"] == 2 and split_key == "k10"
-        time.sleep(0.5)  # let followers apply the split
+        deadline = time.time() + 10  # let followers apply the split
+        while time.time() < deadline and not all(
+                2 in sn.shards and sn.shards[1].end == split_key
+                and sn.shards[1].count() == 10 and sn.shards[2].count() == 10
+                for sn in nodes):
+            time.sleep(0.05)
         for sn in nodes:
             assert sn.shards[1].end == split_key
             assert sn.shards[2].start == split_key

@@ -17,7 +17,7 @@ force a device round-trip on every call:
 A coercion is only flagged when its argument expression mentions a
 non-static parameter of the traced function (values derived from
 closure constants or static args are concrete and fine — see
-ops/pallas_gf.py's `w_np` closure idiom).
+ops/pallas_gf.py's `_apply_fn`, whose shapes are closure constants).
 
 The family also covers the *distributed* tracer (`TraceClockChecker`):
 
