@@ -5,7 +5,7 @@ alloc → split → EC encode → quorum write, stream_put.go:44-169; Get:
 n-of-N+M read with degraded-path reconstruction, stream_get.go:115,461).
 
 TPU-first redesign of the hot path: a PUT's blobs are encoded as ONE
-batched stripe stack (B, total, S) on the device — the reference
+batched stack of data rows (B, n, S) on the device — the reference
 pipelines blob-by-blob through an AVX2 encoder (bounded concurrency 4,
 stream_put.go:106); here batching IS the throughput story, and the
 device sees large contiguous arrays.
@@ -45,6 +45,18 @@ DEFAULT_POLICIES = [
 ]
 
 
+# glibc serves an allocation above this size (its
+# DEFAULT_MMAP_THRESHOLD_MAX on 64-bit) from a mapping of its own, every
+# time: each page of such an array is a fault at first touch (~5 us a
+# 4 KiB page where the host has no transparent huge pages, PERF.md §6),
+# whatever the memory bandwidth. Below it free() grows the heap's
+# threshold to the sizes the process frees, and malloc hands them back
+# mapped. So a PUT's data rows above it are kept for the next PUT of the
+# same shape, up to STRIPE_ROWS_KEPT_BYTES (then the oldest is freed).
+MALLOC_MMAP_MAX = 32 << 20
+STRIPE_ROWS_KEPT_BYTES = 512 << 20
+
+
 @dataclass
 class AccessConfig:
     blob_size: int = 8 << 20  # max payload bytes per blob
@@ -77,6 +89,8 @@ class AccessHandler:
         self.delete_queue = delete_queue
         self._pool = ThreadPoolExecutor(max_workers=self.cfg.max_workers)
         self._encoders: dict[int, object] = {}
+        # data-row arrays of ended PUTs, oldest first (_take_stripe_rows)
+        self._free_rows: list[np.ndarray] = []
         self._lock = lockwitness.make_lock("AccessHandler._lock")
 
     def _submit(self, fn, *args):
@@ -119,26 +133,31 @@ class AccessHandler:
         # also coalesces with concurrent PUTs/repairs of the same
         # geometry, codec/batcher.py) runs while this request does its
         # allocation round-trips, instead of starting after them.
+        # The payload is copied once, into the (blobs, n, S) array the
+        # step takes as it is. A reused array holds another PUT's bytes:
+        # every pad byte (a blob's tail, a short last blob's rest) is
+        # zeroed here, so a stored shard never depends on it.
         with tracelib.stage("stripe_fill"):
             blob_size = self.cfg.blob_size
-            blobs = [data[i : i + blob_size]
-                     for i in range(0, len(data), blob_size)]
-            shard_size = enc.shard_size(len(blobs[0]))
-            stripes = np.zeros((len(blobs), t.total, shard_size),
-                               dtype=np.uint8)
-            for i, blob in enumerate(blobs):
-                buf = np.frombuffer(blob, dtype=np.uint8)
-                stripes[i].reshape(-1)[: buf.size] = buf
-        # the contiguous copy of the data rows, then the enqueue (an
-        # engine without an admission surface encodes inline here)
+            n_blobs = -(-len(data) // blob_size)
+            shard_size = enc.shard_size(min(len(data), blob_size))
+            rows = self._take_stripe_rows((n_blobs, t.n, shard_size))
+            src = np.frombuffer(data, dtype=np.uint8)
+            flat = rows.reshape(n_blobs, -1)
+            for i in range(n_blobs):
+                blob = src[i * blob_size : (i + 1) * blob_size]
+                flat[i, : blob.size] = blob
+                flat[i, blob.size :] = 0
+        # the enqueue (an engine without an admission surface encodes
+        # inline here)
         with tracelib.stage("encode_submit"):
             encode_admitted = time.monotonic()
-            pending = enc.encode_async(stripes)
+            pending = enc.encode_rows_async(rows)
 
         with tracelib.stage("bid_alloc"):
             if self.proxy is not None:  # alloc cache: no per-put cm trip
                 meta, _ = self.proxy.call("alloc", {"codemode": mode,
-                                                    "count": len(blobs)})
+                                                    "count": n_blobs})
                 vol = VolumeInfo.from_dict(meta["volume"])
                 min_bid = meta["min_bid"]
             else:
@@ -147,14 +166,14 @@ class AccessHandler:
                                      "op_id": uuid.uuid4().hex})
                 vol = VolumeInfo.from_dict(meta["volume"])
                 meta, _ = self.cm.call(
-                    "alloc_bids", {"count": len(blobs),
+                    "alloc_bids", {"count": n_blobs,
                                    "op_id": uuid.uuid4().hex})
                 min_bid = meta["start"]
         # the stage is the RESIDUAL admission wait left on the critical
         # path after overlapping allocation; admitted->done wall time
         # rides as a tag on the stage span
         with tracelib.stage("encode_admission") as st:
-            pending.wait()
+            parity = pending.wait()
             if getattr(st, "span", None) is not None:
                 st.span.set_tag(
                     "encode_total_ms",
@@ -164,21 +183,26 @@ class AccessHandler:
         quorum = self.cfg.put_quorum_override or t.put_quorum
         with tracelib.stage("quorum_write"):
             futures = []
-            for i in range(len(blobs)):
+            for i in range(n_blobs):
                 bid = min_bid + i
                 for u in vol.units:
+                    shard = (rows[i, u.index] if u.index < t.n
+                             else parity[i, u.index - t.n])
                     futures.append(
-                        self._submit(self._write_shard, vol, u, bid,
-                                     stripes[i, u.index])
+                        self._submit(self._write_shard, vol, u, bid, shard)
                     )
             fails: list[tuple[int, int]] = []  # (bid, unit index)
-            ok_per_bid = {min_bid + i: 0 for i in range(len(blobs))}
-            for f in futures:
+            ok_per_bid = {min_bid + i: 0 for i in range(n_blobs)}
+            for f in futures:  # every one, stragglers past quorum too
                 bid, idx, err = f.result()
                 if err is None:
                     ok_per_bid[bid] += 1
                 else:
                     fails.append((bid, idx))
+        # the step and every shard write of this PUT have ended: nothing
+        # but this thread can read `rows` now. On every way out above,
+        # where one may still run, the array is dropped and not kept.
+        self._return_stripe_rows(rows)
         for bid, n_ok in ok_per_bid.items():
             if n_ok < quorum:
                 if self.proxy is not None:
@@ -202,10 +226,36 @@ class AccessHandler:
             cluster_id=1,
             codemode=mode,
             size=len(data),
-            slices=[Slice(min_bid=min_bid, vid=vol.vid, count=len(blobs),
+            slices=[Slice(min_bid=min_bid, vid=vol.vid, count=n_blobs,
                           blob_size=blob_size)],
             crc=crc,
         )
+
+    def _take_stripe_rows(self, shape: tuple) -> np.ndarray:
+        """An uninitialised uint8 array of `shape`: the newest of that
+        shape on the free list, else a new one. Arrays malloc serves
+        from its own heap are warm already and never enter the list."""
+        rows = None
+        if shape[0] * shape[1] * shape[2] > MALLOC_MMAP_MAX:
+            with self._lock:
+                free = self._free_rows
+                for k in range(len(free) - 1, -1, -1):
+                    if free[k].shape == shape:
+                        rows = free.pop(k)
+                        break
+        if tracelib.current() is not None:
+            metrics.access_stripe_buffers.inc(
+                result="fresh" if rows is None else "reused")
+        return rows if rows is not None else np.empty(shape, dtype=np.uint8)
+
+    def _return_stripe_rows(self, rows: np.ndarray) -> None:
+        if rows.nbytes <= MALLOC_MMAP_MAX:
+            return
+        with self._lock:
+            free = self._free_rows
+            free.append(rows)
+            while sum(r.nbytes for r in free) > STRIPE_ROWS_KEPT_BYTES:
+                del free[0]
 
     def _write_shard(self, vol: VolumeInfo, unit, bid: int, shard: np.ndarray):
         addr = unit.node_addr

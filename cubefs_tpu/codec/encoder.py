@@ -24,28 +24,32 @@ from .engine import Engine
 class PendingEncode:
     """An encode admitted to the codec batcher while its caller still
     has other work in hand (bid allocation, header parsing, streaming
-    the rest of the body). wait() lands the parity rows into the
-    original stripe array — same array encode() would return — raising
-    any per-submission error at the collect point. `resolved` says
-    whether the device step already completed without blocking."""
+    the rest of the body). wait() returns what the entry point that
+    made it promised — encode_rows_async: the parity rows (B, m[+l], S),
+    the engine's own result, copied into nothing; encode_async: the
+    caller's stripe array with the parity rows landed in place, the
+    array encode() would return — raising any per-submission error at
+    the collect point. `resolved` says whether the device step already
+    completed without blocking."""
 
-    __slots__ = ("shards", "_fill", "_fut")
+    __slots__ = ("_value", "_finish", "_fut")
 
-    def __init__(self, shards: np.ndarray, fill=None, fut=None):
-        self.shards = shards
-        self._fill = fill  # runs at most once; None = already complete
+    def __init__(self, value=None, finish=None, fut=None):
+        self._value = value
+        # timeout -> value; runs at most once; None = already complete
+        self._finish = finish
         self._fut = fut
 
     @property
     def resolved(self) -> bool:
-        return self._fill is None or (self._fut is not None
-                                      and self._fut.done)
+        return self._finish is None or (self._fut is not None
+                                        and self._fut.done)
 
     def wait(self, timeout: float = 120.0) -> np.ndarray:
-        if self._fill is not None:
-            fill, self._fill = self._fill, None
-            fill(timeout)
-        return self.shards
+        if self._finish is not None:
+            finish, self._finish = self._finish, None
+            self._value = finish(timeout)
+        return self._value
 
 
 class ECError(Exception):
@@ -121,38 +125,71 @@ class Encoder:
     def encode(self, shards: np.ndarray) -> np.ndarray:
         """Fill parity rows from data rows; returns the same array."""
         shards = self._check(shards)
-        n, m = self.t.n, self.t.m
-        if m:
-            shards[..., n : n + m, :] = self.engine.encode_parity(
-                shards[..., :n, :], m
-            )
-        if self.cfg.enable_verify and not self.verify(shards):
-            raise VerifyError("parity verify failed after encode")
+        n = self.t.n
+        shards[..., n:, :] = self._finish_rows(shards[..., :n, :], None, 0.0)
         return shards
 
+    def encode_rows_async(self, data: np.ndarray) -> PendingEncode:
+        """Admit the encode of C-contiguous data rows (B, n, S) as they
+        are and return immediately; wait() returns the parity rows
+        (B, total - n, S). With a batcher-admitted engine the device
+        step runs (coalesced with concurrent submissions) while the
+        caller overlaps allocation or IO; engines without an admission
+        surface degrade to an inline encode. The caller keeps `data`
+        unchanged until wait() has returned."""
+        data = self._check(data, total=self.t.n)
+        if data.ndim != 3:
+            raise ECError(f"data rows must be (B, n, S), got {data.shape}")
+        fut = self._submit_rows(data)
+        if fut is None:
+            return PendingEncode(self._finish_rows(data, None, 0.0))
+        return PendingEncode(
+            None, lambda timeout: self._finish_rows(data, fut, timeout), fut)
+
     def encode_async(self, shards: np.ndarray) -> PendingEncode:
-        """Admit the parity encode and return immediately; wait() fills
-        the parity rows in place. With a batcher-admitted engine the
-        device step runs (coalesced with concurrent submissions) while
-        the caller overlaps allocation or IO; engines without an
-        admission surface degrade to an inline encode."""
+        """encode_rows_async for a caller that holds whole stripes:
+        wait() fills the parity rows in place and returns `shards`."""
         shards = self._check(shards)
-        n, m = self.t.n, self.t.m
-        if not m:
-            return PendingEncode(shards)
-        batcher = getattr(self.engine, "batcher", None)
-        if batcher is None or not batcher.enabled:
-            return PendingEncode(self.encode(shards))
+        n = self.t.n
         flat = shards.reshape(-1, self.t.total, shards.shape[-1])
-        fut = batcher.submit_encode_async(
-            self.engine.label, np.ascontiguousarray(flat[:, :n, :]), m)
+        pending = self.encode_rows_async(np.ascontiguousarray(flat[:, :n, :]))
 
-        def fill(timeout: float) -> None:
-            flat[:, n:n + m, :] = fut.result(timeout)
-            if self.cfg.enable_verify and not self.verify(shards):
-                raise VerifyError("parity verify failed after encode")
+        def fill(timeout: float) -> np.ndarray:
+            flat[:, n:, :] = pending.wait(timeout)
+            return shards
 
-        return PendingEncode(shards, fill, fut)
+        if pending._fut is None:  # nothing admitted: complete already
+            return PendingEncode(fill(0.0))
+        return PendingEncode(None, fill, pending._fut)
+
+    def _batcher(self):
+        batcher = getattr(self.engine, "batcher", None)
+        return batcher if batcher is not None and batcher.enabled else None
+
+    def _submit_rows(self, data: np.ndarray):
+        """The one way from an encoder to the batcher: the future of
+        the step that needs `data`, or None where there is no step to
+        wait for (no parity, or no admission surface)."""
+        batcher = self._batcher()
+        if batcher is None or not self.t.m:
+            return None
+        return batcher.submit_encode_async(self.engine.label, data, self.t.m)
+
+    def _finish_rows(self, data: np.ndarray, fut, timeout: float
+                     ) -> np.ndarray:
+        """Parity rows of `data` from its step's result (computed
+        inline where nothing was admitted)."""
+        if not self.t.m:
+            return data[..., :0, :]
+        parity = (fut.result(timeout) if fut is not None
+                  else self.engine.encode_parity(data, self.t.m))
+        return self._verified(data, parity)
+
+    def _verified(self, data: np.ndarray, parity: np.ndarray) -> np.ndarray:
+        if self.cfg.enable_verify and not self.verify(
+                np.concatenate([data, parity], axis=-2)):
+            raise VerifyError("parity verify failed after encode")
+        return parity
 
     def verify(self, shards: np.ndarray) -> bool:
         shards = self._check(shards)
@@ -252,35 +289,22 @@ class MsrEncoder(Encoder):
         t = self.t
         return rs_kernel.msr_encode_rows(t.n, t.n + t.m, t.d)
 
-    def encode(self, shards: np.ndarray) -> np.ndarray:
-        shards = self._check(shards)
-        t, alpha = self.t, self.alpha
-        sub = rs_kernel.msr_subshards(shards[..., : t.n, :], alpha)
-        parity = self.engine.matrix_apply(self._parity_rows(), sub)
-        shards[..., t.n:, :] = rs_kernel.msr_join_subshards(parity, alpha)
-        if self.cfg.enable_verify and not self.verify(shards):
-            raise VerifyError("parity verify failed after encode")
-        return shards
+    def _submit_rows(self, data: np.ndarray):
+        batcher = self._batcher()
+        if batcher is None:
+            return None
+        return batcher.submit_apply_async(
+            self.engine.label, self._parity_rows(),
+            rs_kernel.msr_subshards(data, self.alpha))
 
-    def encode_async(self, shards: np.ndarray) -> PendingEncode:
-        shards = self._check(shards)
-        t, alpha = self.t, self.alpha
-        batcher = getattr(self.engine, "batcher", None)
-        if batcher is None or not batcher.enabled:
-            return PendingEncode(self.encode(shards))
-        flat = shards.reshape(-1, t.total, shards.shape[-1])
-        sub = np.ascontiguousarray(
-            rs_kernel.msr_subshards(flat[:, : t.n, :], alpha))
-        fut = batcher.submit_apply_async(
-            self.engine.label, self._parity_rows(), sub)
-
-        def fill(timeout: float) -> None:
-            flat[:, t.n:, :] = rs_kernel.msr_join_subshards(
-                fut.result(timeout), alpha)
-            if self.cfg.enable_verify and not self.verify(shards):
-                raise VerifyError("parity verify failed after encode")
-
-        return PendingEncode(shards, fill, fut)
+    def _finish_rows(self, data: np.ndarray, fut, timeout: float
+                     ) -> np.ndarray:
+        sub = (fut.result(timeout) if fut is not None
+               else self.engine.matrix_apply(
+                   self._parity_rows(),
+                   rs_kernel.msr_subshards(data, self.alpha)))
+        return self._verified(
+            data, rs_kernel.msr_join_subshards(sub, self.alpha))
 
     def verify(self, shards: np.ndarray) -> bool:
         shards = self._check(shards)
@@ -320,46 +344,26 @@ class LrcEncoder(Encoder):
         t = self.t
         return (t.n + t.m) // t.az_count, t.l // t.az_count
 
-    def encode(self, shards: np.ndarray) -> np.ndarray:
-        shards = self._check(shards)
+    def _finish_rows(self, data: np.ndarray, fut, timeout: float
+                     ) -> np.ndarray:
+        """The global parity rides the admitted step; the per-AZ local
+        parity (cheap, depends on the global rows) is computed here,
+        after the step lands."""
         t = self.t
-        shards[..., t.n : t.n + t.m, :] = self.engine.encode_parity(
-            shards[..., : t.n, :], t.m
-        )
+        parity = np.empty(data.shape[:-2] + (t.m + t.l, data.shape[-1]),
+                          dtype=np.uint8)
+        parity[..., : t.m, :] = (
+            fut.result(timeout) if fut is not None
+            else self.engine.encode_parity(data, t.m))
         ln, lm = self._local_nm
         for az in range(t.az_count):
             stripe_idx, _, _ = t.local_stripe_in_az(az)
-            local_data = shards[..., stripe_idx[:ln], :]
-            shards[..., stripe_idx[ln:], :] = self.engine.encode_parity(local_data, lm)
-        if self.cfg.enable_verify and not self.verify(shards):
-            raise VerifyError("parity verify failed after encode")
-        return shards
-
-    def encode_async(self, shards: np.ndarray) -> PendingEncode:
-        """Admit the global parity step; the per-AZ local parity (cheap,
-        depends on the global rows) is computed at wait() time, after
-        the batched device step lands."""
-        shards = self._check(shards)
-        t = self.t
-        batcher = getattr(self.engine, "batcher", None)
-        if batcher is None or not batcher.enabled or not t.m:
-            return PendingEncode(self.encode(shards))
-        flat = shards.reshape(-1, t.total, shards.shape[-1])
-        fut = batcher.submit_encode_async(
-            self.engine.label, np.ascontiguousarray(flat[:, : t.n, :]), t.m)
-
-        def fill(timeout: float) -> None:
-            flat[:, t.n : t.n + t.m, :] = fut.result(timeout)
-            ln, lm = self._local_nm
-            for az in range(t.az_count):
-                stripe_idx, _, _ = t.local_stripe_in_az(az)
-                local_data = shards[..., stripe_idx[:ln], :]
-                shards[..., stripe_idx[ln:], :] = self.engine.encode_parity(
-                    local_data, lm)
-            if self.cfg.enable_verify and not self.verify(shards):
-                raise VerifyError("parity verify failed after encode")
-
-        return PendingEncode(shards, fill, fut)
+            local_data = np.stack(
+                [data[..., i, :] if i < t.n else parity[..., i - t.n, :]
+                 for i in stripe_idx[:ln]], axis=-2)
+            parity[..., [i - t.n for i in stripe_idx[ln:]], :] = \
+                self.engine.encode_parity(local_data, lm)
+        return self._verified(data, parity)
 
     def verify(self, shards: np.ndarray) -> bool:
         shards = np.asarray(shards, dtype=np.uint8)

@@ -323,6 +323,12 @@ reconstruct_reads = DEFAULT.counter(
     "cubefs_reconstruct_total",
     "degraded-read reconstructions by stripe scope (local = intra-AZ "
     "LRC stripe, global = full-width RS)", ("path",))
+# a PUT's data rows (blob/access.py): `reused` came from the handler's
+# free list (mapped pages), `fresh` from the allocator; one a PUT
+access_stripe_buffers = DEFAULT.counter(
+    "cubefs_access_stripe_buffers_total",
+    "data-row arrays PUTs filled, by where the array came from "
+    "(reused / fresh)", ("result",))
 
 # batched codec admission (codec/batcher.py): device-sized steps
 codec_batch_submissions = DEFAULT.counter(

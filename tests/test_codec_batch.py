@@ -413,6 +413,45 @@ def test_lrc_encode_async_matches_sync(rng):
     assert enc.verify(out)
 
 
+@pytest.mark.parametrize("door", [True, False], ids=["admitted", "inline"])
+@pytest.mark.parametrize("mode", ["EC6P3", "EC4P4L2", "EC4P4MSR"])
+def test_encode_rows_async_returns_the_parity_rows(rng, mode, door):
+    """encode_rows_async(data rows).wait() is the parity rows a blocking
+    encode() of the whole stripe lands, RS, LRC and MSR; the step reads
+    the caller's array itself (no copy on the way in) and leaves it as
+    it was."""
+    from cubefs_tpu.codec.codemode import CodeMode
+    from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
+
+    seen = []
+
+    class Seeing(_CountingCodec):
+        def _engine_call(self, key, coeff, arr):
+            seen.append(arr)
+            return super()._engine_call(key, coeff, arr)
+
+    bc = Seeing(enabled=door, max_wait_ms=1.0)
+    enc = new_encoder(CodecConfig(mode=CodeMode[mode], engine="numpy"))
+    enc.engine = AdmittedEngine(bc, "numpy")
+    t = enc.t
+    size = 12 * getattr(enc, "alpha", 1)
+    data = _stripes(rng, 3, t.n, size)
+    stripes = np.zeros((3, t.total, size), dtype=np.uint8)
+    stripes[:, : t.n, :] = data
+    ref = enc.encode(stripes)
+    seen.clear()
+
+    before = data.copy()
+    pending = enc.encode_rows_async(data)
+    assert pending.resolved is (not door)
+    parity = pending.wait()
+    assert parity.shape == (3, t.total - t.n, size)
+    assert np.array_equal(parity, ref[:, t.n:, :])
+    assert np.array_equal(data, before)
+    assert np.shares_memory(seen[0], data)
+    assert pending.wait() is parity  # collected once, kept
+
+
 def test_encode_async_disabled_door_is_inline(rng):
     """With the batcher door closed the handle degrades to an inline
     encode: already resolved before wait()."""
