@@ -1,45 +1,39 @@
 """Batched codec admission: coalesce concurrent submissions into
 device-sized steps.
 
-The encode kernel sustains its headline throughput only at large batch
-dimensions (`encode_1024stripes_gibs` in
-artifacts/BENCH_tpu_r03_early.json), but the blob plane
-batches only *within* one PUT — concurrent PUTs and repair legs each
-dispatch their own tiny device step, feeding the accelerator at request
-granularity. This module is the admission layer in between: every
-`encode_parity` / `matrix_apply` submission with compatible geometry
+An engine call costs milliseconds of host time whatever it carries
+(`engine.call_ms-small` 6.83 for ~18 us of device work; ledger, PR 29,
+and PERF.md section 5), and the blob plane batches only *within* one PUT. This module is the admission
+layer in between, and the only way blob-plane code reaches an engine:
+every `encode_parity` / `matrix_apply` submission with compatible geometry
 ``(op, n, m, shard_size)`` parks in a per-geometry queue, and whichever
-submitter finds the queue idle drains it as ONE device call — the same
+submitter finds the queue idle drains it as ONE engine call — the same
 first-caller-drains pattern the raft proposal batcher uses for group
-commit (parallel/raft.py): the device-step duration itself is the
-batching window, so uncontended callers pay no added idle latency and
-batch width tracks contention.
+commit (parallel/raft.py): the step's duration itself is the batching
+window, so an uncontended caller pays no added wait and batch width
+tracks contention. What the benchmark's cells read
+(`batcher.stripes_per_step`; ledger, PR 29): 8.0 where a 64 MiB PUT is
+its own step (`ingest-large`; 32 B over `max_step_bytes`, so no second
+PUT joins), 64.0 where a repair task is (`disk-repair`,
+`disk-repair-2disk`), ~1.5 where eight clients PUT small objects
+(`put-small`, the one cell that coalesces; PERF.md sections 4-5).
 
 Per-submission results and errors fan back through private events (a
 malformed submission mid-batch is rejected alone; its batch-mates
-proceed). A bounded pending-stripe queue provides backpressure, a
-max-batch / max-wait pair bounds step size and adds an optional linger
-window, and drained batches are split dp-wise across the device mesh
-(parallel/sharded_codec.py) when multiple devices are visible — the
-dp=16/32 dryruns (MULTICHIP_r06.json) prove 1/n per-device splits stay
-bit-identical.
+proceed). A bounded pending-stripe queue provides backpressure,
+`max_batch` and `max_step_bytes` bound a step, `max_wait_ms` adds an
+optional linger, and a drained step of the device engine is split
+dp-wise across the device mesh (parallel/sharded_codec.py) when several
+devices are visible.
 
-Knobs (env, read at construction):
-  CUBEFS_CODEC_BATCH=0           A/B door: submissions call the engine
-                                 directly, no coalescing
-  CUBEFS_CODEC_BATCH_MAX         max stripes per device step (1024)
-  CUBEFS_CODEC_BATCH_WAIT_MS     drainer linger before the first swap
-                                 (0: the device step is the window)
-  CUBEFS_CODEC_BATCH_PENDING     pending-stripe bound before submitters
-                                 block (4096)
-  CUBEFS_CODEC_DP=0              disable dp-wise sharding of drained
-                                 batches
-  CUBEFS_CODEC_DP_MIN_BYTES      smallest step worth sharding (1 MiB)
+Environment, read at construction: CUBEFS_CODEC_STEP_BYTES (the byte
+bound of a step, 64 MiB; blob/scheduler.py sizes repair tasks by the
+same variable) and CUBEFS_CODEC_DP=0 (no dp split).
 
 Bit-identity: GF(2^8) math has no rounding, every engine is
 bit-identical per stripe, and the dp split is along the independent
-batch axis — a batched step's output equals the unbatched path's
-byte for byte (asserted in tests/test_codec_batch.py).
+batch axis — a coalesced step's output equals each submission's own
+call byte for byte (asserted in tests/test_codec_batch.py).
 """
 
 from __future__ import annotations
@@ -54,8 +48,7 @@ import numpy as np
 from ..ops import progcache
 from ..utils import metrics
 from ..utils import trace as tracelib
-from .engine import (Engine, _dispatch, engine_for, get_engine,
-                     resolve_leg)
+from .engine import Engine, _dispatch, engine_for, get_engine
 
 _log = logging.getLogger("cubefs.codec")
 
@@ -154,39 +147,25 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
 class BatchCodec:
     """The submit surface. One instance per process is the norm
     (module-level DEFAULT below); tests construct private ones."""
 
-    def __init__(self, enabled: bool | None = None,
-                 max_batch: int | None = None,
-                 max_wait_ms: float | None = None,
-                 max_pending: int | None = None,
+    def __init__(self, max_batch: int = 1024, max_wait_ms: float = 0.0,
+                 max_pending: int = 4096,
                  max_step_bytes: int | None = None):
-        self.enabled = (os.environ.get("CUBEFS_CODEC_BATCH", "1") != "0"
-                        if enabled is None else enabled)
-        self.max_batch = (max_batch if max_batch is not None
-                          else _env_int("CUBEFS_CODEC_BATCH_MAX", 1024))
-        self.max_wait = (max_wait_ms if max_wait_ms is not None
-                         else _env_float("CUBEFS_CODEC_BATCH_WAIT_MS",
-                                         0.0)) / 1e3
-        self.max_pending = (max_pending if max_pending is not None
-                            else _env_int("CUBEFS_CODEC_BATCH_PENDING",
-                                          4096))
+        self.max_batch = max_batch  # stripes per step
+        # drainer linger before the first swap (0: the step is the window)
+        self.max_wait = max_wait_ms / 1e3
+        # stripes parked across all queues before submitters block
+        self.max_pending = max_pending
         # byte bound per device step: keeps 'auto' inside the measured
         # crossover sizes and bounds step working-set memory
         self.max_step_bytes = (max_step_bytes if max_step_bytes is not None
                                else _env_int("CUBEFS_CODEC_STEP_BYTES",
                                              64 << 20))
         self.dp_enabled = os.environ.get("CUBEFS_CODEC_DP", "1") != "0"
-        self.dp_min_bytes = _env_int("CUBEFS_CODEC_DP_MIN_BYTES", 1 << 20)
+        self.dp_min_bytes = 1 << 20  # smallest step worth sharding
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._queues: dict[tuple, _GeometryQueue] = {}
@@ -200,8 +179,6 @@ class BatchCodec:
         """(B, N, S) data -> (B, M, S) parity, coalesced with every
         concurrent submission of the same (N, M, S, engine)."""
         key, coeff, arr = self._prep_encode(engine, data, n_parity)
-        if not self.enabled:  # A/B door: the unbatched control path
-            return self._engine_call(key, coeff, arr)[0]
         return self._enqueue(key, coeff, arr, timeout).result(timeout)
 
     def submit_apply(self, engine: str | None, coeff: np.ndarray,
@@ -210,8 +187,6 @@ class BatchCodec:
         """(R, C) GF matrix x (B, C, S) shards -> (B, R, S), coalesced
         with concurrent submissions sharing the identical matrix."""
         key, coeff, arr = self._prep_apply(engine, coeff, shards)
-        if not self.enabled:
-            return self._engine_call(key, coeff, arr)[0]
         return self._enqueue(key, coeff, arr, timeout).result(timeout)
 
     def submit_encode_async(self, engine: str | None, data: np.ndarray,
@@ -222,8 +197,6 @@ class BatchCodec:
         first collect keeps K stripes continuously admitted — the
         sleep/wake cycle per stripe disappears and step width rises."""
         key, coeff, arr = self._prep_encode(engine, data, n_parity)
-        if not self.enabled:
-            return self._inline(key, coeff, arr)
         return self._enqueue(key, coeff, arr, timeout)
 
     def submit_apply_async(self, engine: str | None, coeff: np.ndarray,
@@ -231,8 +204,6 @@ class BatchCodec:
                            ) -> CodecFuture:
         """submit_apply that parks and returns immediately."""
         key, coeff, arr = self._prep_apply(engine, coeff, shards)
-        if not self.enabled:
-            return self._inline(key, coeff, arr)
         return self._enqueue(key, coeff, arr, timeout)
 
     # ---------------- admission ----------------
@@ -252,15 +223,6 @@ class BatchCodec:
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         c, s = int(shards.shape[1]), int(shards.shape[2])
         return ("apply", engine or "", coeff.tobytes(), c, s), coeff, shards
-
-    def _inline(self, key: tuple, coeff, arr) -> CodecFuture:
-        """Disabled-door async submit: execute now, return resolved."""
-        fut = CodecFuture(self, key, arr)
-        try:
-            fut.resolve(self._engine_call(key, coeff, arr)[0], None)
-        except BaseException as e:
-            fut.resolve(None, e)
-        return fut
 
     def _enqueue(self, key: tuple, coeff: np.ndarray | None,
                  arr: np.ndarray, timeout: float) -> CodecFuture:
@@ -450,7 +412,6 @@ class BatchCodec:
             # the COALESCED size, so concurrent tiny submissions ride
             # the engine measured best for the batch they became
             name = engine_for(int(arr.nbytes)).name
-        name = resolve_leg(name)
         if op == "encode":
             m = int(key[3])
             out = self._maybe_dp(name, None, arr, m)
@@ -460,9 +421,8 @@ class BatchCodec:
             out = self._maybe_dp(name, coeff, arr, None)
             if out is None:
                 out, name = _dispatch(name, "matrix_apply", coeff, arr)
-        # stamped AFTER dispatch with the leg that served the step (XOR
-        # door and device-loss fallback resolved): a quarantined device
-        # engine must not keep counting as 'tpu'
+        # stamped AFTER dispatch with the leg that served the step: a
+        # quarantined device engine must not keep counting as 'tpu'
         metrics.codec_batch_steps.inc(op=op, engine=name)
         return out, name
 
@@ -472,7 +432,7 @@ class BatchCodec:
         """Shard a drained step dp-wise over the visible devices (batch
         axis split 1/n per device, bit-identical). Returns None when not
         profitable/applicable."""
-        if not self.dp_enabled or name not in ("tpu", "tpu-pallas"):
+        if not self.dp_enabled or name != "tpu":
             return None
         if int(arr.nbytes) < self.dp_min_bytes or arr.shape[0] < 2:
             return None

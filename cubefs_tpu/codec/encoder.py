@@ -163,8 +163,8 @@ class Encoder:
         return PendingEncode(None, fill, pending._fut)
 
     def _batcher(self):
-        batcher = getattr(self.engine, "batcher", None)
-        return batcher if batcher is not None and batcher.enabled else None
+        """The engine's admission surface; None for a raw engine."""
+        return getattr(self.engine, "batcher", None)
 
     def _submit_rows(self, data: np.ndarray):
         """The one way from an encoder to the batcher: the future of

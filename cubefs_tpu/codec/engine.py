@@ -1,25 +1,32 @@
-"""Pluggable codec engines — the `--ec-engine={numpy,tpu,...}` analog.
+"""Codec engines: the legs that do the GF(2^8) shard math, and the one
+order a step degrades through when a leg is lost.
 
 The reference hard-wires one SIMD CPU engine (klauspost/reedsolomon behind
-blobstore/common/ec/encoder.go); BASELINE.json's north star is a pluggable
-`codec.Engine` where the TPU path is selectable. Engines expose the raw
-shard-math primitives; cubefs_tpu/codec/encoder.py layers the reference's
-Encoder semantics (Split/Verify/Reconstruct/...) on top.
+blobstore/common/ec/encoder.go); here the engine is named per caller
+(``AccessConfig.engine``; ``CUBEFS_TPU_EC_ENGINE`` where none is).
+Engines expose the raw shard-math primitives; cubefs_tpu/codec/encoder.py
+layers the reference's Encoder semantics (Split/Verify/Reconstruct/...)
+on top.
 
-Engines:
-  * ``numpy`` — table-driven GF(2^8) on host; the in-process CPU baseline
-    and the golden for bit-identity tests.
-  * ``tpu``  — JAX bit-matmul kernels (cubefs_tpu/ops/rs_kernel.py); runs
-    on whatever backend jax selects (TPU on hardware, CPU in tests).
-  * ``cpp``  — native C++ engine (cubefs_tpu/runtime), registered when the
+Four legs, in the order of ``_FALLBACK_CHAIN``:
+  * ``tpu`` — the device engine: JAX programs over the GF(2) bit
+    expansion, on whatever backend jax selects (TPU on hardware, CPU in
+    tests). ``ops/rs_kernel.plan`` picks the fused Pallas program or the
+    jnp bit-matmul per shape, from what it can observe; every cell of
+    the benchmark pins this leg.
+  * ``cpp`` — native SIMD GF engine (cubefs_tpu/runtime), there when the
     shared library has been built.
-  * ``numpy-xor`` / ``cpp-xor`` — compiled XOR-program legs
-    (ops/xorprog.py): the coding matrix is lowered once into a
-    CSE'd, cache-blocked XOR schedule and replayed word-wide. These are
-    the degraded-mode (device-lost) hot paths; the ``CUBEFS_CODEC_XOR``
-    door (default on, ``=0`` disables) decides whether routed host
-    dispatches take them. Explicit ``get_engine("numpy")`` stays the
-    naive golden either way.
+  * ``numpy-xor`` — a coefficient matrix lowered once into a CSE'd,
+    cache-blocked XOR schedule (ops/xorprog.py) and replayed word-wide;
+    bit-identical to ``numpy`` and several times as fast. The host leg
+    of a machine without the native library.
+  * ``numpy`` — table-driven GF(2^8); the golden of every bit-identity
+    test and the leg that is always there. Named, it is served as named.
+
+``auto`` routes each call by size through a measured table
+(``engine_for``); unmeasured on the chip, see ROADMAP D5. A leg that
+raises a device-loss error is quarantined and the step is served by the
+next one down; ``CUBEFS_CODEC_DEAD`` declares legs lost for a drill.
 """
 
 from __future__ import annotations
@@ -27,7 +34,7 @@ from __future__ import annotations
 import logging
 import os
 import time
-from typing import Callable, Protocol
+from typing import Protocol
 
 import numpy as np
 
@@ -121,22 +128,22 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
     return phase("d2h", np.asarray, y)
 
 
-def ready_decode(eng, n: int, s: int) -> None:
-    """Called by a device engine after an encode of geometry (n data
+def ready_decode(n: int, s: int) -> None:
+    """Called by the device engine after an encode of geometry (n data
     shards of s bytes): the first time, one zero stripe goes through the
-    engine's own decode shape — n rows solved from n survivors,
+    geometry's decode shape — n rows solved from n survivors,
     (1, n, s), what codec/encoder.py's reconstruct always asks for — so
     its program is compiled (and its Pallas gate paid) with the encode's
     and a hedged or degraded GET never compiles inside a request. Where
     n == m that is the encode's own program and nothing is built."""
     def build() -> bool:
         coeff = np.eye(n, dtype=np.uint8)
-        planes, program = eng._plan(coeff, (1, n, s))
+        planes, program = rs_kernel.plan(coeff, (1, n, s))
         np.asarray(program(rs_kernel.device_bits(coeff, planes),
                            np.zeros((1, n, s), dtype=np.uint8)))
         return True
 
-    progcache.SHARED.get_or_build("decode_ready", (eng.name, n, s), build)
+    progcache.SHARED.get_or_build("decode_ready", (n, s), build)
 
 
 class JaxEngine:
@@ -149,16 +156,14 @@ class JaxEngine:
         data = np.asarray(data)
         n, s = int(data.shape[-2]), int(data.shape[-1])
         out = self._apply("encode", gf256.parity_matrix(n, n_parity), data)
-        ready_decode(self, n, s)
+        ready_decode(n, s)
         return out
-
-    _plan = staticmethod(rs_kernel.plan)
 
     def _apply(self, op: str, coeff: np.ndarray, shards: np.ndarray
                ) -> np.ndarray:
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         shards = np.asarray(shards)
-        planes, program = self._plan(coeff, shards.shape)
+        planes, program = rs_kernel.plan(coeff, shards.shape)
         return device_call(
             self, op, lambda: rs_kernel.device_bits(coeff, planes, op),
             program, shards)
@@ -215,61 +220,12 @@ class XorNumpyEngine:
             gf256.parity_matrix(data.shape[-2], n_parity), data)
 
 
-class XorCppEngine:
-    """The same compiled XOR schedules replayed by the native executor
-    (runtime/src/gfcpu.cc xor_apply): batched word-wide XOR over the
-    plane workspace, one schedule shared with the numpy-xor leg (same
-    digest, same op stream)."""
-
-    name = "cpp-xor"
-
-    def __init__(self):
-        from ..runtime import build as rt_build
-
-        self._lib = rt_build.load()
-        if not hasattr(self._lib, "xor_apply"):  # stale .so
-            raise RuntimeError("libcubefs_rt.so lacks xor_apply")
-
-    def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray) -> np.ndarray:
-        prog = xorprog.program_for(coeff)
-        shards = np.ascontiguousarray(np.asarray(shards, dtype=np.uint8))
-        lead, (c, s) = shards.shape[:-2], shards.shape[-2:]
-        if c != prog.cols:
-            raise ValueError(f"program is {prog.rows}x{prog.cols}, "
-                             f"shards have {c} rows")
-        batch = int(np.prod(lead)) if lead else 1
-        flat = shards.reshape(batch, c, s)
-        s2 = (s + 63) & ~63  # native executor wants 64-byte multiples
-        if s2 != s:
-            padded = np.zeros((batch, c, s2), dtype=np.uint8)
-            padded[:, :, :s] = flat
-            flat = padded
-        out = np.empty((batch, prog.rows, s2), dtype=np.uint8)
-        ops = prog.opstream()
-        self._lib.xor_apply(ops.ctypes.data, len(ops), flat.ctypes.data,
-                            out.ctypes.data, c, prog.rows, prog.nslots,
-                            s2, batch, prog.block_bytes)
-        if s2 != s:
-            out = np.ascontiguousarray(out[:, :, :s])
-        return out.reshape(*lead, prog.rows, s)
-
-    def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
-        return self.matrix_apply(
-            gf256.parity_matrix(data.shape[-2], n_parity), data)
-
-
-_REGISTRY: dict[str, Callable[[], Engine]] = {
+_REGISTRY: dict[str, type] = {
     "numpy": NumpyEngine,
     "tpu": JaxEngine,
     "cpp": CppEngine,
     "numpy-xor": XorNumpyEngine,
-    "cpp-xor": XorCppEngine,
-}
-
-
-def register_engine(name: str, factory: Callable[[], Engine]) -> None:
-    _REGISTRY[name] = factory
-
+}  # and "auto", below its router
 
 _instances: dict[str, Engine] = {}
 
@@ -278,10 +234,6 @@ def get_engine(name: str | None = None) -> Engine:
     """Resolve an engine by name; default from CUBEFS_TPU_EC_ENGINE
     (the --ec-engine flag analog), falling back to the TPU path."""
     name = name or os.environ.get("CUBEFS_TPU_EC_ENGINE", "tpu")
-    if name == "tpu-pallas" and name not in _REGISTRY:
-        from ..ops import pallas_gf
-
-        pallas_gf.register()  # idempotent; import alone is a no-op if cached
     if name not in _REGISTRY:
         raise KeyError(f"unknown ec engine {name!r}; have {sorted(_REGISTRY)}")
     if name not in _instances:
@@ -319,10 +271,9 @@ def _policy_path() -> str:
 
 def measure_crossover(sizes=_POLICY_SIZES, repeats: int = 3,
                       save: bool = True) -> list:
-    """Times the host legs (cpp, and the compiled-XOR legs cpp-xor /
-    numpy-xor) against the device engine on RS(6+3)-shaped single
-    stripes per total-size class; returns [[max_total_bytes, engine],
-    ...] sorted ascending. Persisted (with per-engine timings and the
+    """Times the host legs (cpp, numpy-xor) against the device engine
+    on RS(6+3)-shaped single stripes per total-size class; returns
+    [[max_total_bytes, engine], ...] sorted ascending. Persisted (with per-engine timings and the
     host-vs-device crossover point) so later processes inherit the
     policy without re-measuring."""
     import json
@@ -331,7 +282,7 @@ def measure_crossover(sizes=_POLICY_SIZES, repeats: int = 3,
     table = []
     timings: dict[str, dict[str, float]] = {}
     candidates = []
-    for name in ("cpp", "cpp-xor", "numpy-xor"):
+    for name in ("cpp", "numpy-xor"):
         try:
             get_engine(name)
             candidates.append(name)
@@ -390,7 +341,7 @@ def _static_policy() -> list:
         get_engine("cpp")
     except Exception:
         have_cpp = False
-    small = "cpp" if have_cpp else "numpy"
+    small = "cpp" if have_cpp else "numpy-xor"
     return [[1 << 20, small], [1 << 62, "tpu"]]
 
 
@@ -445,27 +396,9 @@ def _load_policy() -> list:
 # engine_for so a lost accelerator degrades once, not on every call.
 _dead_engines: set[str] = set()
 
-# Degradation order on device loss: pallas kernels -> plain jax ->
-# native SIMD -> native XOR programs -> host XOR programs ->
-# table-driven host math (always available).
-_FALLBACK_CHAIN = ("tpu-pallas", "tpu", "cpp", "cpp-xor",
-                   "numpy-xor", "numpy")
-
-# CUBEFS_CODEC_XOR door aliasing. Upgrades are asymmetric on purpose:
-# routed `numpy` dispatches upgrade to the compiled-XOR leg (a strict
-# ~4x win — same answer, no table gathers), but `cpp` is NOT statically
-# aliased — on AVX2 hosts the nibble-shuffle gather beats the XOR
-# replay, and the measured crossover sweep (which times cpp-xor as a
-# candidate) is the one allowed to decide that, not an alias.
-_XOR_UP = {"numpy": "numpy-xor"}
-# Door closed: any routed xor leg drops back to its naive base.
-_XOR_BASE = {"numpy-xor": "numpy", "cpp-xor": "cpp"}
-
-def _xor_enabled() -> bool:
-    """The CUBEFS_CODEC_XOR A/B door (default ON; =0 reverts routed
-    host dispatches to the naive table legs). Read per call so a drill
-    can flip it mid-process."""
-    return os.environ.get("CUBEFS_CODEC_XOR", "1") != "0"
+# Degradation order on device loss: the device, native SIMD, the host
+# XOR programs, table-driven host math (always there).
+_FALLBACK_CHAIN = ("tpu", "cpp", "numpy-xor", "numpy")
 
 
 def _drilled_dead() -> set[str]:
@@ -477,37 +410,15 @@ def _drilled_dead() -> set[str]:
     return {x.strip() for x in v.split(",") if x.strip()}
 
 
-def resolve_leg(name: str) -> str:
-    """Door-aware leg for a routed host dispatch: `numpy` upgrades to
-    its compiled-XOR leg while the door is open, and xor legs drop back
-    to their naive bases when it is closed. Explicit `get_engine(...)`
-    calls bypass this — only routed paths (_call_with_fallback /
-    engine_for / the batcher) alias."""
-    if _xor_enabled():
-        alias = _XOR_UP.get(name)
-        if (alias and alias not in _dead_engines
-                and alias not in _drilled_dead()):
-            try:
-                get_engine(alias)
-                return alias
-            except Exception:
-                return name
-        return name
-    return _XOR_BASE.get(name, name)
-
-
-def _fallback_for(name: str) -> str | None:
+def _fallback_for(name: str, drilled: set[str]) -> str | None:
     """Next live engine after `name` in the degradation chain."""
     try:
         i = _FALLBACK_CHAIN.index(name)
     except ValueError:
         return None
-    drilled = _drilled_dead()
     for nxt in _FALLBACK_CHAIN[i + 1:]:
         if nxt in _dead_engines or nxt in drilled:
             continue
-        if nxt in _XOR_BASE and not _xor_enabled():
-            continue  # door closed: xor legs are not in the chain
         try:
             get_engine(nxt)
         except Exception:
@@ -528,20 +439,19 @@ def _dispatch(name: str, method: str, *args) -> tuple[object, str]:
     guarantee, not a way to leave the device unseen. Drilled-dead
     engines (CUBEFS_CODEC_DEAD) are skipped before dispatch without
     being quarantined."""
+    drilled = _drilled_dead()
+    if name in drilled:
+        nxt = _fallback_for(name, drilled)
+        if nxt is None:
+            raise RuntimeError(
+                f"engine {name!r} drilled dead and no fallback left")
+        name = nxt
     while True:
-        name = resolve_leg(name)
-        if name in _drilled_dead():
-            nxt = _fallback_for(name)
-            if nxt is None:
-                raise RuntimeError(
-                    f"engine {name!r} drilled dead and no fallback left")
-            name = nxt
-            continue
         eng = get_engine(name)
         try:
             return getattr(eng, method)(*args), name
         except (RuntimeError, OSError):
-            nxt = _fallback_for(name)
+            nxt = _fallback_for(name, drilled)
             if nxt is None:
                 raise
             _log.exception(
@@ -560,9 +470,8 @@ def engine_for(nbytes: int) -> Engine:
     drilled = _drilled_dead()
     for limit, name in _load_policy():
         if nbytes <= limit:
-            name = resolve_leg(name)
             if name in _dead_engines or name in drilled:
-                name = _fallback_for(name) or name
+                name = _fallback_for(name, drilled) or name
             try:
                 return get_engine(name)
             except Exception:

@@ -25,7 +25,15 @@ def configure_compile_cache(environ=os.environ) -> str | None:
     path that moves (tempfile, pid, time) never hits. Either way the
     minimum-compile-time threshold drops to 0: the codec kernels
     compile in 0.2-2 s each, mostly under JAX's default 1 s floor, and
-    every drained batch width is its own jit shape.
+    every drained batch width is its own jit shape. And a source
+    location carries its innermost frame only: XLA strips locations
+    from a module before it keys it, but not from the Mosaic payload of
+    a Pallas program, and with ten frames of call stack in them that key
+    named whoever called the program first — the bare or the phased
+    engine call, a traced run's wrappers, any edited caller — so one
+    tree's processes kept missing each other's entries (9, 4, 3, 0 of
+    40). (Not ``jax_include_full_tracebacks_in_locations``: turning
+    that off renames the kernel in the device trace.)
 
     A process pinned to CPU (``JAX_PLATFORMS=cpu``: the test suite, the
     launcher's non-owner roles) is left alone: XLA:CPU executables are
@@ -38,6 +46,7 @@ def configure_compile_cache(environ=os.environ) -> str | None:
         path = os.path.join(_CHECKOUT, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return path
 
 
@@ -45,7 +54,7 @@ COMPILE_CACHE_DIR = configure_compile_cache()
 
 
 def require_tpu() -> list:
-    """For a process meant to own the chip (chip_smoke.py, bench.py):
+    """For a process meant to own the chip (chip_smoke.py, cellbench):
     ask for the TPU by name and return its devices, or raise. With
     JAX_PLATFORMS unset JAX registers the TPU ``fail_quietly`` and hands
     a process that cannot get the chip — none attached, or held by

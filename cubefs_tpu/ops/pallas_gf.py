@@ -16,7 +16,8 @@ survivor set nobody warmed costs a lookup or a 83 KB upload, never a
 compile.
 
 Bit-identical to the jnp path by construction (same exact integer math);
-tests compare both on every codemode (interpret mode off-TPU).
+tests compare both on every codemode (interpret mode off-TPU). Which
+shapes this program serves is rs_kernel.plan's decision alone.
 """
 
 from __future__ import annotations
@@ -29,7 +30,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..codec.engine import JaxEngine, register_engine
 from ..utils import metrics
 from . import progcache
 
@@ -37,12 +37,12 @@ from . import progcache
 # for C input shards and R output rows: at T=32KiB and RS(12+4) repair
 # (C=12, R<=6) that is ~8 MiB — comfortably inside a v5e core's ~16 MiB
 # VMEM while amortizing grid overhead far better than tiny tiles.
-# bench.py autotunes over TILE_CANDIDATES on real hardware — and MUST
-# verify bit-identity per tile first (verify_tile below): Mosaic was
+# Whoever tunes over TILE_CANDIDATES on real hardware MUST verify
+# bit-identity per tile first (verify_tile below): Mosaic was
 # observed to MISCOMPILE this kernel at tile >= 65536 (silent wrong
 # parity), so an unvalidated autotune can "win" with garbage output.
 # CUBEFS_PALLAS_TILE pins the production tile if a deployment's
-# autotune says otherwise.
+# tuning says otherwise.
 DEFAULT_TILE = int(os.environ.get("CUBEFS_PALLAS_TILE", "32768"))
 TILE_CANDIDATES = (8192, 16384, 32768)
 
@@ -194,31 +194,3 @@ def verify_tile(coeff: np.ndarray, tile: int, seed: int = 0) -> bool:
         want = rs_kernel._bits_fn(*coeff.shape, tuple(x.shape))(
             rs_kernel.device_bits(coeff, False), x)
         return bool(jax.device_get(_jnp.array_equal(got, want)))
-
-
-class PallasEngine(JaxEngine):
-    """codec engine backed by the fused kernel (--ec-engine=tpu-pallas):
-    JaxEngine with the fused program at every shard size."""
-
-    name = "tpu-pallas"
-
-    @staticmethod
-    def _plan(coeff: np.ndarray, shape: tuple) -> tuple[bool, object]:
-        # same miscompile gate as the rs_kernel dispatch: even when the
-        # operator forces this engine, a program Mosaic miscompiles must
-        # fall back to the exact jnp path rather than write bad parity
-        from . import rs_kernel
-
-        shape = tuple(shape)
-        if on_tpu() and not rs_kernel._pallas_verified(
-                *coeff.shape, DEFAULT_TILE, coeff):
-            return False, rs_kernel._bits_fn(*coeff.shape, shape)
-        return True, _apply_fn(*coeff.shape, shape, DEFAULT_TILE,
-                               not on_tpu())
-
-
-def register() -> None:
-    register_engine("tpu-pallas", PallasEngine)
-
-
-register()

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import collections
 import logging
-import os
 import threading
 
 import jax
@@ -42,10 +41,7 @@ _log = logging.getLogger("cubefs.codec")
 
 def _use_pallas() -> bool:
     """On real TPU the fused plane-major Pallas kernel avoids the 8x bit
-    tensor in HBM; CUBEFS_NO_PALLAS=1 forces the jnp path (debugging /
-    A-B measurement)."""
-    if os.environ.get("CUBEFS_NO_PALLAS"):
-        return False
+    tensor in HBM."""
     from . import pallas_gf
 
     return pallas_gf.on_tpu()
@@ -273,7 +269,8 @@ def plan(coeff: np.ndarray, shape: tuple) -> tuple[bool, object]:
         from . import pallas_gf
 
         return True, pallas_gf._apply_fn(rows, cols, shape,
-                                         pallas_gf.DEFAULT_TILE, False)
+                                         pallas_gf.DEFAULT_TILE,
+                                         not pallas_gf.on_tpu())
     return False, _bits_fn(rows, cols, shape)
 
 

@@ -161,8 +161,7 @@ def _greedy_cse(rows_of: dict[int, int], next_col: int,
 class XorProgram:
     """One compiled schedule for one (R, C) GF(2^8) matrix.
 
-    Slot layout (shared with the native executor in runtime/src/
-    gfcpu.cc — outputs are always the LAST 8R slots):
+    Slot layout (outputs are always the LAST 8R slots):
 
       [0, 8C)              input planes   (shard j bit k -> slot 8j+k)
       [8C, 8C+T)           temp planes    (CSE intermediates)
@@ -243,9 +242,8 @@ class XorProgram:
             h.update(("o%d=" % dst).encode())
             h.update(np.asarray(idx, dtype=np.int64).tobytes())
         self.schedule_digest = h.hexdigest()
-        self._c_opstream: np.ndarray | None = None
 
-    # ---- stats / native export ----
+    # ---- stats ----
 
     def stats(self) -> dict:
         return {
@@ -256,19 +254,6 @@ class XorProgram:
             "block_bytes": self.block_bytes,
             "digest": self.schedule_digest,
         }
-
-    def opstream(self) -> np.ndarray:
-        """The schedule as the int32 stream the native executor
-        (gfcpu.cc xor_apply) replays: repeated [dst, nsrc, src...],
-        temps first, then outputs (nsrc=0 zeroes the plane)."""
-        if self._c_opstream is None:
-            words: list[int] = []
-            for dst, a, b in self.temp_ops:
-                words += [dst, 2, a, b]
-            for dst, idx in self.out_ops:
-                words += [dst, len(idx), *map(int, idx)]
-            self._c_opstream = np.array(words, dtype=np.int32)
-        return self._c_opstream
 
     # ---- execution (numpy leg) ----
 

@@ -239,12 +239,6 @@ def load() -> ctypes.CDLL:
                 c.c_void_p, c.c_uint64, c.c_uint64, c.c_void_p,
                 c.c_void_p, c.c_uint64, c.c_uint64]
             lib.gf_cpu_level.restype = c.c_int
-            # scheduled XOR-program executor (ops/xorprog.py schedules
-            # replayed natively; the cpp-xor codec leg)
-            lib.xor_apply.argtypes = [
-                c.c_void_p, c.c_uint64, c.c_void_p, c.c_void_p,
-                c.c_uint64, c.c_uint64, c.c_uint64,
-                c.c_uint64, c.c_uint64, c.c_uint64]
             # shared native CRC32 (clmul folding; crc32cpu.cc)
             lib.rt_crc32.restype = c.c_uint32
             lib.rt_crc32.argtypes = [c.c_uint32, c.c_void_p, c.c_size_t]

@@ -143,90 +143,6 @@ void gf_apply(const uint8_t* mat, uint64_t m, uint64_t n, const uint8_t* in,
   }
 }
 
-// Scheduled XOR-program executor — the native replay of the schedules
-// ops/xorprog.py compiles (the arXiv 2108.02692 direction). The op
-// stream is int32 [dst, nsrc, src...]* over plane slots: slots
-// [0, 8*cin) are input bit-planes (shard j bit k -> slot 8j+k,
-// LSB-first, matching ops/bitlin.py), the LAST 8*rout slots are output
-// planes (row i bit b -> nslots-8*rout+8i+b), temps in between. Per
-// block, input shards are split to bit-planes with the 8x8 SWAR bit
-// transpose, the ops replay as word-wide XOR (auto-vectorized at -O3),
-// and output planes transpose back to bytes. s and block must be
-// multiples of 64 (the python caller pads); the plane workspace is
-// sized nslots*block/8 so the whole block stays cache-resident.
-
-static inline uint64_t xp_transpose8(uint64_t x) {
-  uint64_t t;
-  t = (x ^ (x >> 7)) & 0x00AA00AA00AA00AAULL;
-  x = x ^ t ^ (t << 7);
-  t = (x ^ (x >> 14)) & 0x0000CCCC0000CCCCULL;
-  x = x ^ t ^ (t << 14);
-  t = (x ^ (x >> 28)) & 0x00000000F0F0F0F0ULL;
-  x = x ^ t ^ (t << 28);
-  return x;
-}
-
-void xor_apply(const int32_t* ops, uint64_t ops_words, const uint8_t* in,
-               uint8_t* out, uint64_t cin, uint64_t rout, uint64_t nslots,
-               uint64_t s, uint64_t batch, uint64_t block) {
-  if (s % 64 || block % 64 || block == 0) return;  // caller contract
-  const uint64_t plane_w = block / 64;  // uint64 words per plane slot
-  uint64_t* ws = new uint64_t[nslots * plane_w];
-  const uint64_t obase = nslots - 8 * rout;
-  for (uint64_t b = 0; b < batch; b++) {
-    for (uint64_t off = 0; off < s; off += block) {
-      const uint64_t cur = (s - off < block) ? (s - off) : block;
-      const uint64_t nw = cur / 8;   // words per shard block
-      const uint64_t pw = cur / 64;  // words per plane this block
-      // split: shard bytes -> 8 bit-planes each
-      for (uint64_t j = 0; j < cin; j++) {
-        const uint8_t* src = in + (b * cin + j) * s + off;
-        uint8_t* pl = (uint8_t*)(ws + 8 * j * plane_w);
-        const uint64_t pb = plane_w * 8;  // plane stride in bytes
-        for (uint64_t w = 0; w < nw; w++) {
-          uint64_t x;
-          memcpy(&x, src + w * 8, 8);
-          x = xp_transpose8(x);
-          for (int k = 0; k < 8; k++)
-            pl[(uint64_t)k * pb + w] = (uint8_t)(x >> (8 * k));
-        }
-      }
-      // replay the schedule
-      const int32_t* p = ops;
-      const int32_t* end = ops + ops_words;
-      while (p < end) {
-        const int32_t dst = *p++;
-        const int32_t n = *p++;
-        uint64_t* d = ws + (uint64_t)dst * plane_w;
-        if (n == 0) {
-          memset(d, 0, pw * 8);
-        } else {
-          memcpy(d, ws + (uint64_t)p[0] * plane_w, pw * 8);
-          for (int32_t i = 1; i < n; i++) {
-            const uint64_t* si = ws + (uint64_t)p[i] * plane_w;
-            for (uint64_t w = 0; w < pw; w++) d[w] ^= si[w];
-          }
-          p += n;
-        }
-      }
-      // join: output planes -> bytes
-      for (uint64_t i = 0; i < rout; i++) {
-        uint8_t* dst = out + (b * rout + i) * s + off;
-        const uint8_t* pl = (const uint8_t*)(ws + (obase + 8 * i) * plane_w);
-        const uint64_t pb = plane_w * 8;
-        for (uint64_t w = 0; w < nw; w++) {
-          uint64_t x = 0;
-          for (int k = 0; k < 8; k++)
-            x |= (uint64_t)pl[(uint64_t)k * pb + w] << (8 * k);
-          x = xp_transpose8(x);
-          memcpy(dst + w * 8, &x, 8);
-        }
-      }
-    }
-  }
-  delete[] ws;
-}
-
 // which SIMD path gf_apply will take: 2=avx2, 1=ssse3, 0=scalar
 int gf_cpu_level() {
 #ifdef GF_X86

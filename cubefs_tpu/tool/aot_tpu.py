@@ -1,7 +1,7 @@
 """AOT-compile the judged bench graphs for a real v5e TPU target — no chip.
 
-A pre-flight that costs no chip time: the north-star kernels (bench.py
-configs 1-5, BASELINE.json) are lowered and compiled for a TPU target
+A pre-flight that costs no chip time: the north-star kernels
+(BASELINE.json configs 1-5) are lowered and compiled for a TPU target
 from a CPU-only box. `jax.experimental.topologies.get_topology_desc(
 "v5e:2x2")` (PJRT TPU compile-only client over the baked-in libtpu)
 yields real v5e devices to lower + compile against, including Mosaic
@@ -106,7 +106,7 @@ def _compile_one(name: str, fn, arg_structs, out_dir: Path | None):
 def compile_judged_graphs(out_dir: Path | None = None) -> list[dict]:
     """Compile every BASELINE.json config's graph for the v5e target.
 
-    Shapes are exactly bench.py's on-TPU shapes (4MiB shards, judged
+    Shapes are BASELINE.json's judged ones (4MiB shards, judged
     stripes-per-step), so a green record here means the judged
     configuration itself compiles for the chip.
     """
@@ -123,7 +123,7 @@ def compile_judged_graphs(out_dir: Path | None = None) -> list[dict]:
     def arg(shape, dtype=jnp.uint8):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    S, Br, B = 4 << 20, 4, 8  # bench.py on-TPU shapes
+    S, Br, B = 4 << 20, 4, 8  # BASELINE.json's judged shapes
     plan = repair.make_plan(12, 4, bad=[1, 7])
     rows = plan.rows
     records = []
@@ -147,7 +147,7 @@ def compile_judged_graphs(out_dir: Path | None = None) -> list[dict]:
         )
     )
     # config 3, fused pallas kernel, every tile candidate — through the
-    # public wrapper so the compiled graph is exactly what bench.py runs
+    # public wrapper, so the compiled graph is the one its callers run
     for tile in pallas_gf.TILE_CANDIDATES:
         records.append(
             _compile_one(
@@ -234,7 +234,7 @@ def roofline_md(records: list[dict]) -> str:
         f"reachable; the fused kernel's advantage is the ~{hbm_jnp/hbm_fused:.1f}x lower HBM",
         "traffic (and measured compiled temp memory below). Tile size",
         "(8/16/32 KiB) only changes grid amortization, not the roofline —",
-        "the autotune in bench.py picks among them on-chip.",
+        "the served tile is pallas_gf.DEFAULT_TILE (32 KiB).",
         "",
         "## Compiled memory per graph (from XLA memory_analysis)",
         "",

@@ -1886,7 +1886,7 @@ def geo_ab(workdir: str, files: int = 48, file_kb: int = 768,
 
 def merge_artifact(path: str, section: str, data: dict) -> None:
     """Read-merge-write one section of a shared artifact JSON, so
-    bench_fs and bench_codec can fill their halves independently."""
+    each mode fills its own section and keeps the others'."""
     existing: dict = {}
     if os.path.exists(path):
         try:

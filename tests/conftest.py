@@ -13,6 +13,20 @@ def rng():
     return np.random.default_rng(0xC0DEC)
 
 
+@pytest.fixture(params=["bits", "fused"])
+def program(request, monkeypatch):
+    """Which of the device engine's two programs `rs_kernel.plan` hands
+    it: the jnp bit-matmul (what it picks off the chip) or the fused
+    Pallas program — interpreted here, at a tile of 256 so test-sized
+    shards span several grid steps."""
+    if request.param == "fused":
+        from cubefs_tpu.ops import pallas_gf, rs_kernel
+
+        monkeypatch.setattr(rs_kernel, "serves_fused", lambda coeff, s: True)
+        monkeypatch.setattr(pallas_gf, "DEFAULT_TILE", 256)
+    return request.param
+
+
 class _StillTracker:
     """Empty SLO snapshot: the gate sees a healthy system."""
 

@@ -55,6 +55,29 @@ def test_repair_graphs_compile_for_v5e(v5e_sharding):
     assert m_pl.temp_size_in_bytes < 1 << 30
 
 
+def test_fused_kernel_keeps_its_name_in_the_compiled_program(v5e_sharding):
+    """The device trace knows the kernel by its instruction's name
+    (`%gf256_apply.1 custom-call`; cellbench's readers and PERF.md match
+    on it), and XLA takes that name from the source locations — which a
+    process that keeps a compile cache cuts to their innermost frame
+    (ops/__init__.py)."""
+    import re
+
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        program = pallas_gf._apply_fn.__wrapped__(
+            4, 12, (2, 12, 65536), 32768, False)
+        w = jax.ShapeDtypeStruct((32, 96), jnp.int8, sharding=v5e_sharding)
+        x = jax.ShapeDtypeStruct((2, 12, 65536), jnp.uint8,
+                                 sharding=v5e_sharding)
+        text = jax.jit(program).lower(w, x).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert re.findall(r"(%\S+) = \S+ custom-call\(", text) == [
+        "%gf256_apply.1"]
+
+
 def test_all_judged_graphs_green_in_artifact():
     """Every BASELINE config's graph compiled for v5e in the committed
     artifact sweep (regenerated by `python -m cubefs_tpu.tool.aot_tpu`),

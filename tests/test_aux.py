@@ -199,18 +199,6 @@ def test_blob_bench_tool(mini_blob):
     assert out["put_mbps"] > 0 and out["get_mbps"] > 0
 
 
-def test_pallas_engine_lazy_registration():
-    """get_engine('tpu-pallas') must work without a prior pallas import
-    (fresh interpreter check is in test_native's subprocess pattern; here
-    exercise the lazy-import branch path at least)."""
-    import importlib
-    from cubefs_tpu.codec import engine as eng
-    eng._REGISTRY.pop("tpu-pallas", None)
-    eng._instances.pop("tpu-pallas", None)
-    e = eng.get_engine("tpu-pallas")
-    assert e.name == "tpu-pallas"
-
-
 def test_fs_bench_tool(tmp_path):
     from cubefs_tpu.tool import bench_fs
 

@@ -21,12 +21,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def jax_cache_config():
     import jax
 
-    saved = (jax.config.jax_compilation_cache_dir,
-             jax.config.jax_persistent_cache_min_compile_time_secs)
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_traceback_in_locations_limit")
+    saved = [getattr(jax.config, name) for name in names]
     jax.config.update("jax_compilation_cache_dir", None)
     yield jax.config
-    jax.config.update("jax_compilation_cache_dir", saved[0])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
+    for name, value in zip(names, saved):
+        jax.config.update(name, value)
 
 
 def test_compile_cache_env_set_sets_no_path(jax_cache_config):
@@ -37,6 +39,7 @@ def test_compile_cache_env_set_sets_no_path(jax_cache_config):
     assert got == "/somewhere/else"
     assert jax_cache_config.jax_compilation_cache_dir is None
     assert jax_cache_config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax_cache_config.jax_traceback_in_locations_limit == 1
 
 
 def test_compile_cache_unset_is_fixed_in_checkout(jax_cache_config):
@@ -48,6 +51,45 @@ def test_compile_cache_unset_is_fixed_in_checkout(jax_cache_config):
     assert jax_cache_config.jax_persistent_cache_min_compile_time_secs == 0
     # fixed: a second process (or call) lands on the same directory
     assert ops.configure_compile_cache({}) == want
+
+
+def test_a_fused_programs_cache_key_does_not_name_its_callers(
+        jax_cache_config):
+    """What keys a Pallas program in the persistent cache is its Mosaic
+    payload, source locations and all. A process that keeps a cache
+    lowers the same payload whoever calls the program first; with ten
+    frames of call stack in the locations (JAX's default) it did not,
+    and runs of one tree kept missing each other's entries. (That the
+    kernel keeps its name in the compiled program: tests/test_aot_tpu.py.)"""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from cubefs_tpu import ops
+    from cubefs_tpu.ops import pallas_gf
+
+    ops.configure_compile_cache({"JAX_COMPILATION_CACHE_DIR": "/elsewhere"})
+    assert jax_cache_config.jax_traceback_in_locations_limit == 1
+    w = jax.ShapeDtypeStruct((32, 96), jnp.int8)
+    x = jax.ShapeDtypeStruct((2, 12, 1024), jnp.uint8)
+
+    def payload(call) -> list[str]:
+        # a program built anew: it is traced on first use, by this caller
+        program = pallas_gf._apply_fn.__wrapped__(
+            4, 12, (2, 12, 1024), 256, False)
+        text = jax.jit(lambda w, x: call(program, w, x)).trace(w, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+        return re.findall(r'backend_config\s*=\s*"((?:[^"\\]|\\.)*)"', text)
+
+    def bare(program, w, x):
+        return program(w, x)
+
+    def phased(program, w, x):
+        return (lambda fn, *args: fn(*args))(program, w, x)
+
+    direct = payload(bare)
+    assert direct and payload(phased) == direct
 
 
 def test_compile_cache_left_alone_when_pinned_to_cpu(jax_cache_config):
@@ -171,7 +213,7 @@ def test_step_counter_names_the_engine_that_served(monkeypatch, rng):
 
     monkeypatch.setattr(eng, "_dead_engines", set())
     monkeypatch.setattr(eng, "_instances", dict(eng._instances, tpu=Lost()))
-    bc = batcher.BatchCodec(enabled=True)
+    bc = batcher.BatchCodec()
     bc.dp_enabled = False
     before = dict(metrics.codec_batch_steps.samples())
     data = rng.integers(0, 256, (1, 6, 64), dtype=np.uint8)
@@ -294,7 +336,7 @@ def test_a_mismatching_fused_program_is_refused_and_the_jnp_path_serves(
 def test_dp_failure_is_logged_not_silent(monkeypatch, caplog, rng):
     from cubefs_tpu.codec import batcher
 
-    bc = batcher.BatchCodec(enabled=True)
+    bc = batcher.BatchCodec()
     bc.dp_min_bytes = 0
 
     def boom(*a):
@@ -317,7 +359,7 @@ def test_dp_counter_reports_devices_holding_input(rng):
     n_dev = len(jax.devices())
     if n_dev < 2:
         pytest.skip("needs the virtual multi-device mesh")
-    bc = batcher.BatchCodec(enabled=True)
+    bc = batcher.BatchCodec()
     bc.dp_min_bytes = 0
     before = metrics.codec_batch_dp_steps.value(dp=n_dev)
     bc.submit_encode("tpu", rng.integers(

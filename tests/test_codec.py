@@ -276,9 +276,9 @@ def test_autoengine_degrades_on_device_loss(monkeypatch, rng):
 
 def test_fallback_lands_on_numpy_when_cpp_unavailable(monkeypatch, rng):
     """A host without the native .so (or with a broken one) degrades
-    cpp-xor -> cpp -> numpy leg; the healthy engines are NOT
-    quarantined along the way — only the engines that actually failed
-    are."""
+    cpp -> numpy-xor, the host leg below it; the healthy engines are
+    NOT quarantined along the way — only the engine that actually
+    failed is."""
     from cubefs_tpu.codec import engine as eng
 
     class BrokenNative:
@@ -292,14 +292,13 @@ def test_fallback_lands_on_numpy_when_cpp_unavailable(monkeypatch, rng):
             raise OSError("libgfcpu.so: cannot open shared object file")
 
     monkeypatch.setattr(eng, "_dead_engines", set())
-    monkeypatch.setattr(eng, "_instances",
-                        {"cpp": BrokenNative("cpp"),
-                         "cpp-xor": BrokenNative("cpp-xor")})
+    monkeypatch.setattr(eng, "_instances", {"cpp": BrokenNative("cpp")})
     data = rng.integers(0, 256, (6, 64)).astype(np.uint8)
-    parity = eng._call_with_fallback("cpp", "encode_parity", data, 3)
+    parity, served = eng._dispatch("cpp", "encode_parity", data, 3)
+    assert served == "numpy-xor"
     assert np.array_equal(parity, eng.NumpyEngine().encode_parity(data, 3))
-    # both broken native legs quarantined; tpu/numpy stay in rotation
-    assert eng._dead_engines == {"cpp", "cpp-xor"}
+    # the broken native leg quarantined; tpu/numpy stay in rotation
+    assert eng._dead_engines == {"cpp"}
     # the router now routes around the dead native engine too
     monkeypatch.setattr(eng, "_policy", [[1 << 62, "cpp"]])
     assert eng.engine_for(64).name in ("tpu", "numpy", "numpy-xor")
@@ -312,15 +311,12 @@ def test_crossover_policy_routes_by_size(monkeypatch, rng):
     from cubefs_tpu.codec import engine as eng
 
     monkeypatch.setattr(eng, "_dead_engines", set())
+    # a table's leg is served as the table names it: no alias
+    monkeypatch.setattr(eng, "_policy", [[1 << 62, "numpy"]])
+    assert eng.engine_for(64).name == "numpy"
     monkeypatch.setattr(eng, "_policy",
-                        [[1024, "numpy"], [1 << 62, "tpu"]])
-    # a policy's host leg aliases to its compiled-XOR twin while the
-    # CUBEFS_CODEC_XOR door is open (the default)
-    monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
+                        [[1024, "numpy-xor"], [1 << 62, "tpu"]])
     assert eng.engine_for(1024).name == "numpy-xor"  # inclusive bound
-    monkeypatch.setenv("CUBEFS_CODEC_XOR", "0")
-    assert eng.engine_for(1024).name == "numpy"
-    monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
     assert eng.engine_for(1025).name == "tpu"
     auto = eng.AutoEngine()
     small = rng.integers(0, 256, (4, 64)).astype(np.uint8)   # 256 B
@@ -332,13 +328,12 @@ def test_crossover_policy_routes_by_size(monkeypatch, rng):
                           golden.encode_parity(big, 2))
 
 
-def test_chaos_drill_full_fallback_chain_both_door_positions(monkeypatch):
-    """Seeded device-loss drill: with every device/native leg declared
-    transiently dead (CUBEFS_CODEC_DEAD), a tpu-requested decode walks
-    the whole tpu→cpp→numpy chain and lands on the surviving numpy leg
-    the XOR door selects — byte-identical either way, reproducible
-    schedule digest, and NO permanent quarantine (a drill is not an
-    engine failure)."""
+def test_chaos_drill_full_fallback_chain(monkeypatch):
+    """Seeded device-loss drill: with the device and the native leg
+    declared transiently dead (CUBEFS_CODEC_DEAD), a tpu-requested
+    decode walks the tpu→cpp→numpy-xor chain and lands on the compiled
+    XOR leg — byte-identical, reproducible schedule digest, and NO
+    permanent quarantine (a drill is not an engine failure)."""
     from cubefs_tpu.codec import engine as eng
     from cubefs_tpu.ops import gf256, xorprog
 
@@ -352,23 +347,62 @@ def test_chaos_drill_full_fallback_chain_both_door_positions(monkeypatch):
     gold = gf256.gf_matmul(rows, recv)
 
     monkeypatch.setattr(eng, "_dead_engines", set())
-    monkeypatch.setenv("CUBEFS_CODEC_DEAD", "tpu-pallas,tpu,cpp,cpp-xor")
+    monkeypatch.setenv("CUBEFS_CODEC_DEAD", "tpu, cpp")
 
-    monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
-    out_on, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
+    out, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
     assert served == "numpy-xor"
-    assert np.array_equal(out_on, gold)
+    assert np.array_equal(out, gold)
     digest1 = xorprog.program_for(rows).schedule_digest
 
-    monkeypatch.setenv("CUBEFS_CODEC_XOR", "0")
-    out_off, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
-    assert served == "numpy"
-    assert np.array_equal(out_off, out_on)  # byte-identical across door
-
-    monkeypatch.delenv("CUBEFS_CODEC_XOR", raising=False)
+    again, served = eng._dispatch("tpu", "matrix_apply", rows, recv)
+    assert served == "numpy-xor" and np.array_equal(again, out)
     digest2 = xorprog.program_for(rows).schedule_digest
     assert digest1 == digest2  # the drill replays ONE schedule
     assert eng._dead_engines == set()  # transient death ≠ quarantine
+
+
+@pytest.mark.parametrize("gone", ["cpp-xor", "tpu-pallas"])
+def test_the_registry_is_the_four_legs_and_auto(gone):
+    from cubefs_tpu.codec import engine as eng
+
+    assert sorted(eng._REGISTRY) == [
+        "auto", "cpp", "numpy", "numpy-xor", "tpu"]
+    assert set(eng._FALLBACK_CHAIN) == set(eng._REGISTRY) - {"auto"}
+    with pytest.raises(KeyError, match="unknown ec engine"):
+        eng.get_engine(gone)
+
+
+@pytest.mark.parametrize("op", ["encode", "apply"])
+def test_chain_drilled_dead_one_leg_at_a_time(op, rng, monkeypatch):
+    """The one fallback order, leg by leg: with the legs above it
+    drilled dead, a step pinned to `tpu` is served by — and stamped
+    with — the next one down, bit-identical to the table reference."""
+    from cubefs_tpu.codec import engine as eng
+    from cubefs_tpu.codec.batcher import BatchCodec
+    from cubefs_tpu.ops import gf256
+    from cubefs_tpu.utils import metrics
+
+    monkeypatch.setattr(eng, "_dead_engines", set())
+    eng.get_engine("cpp")  # the native leg is built here
+    chain = ("tpu", "cpp", "numpy-xor", "numpy")
+    assert eng._FALLBACK_CHAIN == chain
+    bc = BatchCodec()
+    x = rng.integers(0, 256, (1, 6, 512), dtype=np.uint8)
+    rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:2]
+    want = (eng.NumpyEngine().encode_parity(x, 3) if op == "encode"
+            else eng.NumpyEngine().matrix_apply(rows, x))
+    for k, leg in enumerate(chain):
+        monkeypatch.setenv("CUBEFS_CODEC_DEAD", ",".join(chain[:k]))
+        before = metrics.codec_batch_steps.value(op=op, engine=leg)
+        got = (bc.submit_encode("tpu", x, 3) if op == "encode"
+               else bc.submit_apply("tpu", rows, x))
+        assert np.array_equal(got, want), leg
+        assert metrics.codec_batch_steps.value(
+            op=op, engine=leg) == before + 1, leg
+    assert eng._dead_engines == set()
+    monkeypatch.setenv("CUBEFS_CODEC_DEAD", ",".join(chain))
+    with pytest.raises(RuntimeError, match="no fallback left"):
+        bc.submit_apply("tpu", rows, x)
 
 
 def test_stale_policy_is_logged_not_silently_kept(tmp_path, monkeypatch,
@@ -451,9 +485,7 @@ def every_call_phased(monkeypatch):
     from cubefs_tpu.codec import engine
 
     monkeypatch.setattr(engine, "PHASE_EVERY_S", 0.0)
-    for name in ("tpu", "tpu-pallas"):
-        monkeypatch.setattr(get_engine(name), "_phase_due", 0.0,
-                            raising=False)
+    monkeypatch.setattr(get_engine("tpu"), "_phase_due", 0.0, raising=False)
 
 
 def _grew(before, after):
@@ -461,10 +493,10 @@ def _grew(before, after):
             if after[k] != before.get(k, 0)}
 
 
-def _device_engine_call(name, op, rng):
+def _device_engine_call(op, rng):
     from cubefs_tpu.ops import gf256
 
-    eng = get_engine(name)
+    eng = get_engine("tpu")
     data = rng.integers(0, 256, (3, 6, 4096), dtype=np.uint8)
     if op == "encode":
         return (eng.encode_parity(data, 3),
@@ -474,27 +506,27 @@ def _device_engine_call(name, op, rng):
             get_engine("numpy").matrix_apply(rows, data))
 
 
-@pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
 @pytest.mark.parametrize("op", ["encode", "apply"])
 def test_device_engine_call_observes_its_five_phases_once(
-        name, op, rng, monkeypatch, every_call_phased):
+        program, op, rng, monkeypatch, every_call_phased):
+    """Through either program plan hands the one device engine."""
     monkeypatch.delenv("CUBEFS_TRACE", raising=False)
-    before = _phase_counts(name)
-    got, want = _device_engine_call(name, op, rng)
+    before = _phase_counts("tpu")
+    got, want = _device_engine_call(op, rng)
     assert np.array_equal(got, want)  # bit-identical to the table path
-    assert _grew(before, _phase_counts(name)) == {(op, p): 1 for p in PHASES}
+    assert _grew(before, _phase_counts("tpu")) == {
+        (op, p): 1 for p in PHASES}
 
 
-@pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
-def test_trace_door_off_is_the_bare_engine_call(name, rng, monkeypatch,
+def test_trace_door_off_is_the_bare_engine_call(program, rng, monkeypatch,
                                                 every_call_phased):
     """CUBEFS_TRACE=0: no phase sample, no extra wait — the same bytes."""
-    got_on, want = _device_engine_call(name, "encode", rng)
+    got_on, want = _device_engine_call("encode", rng)
     monkeypatch.setenv("CUBEFS_TRACE", "0")
-    before = _phase_counts(name)
+    before = _phase_counts("tpu")
     got_off, _ = _device_engine_call(
-        name, "encode", np.random.default_rng(0xC0DEC))
-    assert _phase_counts(name) == before
+        "encode", np.random.default_rng(0xC0DEC))
+    assert _phase_counts("tpu") == before
     assert np.array_equal(got_off, got_on) and np.array_equal(got_off, want)
 
 
@@ -506,7 +538,7 @@ def test_coalesced_step_observes_one_gather_and_names_its_engine(
     from cubefs_tpu.codec.batcher import BatchCodec
     from cubefs_tpu.utils import trace as tracelib
 
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     datas = [rng.integers(0, 256, (1, 6, 2048), dtype=np.uint8)
              for _ in range(3)]
     bc.submit_encode("tpu", datas[0], 3)  # compiles the 1-stripe program
@@ -590,6 +622,8 @@ def test_cli_codec_view_reads_decode_legs_from_the_step_counter(rng):
 
     before = legs()
     rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:1]
-    BatchCodec(enabled=True).submit_apply(
-        "numpy", rows, rng.integers(0, 256, (2, 6, 512), dtype=np.uint8))
-    assert _grew(before, legs()) == {"numpy-xor": 1}  # the XOR door's leg
+    bc = BatchCodec()
+    x = rng.integers(0, 256, (2, 6, 512), dtype=np.uint8)
+    bc.submit_apply("numpy", rows, x)  # the reference, served as named
+    bc.submit_apply("numpy-xor", rows, x)
+    assert _grew(before, legs()) == {"numpy": 1, "numpy-xor": 1}

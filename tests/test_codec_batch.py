@@ -1,7 +1,6 @@
 """Batched codec admission layer (codec/batcher.py): bit-identity,
-coalescing, per-submission error fan-back, backpressure, the
-CUBEFS_CODEC_BATCH A/B door, step-size bounds, the AdmittedEngine
-facade, and the CodecService RPC arg validation that guards it.
+coalescing, per-submission error fan-back, backpressure, step-size
+bounds, the AdmittedEngine facade, and the CodecService RPC arg validation that guards it.
 
 Every test constructs a PRIVATE BatchCodec so nothing leaks into the
 process-wide DEFAULT instance other callers share."""
@@ -56,7 +55,7 @@ def test_concurrent_submits_bit_identical(rng):
     """32 synthetic PUT/repair submitters race one BatchCodec; every
     result matches the raw single-submission engine output byte for
     byte (GF math has no rounding; coalescing must be invisible)."""
-    bc = _CountingCodec(enabled=True)
+    bc = _CountingCodec()
     eng = get_engine("numpy")
     n, m, s = 6, 3, 128
     inputs = [_stripes(rng, 2, n, s) for _ in range(32)]
@@ -89,7 +88,7 @@ def test_concurrent_submits_bit_identical(rng):
 def test_async_pipeline_coalesces_into_one_step(rng):
     """Pipelined async submissions park until the first collector
     drains them — 10 submissions, ONE device step, bit-identical."""
-    bc = _CountingCodec(enabled=True)
+    bc = _CountingCodec()
     n, m, s = 4, 2, 64
     inputs = [_stripes(rng, 3, n, s) for _ in range(10)]
     futs = [bc.submit_encode_async("numpy", d, m) for d in inputs]
@@ -105,7 +104,7 @@ def test_async_pipeline_coalesces_into_one_step(rng):
 
 def test_mixed_geometry_does_not_coalesce(rng):
     """Different (n, m, s) keys never share a device step."""
-    bc = _CountingCodec(enabled=True)
+    bc = _CountingCodec()
     a = bc.submit_encode_async("numpy", _stripes(rng, 1, 4, 64), 2)
     b = bc.submit_encode_async("numpy", _stripes(rng, 1, 6, 64), 3)
     a.result()
@@ -119,7 +118,7 @@ def test_midbatch_bad_submission_fails_alone(rng):
     """A malformed submission inside a drained batch is rejected back
     to exactly its submitter; batch-mates proceed bit-identically —
     the admission layer must never amplify one caller's bug."""
-    bc = _CountingCodec(enabled=True)
+    bc = _CountingCodec()
     n, m, s = 5, 2, 96
     good = [_stripes(rng, 2, n, s) for _ in range(8)]
     futs = [bc.submit_encode_async("numpy", d, m) for d in good[:4]]
@@ -144,7 +143,7 @@ def test_engine_failure_fans_back_to_whole_step(rng):
         def _engine_call(self, key, coeff, arr):
             raise RuntimeError("DEVICE_LOST mid step")
 
-    bc = _Dying(enabled=True)
+    bc = _Dying()
     futs = [bc.submit_encode_async("numpy", _stripes(rng, 1, 4, 32), 2)
             for _ in range(3)]
     for f in futs:
@@ -155,7 +154,7 @@ def test_engine_failure_fans_back_to_whole_step(rng):
 # ---------------- backpressure ----------------
 
 def test_backpressure_bounds_pending_stripes(rng):
-    bc = _BlockingCodec(enabled=True, max_pending=4)
+    bc = _BlockingCodec(max_pending=4)
     first = bc.submit_encode_async("numpy", _stripes(rng, 4, 4, 32), 2)
     collector = threading.Thread(target=first.result)
     collector.start()
@@ -177,35 +176,15 @@ def test_idle_submitter_never_parks_itself(rng):
     """The backpressure loop must only block when a drain in flight
     will free space — a lone submitter over the bound proceeds (it IS
     the drainer)."""
-    bc = _CountingCodec(enabled=True, max_pending=1)
+    bc = _CountingCodec(max_pending=1)
     out = bc.submit_encode("numpy", _stripes(rng, 4, 4, 32), 2)
     assert out.shape == (4, 2, 32)
-
-
-# ---------------- A/B door ----------------
-
-def test_disabled_door_bypasses_queues(rng):
-    bc = _CountingCodec(enabled=False)
-    d = _stripes(rng, 2, 4, 64)
-    out = bc.submit_encode("numpy", d, 2)
-    assert np.array_equal(out, get_engine("numpy").encode_parity(d, 2))
-    fut = bc.submit_encode_async("numpy", d, 2)
-    assert fut.done  # inline-resolved: no parked state to collect from
-    assert np.array_equal(fut.result(), out)
-    assert bc.steps == 2 and not bc._queues
-
-
-def test_env_door(rng, monkeypatch):
-    monkeypatch.setenv("CUBEFS_CODEC_BATCH", "0")
-    assert BatchCodec().enabled is False
-    monkeypatch.setenv("CUBEFS_CODEC_BATCH", "1")
-    assert BatchCodec().enabled is True
 
 
 # ---------------- step-size bounds ----------------
 
 def test_max_batch_splits_steps(rng):
-    bc = _CountingCodec(enabled=True, max_batch=4)
+    bc = _CountingCodec(max_batch=4)
     futs = [bc.submit_encode_async("numpy", _stripes(rng, 3, 4, 32), 2)
             for _ in range(3)]
     for f in futs:
@@ -216,8 +195,7 @@ def test_max_batch_splits_steps(rng):
 
 def test_max_step_bytes_splits_steps(rng):
     n, s = 4, 64
-    bc = _CountingCodec(enabled=True,
-                        max_step_bytes=2 * n * s)  # two stripes of input
+    bc = _CountingCodec(max_step_bytes=2 * n * s)  # two stripes of input
     futs = [bc.submit_encode_async("numpy", _stripes(rng, 2, n, s), 2)
             for _ in range(4)]
     for f in futs:
@@ -228,7 +206,7 @@ def test_max_step_bytes_splits_steps(rng):
 # ---------------- AdmittedEngine facade ----------------
 
 def test_admitted_engine_shapes(rng):
-    eng = AdmittedEngine(_CountingCodec(enabled=True), "numpy")
+    eng = AdmittedEngine(_CountingCodec(), "numpy")
     raw = get_engine("numpy")
     rows = np.ascontiguousarray(
         np.arange(1, 13, dtype=np.uint8).reshape(2, 6))
@@ -258,7 +236,7 @@ def test_admit_rejects_unknown_engine():
 
 
 def test_submit_shape_validation(rng):
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     with pytest.raises(ValueError, match=r"\(B, N, S\)"):
         bc.submit_encode("numpy", np.zeros((4, 32), dtype=np.uint8), 2)
     with pytest.raises(ValueError, match=r"\(B, C, S\)"):
@@ -270,7 +248,7 @@ def test_submit_shape_validation(rng):
 
 def test_step_metrics_account_per_swap(rng):
     sub0 = metrics.codec_batch_submissions.value(op="encode")
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     futs = [bc.submit_encode_async("numpy", _stripes(rng, 2, 4, 32), 2)
             for _ in range(5)]
     for f in futs:
@@ -287,7 +265,7 @@ def test_dp_sharded_step_bit_identical(rng):
     """A drained step wide enough for the mesh splits dp-wise across
     the 8 virtual devices and stays bit-identical (the MULTICHIP_r06
     recipe). `tpu` here is the jax engine on the CPU backend."""
-    bc = _CountingCodec(enabled=True)
+    bc = _CountingCodec()
     bc.dp_min_bytes = 0  # every step qualifies regardless of size
     dp0 = sum(v for _, v in metrics.codec_batch_dp_steps.samples())
     d = _stripes(rng, 8, 6, 256)
@@ -303,7 +281,7 @@ def test_dp_sharded_step_bit_identical(rng):
 
 def test_dp_disabled_by_door(rng, monkeypatch):
     monkeypatch.setenv("CUBEFS_CODEC_DP", "0")
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     assert bc.dp_enabled is False
     assert bc._maybe_dp("tpu", None,
                         _stripes(rng, 8, 6, 256), 3) is None
@@ -380,7 +358,7 @@ def test_encoder_encode_async_matches_sync(rng):
     from cubefs_tpu.codec.codemode import CodeMode
     from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
 
-    bc = _CountingCodec(enabled=True, max_wait_ms=1.0)
+    bc = _CountingCodec(max_wait_ms=1.0)
     enc = new_encoder(CodecConfig(mode=CodeMode.EC6P3, engine="numpy"))
     enc.engine = AdmittedEngine(bc, "numpy")
     stripes = np.zeros((2, enc.t.total, 64), dtype=np.uint8)
@@ -401,7 +379,7 @@ def test_lrc_encode_async_matches_sync(rng):
     from cubefs_tpu.codec.codemode import CodeMode
     from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
 
-    bc = _CountingCodec(enabled=True, max_wait_ms=1.0)
+    bc = _CountingCodec(max_wait_ms=1.0)
     enc = new_encoder(CodecConfig(mode=CodeMode.EC4P4L2, engine="numpy"))
     enc.engine = AdmittedEngine(bc, "numpy")
     stripes = np.zeros((2, enc.t.total, 32), dtype=np.uint8)
@@ -413,15 +391,17 @@ def test_lrc_encode_async_matches_sync(rng):
     assert enc.verify(out)
 
 
-@pytest.mark.parametrize("door", [True, False], ids=["admitted", "inline"])
+@pytest.mark.parametrize("admitted", [True, False], ids=["admitted", "raw"])
 @pytest.mark.parametrize("mode", ["EC6P3", "EC4P4L2", "EC4P4MSR"])
-def test_encode_rows_async_returns_the_parity_rows(rng, mode, door):
+def test_encode_rows_async_returns_the_parity_rows(rng, mode, admitted):
     """encode_rows_async(data rows).wait() is the parity rows a blocking
     encode() of the whole stripe lands, RS, LRC and MSR; the step reads
     the caller's array itself (no copy on the way in) and leaves it as
-    it was."""
+    it was. An encoder over a raw engine (no admission surface) has
+    encoded inline by the time the handle is back."""
     from cubefs_tpu.codec.codemode import CodeMode
     from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
+    from cubefs_tpu.codec.engine import NumpyEngine
 
     seen = []
 
@@ -430,9 +410,14 @@ def test_encode_rows_async_returns_the_parity_rows(rng, mode, door):
             seen.append(arr)
             return super()._engine_call(key, coeff, arr)
 
-    bc = Seeing(enabled=door, max_wait_ms=1.0)
+    class SeeingRaw(NumpyEngine):
+        def matrix_apply(self, coeff, shards):
+            seen.append(shards)
+            return super().matrix_apply(coeff, shards)
+
     enc = new_encoder(CodecConfig(mode=CodeMode[mode], engine="numpy"))
-    enc.engine = AdmittedEngine(bc, "numpy")
+    enc.engine = (AdmittedEngine(Seeing(max_wait_ms=1.0), "numpy")
+                  if admitted else SeeingRaw())
     t = enc.t
     size = 12 * getattr(enc, "alpha", 1)
     data = _stripes(rng, 3, t.n, size)
@@ -443,26 +428,10 @@ def test_encode_rows_async_returns_the_parity_rows(rng, mode, door):
 
     before = data.copy()
     pending = enc.encode_rows_async(data)
-    assert pending.resolved is (not door)
+    assert pending.resolved is (not admitted)
     parity = pending.wait()
     assert parity.shape == (3, t.total - t.n, size)
     assert np.array_equal(parity, ref[:, t.n:, :])
     assert np.array_equal(data, before)
     assert np.shares_memory(seen[0], data)
     assert pending.wait() is parity  # collected once, kept
-
-
-def test_encode_async_disabled_door_is_inline(rng):
-    """With the batcher door closed the handle degrades to an inline
-    encode: already resolved before wait()."""
-    from cubefs_tpu.codec.codemode import CodeMode
-    from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
-
-    bc = _CountingCodec(enabled=False)
-    enc = new_encoder(CodecConfig(mode=CodeMode.EC6P3, engine="numpy"))
-    enc.engine = AdmittedEngine(bc, "numpy")
-    stripes = np.zeros((1, enc.t.total, 32), dtype=np.uint8)
-    stripes[:, : enc.t.n, :] = _stripes(rng, 1, enc.t.n, 32)
-    pending = enc.encode_async(stripes)
-    assert pending.resolved  # inline path: nothing left in flight
-    assert enc.verify(pending.wait())

@@ -239,7 +239,7 @@ def test_a_first_lookup_inside_a_trace_leaves_no_tracer_behind(rng):
 def test_admission_drops_a_queue_that_is_empty_and_idle(rng):
     """A step still has one matrix, so the key still carries it; but 256
     repair matrices must not leave 256 queue objects behind."""
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     t = cm.tactic(cm.CodeMode.EC12P4)
     x = rng.integers(0, 256, (2, 12, 64), dtype=np.uint8)
     for bad in range(16):
@@ -263,7 +263,7 @@ def test_concurrent_submitters_of_many_matrices_all_get_their_answer(rng):
     thirty matrices each: every result is its own matrix's."""
     from concurrent.futures import ThreadPoolExecutor
 
-    bc = BatchCodec(enabled=True)
+    bc = BatchCodec()
     t = cm.tactic(cm.CodeMode.EC12P4)
     x = rng.integers(0, 256, (1, 12, 48), dtype=np.uint8)
     mats = [worker_rows(t, bad, other) for bad in range(16)
@@ -328,26 +328,27 @@ def test_decode_program_is_compiled_with_the_geometrys_encode(
     assert batcher.DEFAULT._queues == {}
 
 
-@pytest.mark.parametrize("name", ["tpu", "tpu-pallas"])
 def test_a_zero_warm_up_covers_real_arrays_bare_and_phased(
-        name, rng, compiles, monkeypatch):
+        program, rng, compiles, monkeypatch):
     """Set-up warms with zeros, through whichever of the engine call's
     two forms comes up; the window brings real bytes (views, copies)
     through both. Nothing may compile then — on the chip one
     ``convert_element_type`` of a host array did, before the fused
     program took its input with ``jnp.asarray`` (PERF.md section 6,
     PR 28)."""
-    monkeypatch.setattr(pallas_gf, "DEFAULT_TILE", 256)
-    eng = get_engine(name)
+    eng = get_engine("tpu")
     monkeypatch.setattr(eng, "_phase_due", 0.0, raising=False)
-    eng.encode_parity(np.zeros((4, 12, 1000), dtype=np.uint8), 4)
+    # a process plans a shape once, so its decode is readied once: each
+    # program gets a shard size of its own here
+    s = {"bits": 1000, "fused": 1003}[program]
+    eng.encode_parity(np.zeros((4, 12, s), dtype=np.uint8), 4)
     del compiles[:]
     for trial in range(4):
         # the bare call first, then one taken apart into its phases
         monkeypatch.setattr(eng, "_phase_due",
                             0.0 if trial % 2 else float("inf"),
                             raising=False)
-        wide = rng.integers(0, 256, (4, 16, 1000), dtype=np.uint8)
+        wide = rng.integers(0, 256, (4, 16, s), dtype=np.uint8)
         data = wide[:, :12] if trial < 2 else np.ascontiguousarray(
             wide[:, :12])
         assert np.array_equal(eng.encode_parity(data, 4),
@@ -357,6 +358,34 @@ def test_a_zero_warm_up_covers_real_arrays_bare_and_phased(
         assert np.array_equal(eng.matrix_apply(rows, data[:1]),
                               NUMPY.matrix_apply(rows, data[:1]))
     assert compiles == []
+
+
+def test_plan_hands_the_device_engine_the_fused_program_off_the_chip(
+        rng, monkeypatch):
+    """Where `serves_fused` says so, `plan` is plane-major and its
+    program the Pallas one — interpreted off the chip, so the one device
+    engine drives it here: encode, apply and the readied decode."""
+    from cubefs_tpu.codec import engine
+
+    monkeypatch.setattr(rs_kernel, "serves_fused", lambda coeff, s: True)
+    monkeypatch.setattr(pallas_gf, "DEFAULT_TILE", TILE)
+    s = 777  # three tiles and a pad; a size no other test uses
+    coeff = np.ascontiguousarray(gf256.parity_matrix(6, 3))
+    planes, program = rs_kernel.plan(coeff, (2, 6, s))
+    assert planes is True
+    assert program is pallas_gf._apply_fn(3, 6, (2, 6, s), TILE, True)
+    eng = engine.JaxEngine()
+    data = rng.integers(0, 256, (2, 6, s), dtype=np.uint8)
+    before = programs()
+    assert np.array_equal(eng.encode_parity(data, 3),
+                          NUMPY.encode_parity(data, 3))
+    # the encode's program was plan's above; its decode came with it
+    assert built_since(before) == {"gf256_apply": 1}
+    rows = rs_kernel.reconstruct_rows(6, 9, [0, 2, 3, 5, 6, 8],
+                                      list(range(6)))
+    assert np.array_equal(eng.matrix_apply(rows, data[:1]),
+                          NUMPY.matrix_apply(rows, data[:1]))
+    assert built_since(before) == {"gf256_apply": 1}
 
 
 def test_reconstruct_of_parity_rows_keeps_the_decode_shape(rng):

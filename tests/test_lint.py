@@ -305,6 +305,32 @@ def test_xorprog_fence_scope():
 
 # ---------------- suppressions ----------------
 
+def test_ops_layer_imports_nothing_from_the_codec_layer():
+    """cubefs_tpu/ops is below cubefs_tpu/codec: the kernels and their
+    dispatch know nothing of the engines that call them."""
+    import ast
+
+    pkg = ["cubefs_tpu", "ops"]
+    ops = os.path.join(core.REPO_ROOT, *pkg)
+    for name in sorted(os.listdir(ops)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(ops, name)) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                seen = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                base = pkg[:len(pkg) - node.level + 1] if node.level else []
+                mod = ".".join(base + ([node.module] if node.module else []))
+                seen = [mod] + [f"{mod}.{a.name}" for a in node.names]
+            else:
+                continue
+            assert not any(m == "cubefs_tpu.codec"
+                           or m.startswith("cubefs_tpu.codec.")
+                           for m in seen), (name, node.lineno, seen)
+
+
 def test_bare_allow_is_cfa001_and_does_not_suppress():
     mod = _module("allow_bare.py", "cubefs_tpu/fs/fx.py")
     lock = LockDisciplineChecker().check(mod)

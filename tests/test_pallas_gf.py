@@ -36,13 +36,3 @@ def test_pallas_batched_reconstruct(rng):
     got = np.asarray(pallas_gf.gf_matrix_apply_pallas(
         rows, shards[:, present[:n]], tile=256))
     assert np.array_equal(got, shards[:, bad])
-
-
-def test_pallas_engine_registered():
-    from cubefs_tpu.codec.engine import get_engine
-
-    eng = get_engine("tpu-pallas")
-    assert eng.name == "tpu-pallas"
-    data = np.arange(6 * 256, dtype=np.uint8).reshape(6, 256)
-    parity = eng.encode_parity(data, 3)
-    assert np.array_equal(parity, gf256.gf_matmul(gf256.parity_matrix(6, 3), data))

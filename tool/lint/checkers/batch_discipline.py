@@ -2,11 +2,10 @@
 
 codec/batcher.py is the single admission surface for device math: it
 coalesces concurrent stripes into device-sized steps, meters occupancy
-and admission wait, applies bounded-queue backpressure, and keeps the
-CUBEFS_CODEC_BATCH A/B door honest. A blob-plane module that grabs a
-raw engine handle and dispatches on it silently opts its stripes out of
-all of that — each call is its own device step, invisible to the codec
-metrics and to backpressure. The regression shape:
+and admission wait, and applies bounded-queue backpressure. A
+blob-plane module that grabs a raw engine handle and dispatches on it
+silently opts its stripes out of all of that — each call is its own
+device step, invisible to the codec metrics and to backpressure. The regression shape:
 
   CFC001  blob-plane import of the raw engine layer (codec.engine /
           get_engine / engine_for) — holding a raw handle is how the

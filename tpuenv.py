@@ -4,7 +4,7 @@ The tier-1 suite (testenv.py) and the multichip dry-run children
 (__graft_entry__.py) run on a virtual CPU mesh: JAX pinned to the CPU
 backend with N host devices. This module is the one definition of that
 environment. The chip itself is reached only by a process that asks for
-it (chip_smoke.py, bench.py, the deploy launcher's codec host).
+it (chip_smoke.py, cellbench, the deploy launcher's codec host).
 """
 
 from __future__ import annotations
