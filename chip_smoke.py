@@ -16,7 +16,9 @@ It PUTs and GETs every size class plus one LRC and one MSR object,
 checks a sample of stored stripes against the numpy table engine and
 zlib, breaks a disk (degraded GET, scheduler -> worker repair, rebuilt
 shards bit-identical), drives the sidecar RPCs and the fused CRC kernel,
-and then proves the device did the work: no engine quarantined, no
+PUTs and reads back seeded random sizes of every size class after the
+front door's `ready` (no program may be built after it), and then
+proves the device did the work: no engine quarantined, no
 matrix refused by the Pallas gate, every large-class step on the fused
 kernel (or, with several devices, dp steps holding data on every one).
 The first failed check ends the run non-zero; nothing is downgraded to
@@ -75,6 +77,7 @@ class Sizes:
     crc_block_len: int = 128 << 10
     crc_tiles: tuple[int, ...] = (128, 256, 512)
     two_loss: tuple[int, int] = (8, 699051)  # (stripes, shard bytes) a step
+    any_size: tuple[int, int] = (32, 16 << 20)  # (objects, largest bytes)
 
 
 FULL = Sizes()
@@ -82,7 +85,8 @@ TINY = Sizes(blob_size=1 << 20, put_threads=2,
              large=(2, (4 << 20) + 4099), mid=(2, (256 << 10) + 1001),
              small=(3, 10_007), special_bytes=300_007, ref_stripes=1,
              sidecar_shard=8192, crc_blocks=8, crc_block_len=8192,
-             crc_tiles=(8,), two_loss=(2, 300))
+             crc_tiles=(8,), two_loss=(2, 300),
+             any_size=(6, (4 << 20) + 8192))
 
 # what .gitignore lists: the only paths a run may create or change
 _IGNORED_DIRS = {".git", ".jax_cache", "chiprun_out", "__pycache__",
@@ -573,6 +577,52 @@ def phase_two_loss(dep: Deployment, sizes: Sizes, clock: CompileClock
             "smoke_wall_s": round(time.perf_counter() - t0, 3)}
 
 
+def phase_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
+                   ) -> dict:
+    """Objects of sizes nobody named: after the front door's `ready`
+    (every rung of the step-shape ladder its policies can reach, built
+    once), seeded random byte counts across the three size classes are
+    PUT and read back — and not one program may be built after it."""
+    from cubefs_tpu.codec import codemode as cm
+    from cubefs_tpu.utils import metrics
+
+    count, largest = sizes.any_size
+    built = lambda: sum(v for _, v in metrics.codec_programs.samples())
+    t0 = time.perf_counter()
+    before = built()
+    steps = dep.access.ready(largest)
+    ready_s = time.perf_counter() - t0
+    at_ready, compiles = built(), clock.compiles
+    rng = np.random.default_rng([SEED, 34])
+    # a third of the objects in each size class, log-uniform inside it
+    bounds = [4096] + [p.max_size for p in dep.access.cfg.policies[:2]] \
+        + [largest]
+    modes = set()
+    for i in range(count):
+        lo, hi = bounds[i % 3], bounds[i % 3 + 1]
+        size = int(np.exp(rng.uniform(np.log(lo + 1), np.log(hi))))
+        data = payload(5, i, size)
+        loc = dep.access.put(data)
+        modes.add(cm.CodeMode(loc.codemode).name)
+        if dep.access.get(loc) != data:
+            raise RuntimeError(f"GET of a {size} B object differs from "
+                               f"what was PUT")
+    if built() != at_ready or clock.compiles != compiles:
+        raise RuntimeError(
+            f"{built() - at_ready} codec programs built and "
+            f"{clock.compiles - compiles} programs compiled after ready, "
+            f"by {count} PUTs and GETs of sizes up to {largest} B")
+    if len(modes) != 3:
+        raise RuntimeError(f"the sizes reached codemodes {sorted(modes)}, "
+                           f"not all three size classes")
+    return {"ok": True, "objects": count, "largest_bytes": largest,
+            "codemodes": sorted(modes), "ready_steps": int(steps),
+            "programs_built_at_ready": int(at_ready - before),
+            "programs_built_after_ready": 0,
+            "ready_wall_s": round(ready_s, 3),
+            "smoke_wall_s": round(time.perf_counter() - t0, 3)}
+
+
 def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
     """Right answers are not enough: show where they were computed."""
     from cubefs_tpu.codec import engine
@@ -646,6 +696,7 @@ def run(sizes: Sizes, workdir: str, device_checks: bool) -> dict:
         phases["break_repair"] = phase_break_repair(dep, sizes, objects)
         phases["sidecar"] = phase_sidecar(dep, sizes)
         phases["two_loss"] = phase_two_loss(dep, sizes, clock)
+        phases["any_size"] = phase_any_size(dep, sizes, clock)
         phases["device_proof"] = phase_device_proof(
             device["count"], device_checks)
     finally:

@@ -133,26 +133,25 @@ class AccessHandler:
         # also coalesces with concurrent PUTs/repairs of the same
         # geometry, codec/batcher.py) runs while this request does its
         # allocation round-trips, instead of starting after them.
-        # The payload is copied once, into the (blobs, n, S) array the
-        # step takes as it is. A reused array holds another PUT's bytes:
-        # every pad byte (a blob's tail, a short last blob's rest) is
-        # zeroed here, so a stored shard never depends on it.
+        # The payload is copied once, into the (blobs, n, width) array
+        # the step takes as it is: rows of S bytes of shard, built at
+        # the width rung their step runs at (enc.row_width). A reused
+        # array holds another PUT's bytes: every pad byte (a blob's
+        # tail, a short last blob's rest, the columns past S) is zeroed
+        # here, so a stored shard never depends on it — and a stored
+        # shard is rows[i, k, :S], never the rung's pad.
         with tracelib.stage("stripe_fill"):
             blob_size = self.cfg.blob_size
             n_blobs = -(-len(data) // blob_size)
             shard_size = enc.shard_size(min(len(data), blob_size))
-            rows = self._take_stripe_rows((n_blobs, t.n, shard_size))
-            src = np.frombuffer(data, dtype=np.uint8)
-            flat = rows.reshape(n_blobs, -1)
-            for i in range(n_blobs):
-                blob = src[i * blob_size : (i + 1) * blob_size]
-                flat[i, : blob.size] = blob
-                flat[i, blob.size :] = 0
+            rows = self._take_stripe_rows(
+                (n_blobs, t.n, enc.row_width(shard_size)))
+            fill_stripe_rows(rows, data, blob_size, shard_size)
         # the enqueue (an engine without an admission surface encodes
         # inline here)
         with tracelib.stage("encode_submit"):
             encode_admitted = time.monotonic()
-            pending = enc.encode_rows_async(rows)
+            pending = enc.encode_rows_async(rows, shard_size)
 
         with tracelib.stage("bid_alloc"):
             if self.proxy is not None:  # alloc cache: no per-put cm trip
@@ -186,7 +185,7 @@ class AccessHandler:
             for i in range(n_blobs):
                 bid = min_bid + i
                 for u in vol.units:
-                    shard = (rows[i, u.index] if u.index < t.n
+                    shard = (rows[i, u.index, :shard_size] if u.index < t.n
                              else parity[i, u.index - t.n])
                     futures.append(
                         self._submit(self._write_shard, vol, u, bid, shard)
@@ -230,6 +229,26 @@ class AccessHandler:
                           blob_size=blob_size)],
             crc=crc,
         )
+
+    def ready(self, max_object_bytes: int) -> int:
+        """What a deployment does once at start-up, from what it knows:
+        the codemodes its policies serve and its largest object. Every
+        program a PUT of 1..`max_object_bytes` bytes (alone or met by
+        others in a codec step) or a degraded GET of one can ask the
+        device for is built here, so none is compiled inside a request.
+        Never implied by construction; returns the number of steps."""
+        blob_size = self.cfg.blob_size
+        steps = 0
+        for p in self.cfg.policies:
+            lo = max(1, p.min_size)
+            hi = min(p.max_size, max_object_bytes)
+            if not p.enable or lo > hi:
+                continue
+            enc = self._encoder(int(cm.CodeMode[p.mode_name]))
+            # a PUT's blobs all take the first blob's shard size
+            steps += enc.ready(min(lo, blob_size), min(hi, blob_size),
+                               stripes=-(-hi // blob_size))
+        return steps
 
     def _take_stripe_rows(self, shape: tuple) -> np.ndarray:
         """An uninitialised uint8 array of `shape`: the newest of that
@@ -561,6 +580,25 @@ class AccessHandler:
         self.delete(Location.from_dict(args["location"]),
                     tenant=args.get("tenant"))
         return {}
+
+
+def fill_stripe_rows(rows: np.ndarray, data: bytes, blob_size: int,
+                     shard_size: int) -> None:
+    """Lay `data` into `rows` (blobs, n, width >= shard_size): blob i
+    row-major over the first `shard_size` columns of stripe i, every
+    other byte of the array zero."""
+    src = np.frombuffer(data, dtype=np.uint8)
+    if shard_size < rows.shape[2]:
+        rows[:, :, shard_size:] = 0
+    for i in range(rows.shape[0]):
+        blob = src[i * blob_size : (i + 1) * blob_size]
+        full, rest = divmod(blob.size, shard_size)
+        stripe = rows[i, :, :shard_size]
+        stripe[:full] = blob[: full * shard_size].reshape(full, shard_size)
+        if full < rows.shape[1]:
+            stripe[full, :rest] = blob[full * shard_size :]
+            stripe[full, rest:] = 0
+            stripe[full + 1 :] = 0
 
 
 NodePool = rpc.NodePool  # canonical home: cubefs_tpu/utils/rpc.py

@@ -272,13 +272,21 @@ class RepairWorker:
             out_pos = wanted_out.index(bad_sub)
             for start in range(0, len(group), self.batch_stripes):
                 chunk = group[start : start + self.batch_stripes]
+                msr_sub = t.is_msr() and bad_sub < total_code
                 with tracelib.stage("decode_stack"):
-                    batch = np.stack([
-                        np.stack([np.frombuffer(s, dtype=np.uint8)
-                                  for s in shards[:n_solve]])
-                        for _, shards in chunk
-                    ])  # (B, n_solve, size)
-                if t.is_msr() and bad_sub < total_code:
+                    # survivors land once, in the array the step takes
+                    # as it is: at the width rung, zeros past `size`
+                    # (MSR rows are cut into sub-shards first, so theirs
+                    # stay `size` wide and the batcher pads them)
+                    wide = size if msr_sub else rs_kernel.rung_width(size)
+                    batch = np.empty((len(chunk), n_solve, wide),
+                                     dtype=np.uint8)
+                    batch[:, :, size:] = 0
+                    for b, (_, shards) in enumerate(chunk):
+                        for r, shard in enumerate(shards[:n_solve]):
+                            batch[b, r, :size] = np.frombuffer(
+                                shard, dtype=np.uint8)
+                if msr_sub:
                     if size % t.alpha:
                         raise RuntimeError(
                             f"shard size {size} not divisible by "
@@ -288,7 +296,8 @@ class RepairWorker:
                     recovered = self.codec.matrix_apply(rows, sub).reshape(
                         len(chunk), len(wanted_out), size)
                 else:
-                    recovered = self.codec.matrix_apply(rows, batch)
+                    recovered = self.codec.matrix_apply(rows, batch,
+                                                        width=size)
                 with tracelib.stage("decode_verify"):
                     for (bid, shards), rec in zip(chunk, recovered):
                         if len(subs) > n_solve:

@@ -6,7 +6,10 @@ An engine call costs milliseconds of host time whatever it carries
 and PERF.md section 5), and the blob plane batches only *within* one PUT. This module is the admission
 layer in between, and the only way blob-plane code reaches an engine:
 every `encode_parity` / `matrix_apply` submission with compatible geometry
-``(op, n, m, shard_size)`` parks in a per-geometry queue, and whichever
+``(op, n, m, width rung)`` parks in a per-geometry queue — the rung of
+`ops/rs_kernel.py`'s ladder that holds its shard size, so PUTs of
+different sizes meet in one queue and a step's shape is one a program
+was built for before the first request — and whichever
 submitter finds the queue idle drains it as ONE engine call — the same
 first-caller-drains pattern the raft proposal batcher uses for group
 commit (parallel/raft.py): the step's duration itself is the batching
@@ -45,10 +48,11 @@ import time
 
 import numpy as np
 
-from ..ops import progcache
+from ..ops import progcache, rs_kernel
 from ..utils import metrics
 from ..utils import trace as tracelib
-from .engine import Engine, _dispatch, engine_for, get_engine
+from .engine import (Engine, _dead_engines, _dispatch, _drilled_dead,
+                     engine_for, get_engine)
 
 _log = logging.getLogger("cubefs.codec")
 
@@ -76,12 +80,15 @@ class CodecFuture:
     never need one (Event allocation and signalling are the admission
     layer's hottest per-submission costs)."""
 
-    __slots__ = ("arr", "stripes", "value", "exc", "done", "event",
-                 "enq_t", "ref", "_batcher", "_key")
+    __slots__ = ("arr", "stripes", "width", "value", "exc", "done",
+                 "event", "enq_t", "ref", "_batcher", "_key")
 
-    def __init__(self, batcher: "BatchCodec", key: tuple, arr: np.ndarray):
+    def __init__(self, batcher: "BatchCodec", key: tuple, arr: np.ndarray,
+                 width: int):
         self.arr = arr
         self.stripes = int(arr.shape[0])
+        # payload columns: the rows handed back are [:, :, :width]
+        self.width = width
         self.value = None
         self.exc: BaseException | None = None
         self.done = False
@@ -147,14 +154,34 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
+# a gathered step's array above this size is kept for the next one
+SPARE_MIN_BYTES = 32 << 20
+_STEP_SERIES: dict[str, tuple] = {}
+
+
+def _step_series(op: str) -> tuple:
+    """(payload bytes, pad bytes, widths) of `op`'s steps, the label
+    lookups done once: this runs once a step."""
+    bound = _STEP_SERIES.get(op)
+    if bound is None:
+        bound = _STEP_SERIES[op] = (
+            metrics.codec_step_bytes.bind(op=op, kind="payload"),
+            metrics.codec_step_bytes.bind(op=op, kind="pad"),
+            metrics.codec_batch_widths.bind(op=op))
+    return bound
+
+
 class BatchCodec:
     """The submit surface. One instance per process is the norm
     (module-level DEFAULT below); tests construct private ones."""
 
-    def __init__(self, max_batch: int = 1024, max_wait_ms: float = 0.0,
+    def __init__(self, max_batch: int = rs_kernel.STEP_BATCH,
+                 max_wait_ms: float = 0.0,
                  max_pending: int = 4096,
                  max_step_bytes: int | None = None):
-        self.max_batch = max_batch  # stripes per step
+        # stripes per coalesced step: with max_step_bytes it bounds the
+        # rungs a step can reach, so the programs a geometry can ask for
+        self.max_batch = max_batch
         # drainer linger before the first swap (0: the step is the window)
         self.max_wait = max_wait_ms / 1e3
         # stripes parked across all queues before submitters block
@@ -172,39 +199,48 @@ class BatchCodec:
         self._pending = 0  # stripes parked across all queues
         self._n_busy = 0  # queues with a drain in flight
         self._dp_meshes: dict[int, object] = {}
+        # (rows, width rung) -> stripes a coalesced step may hold
+        self._caps: dict[tuple[int, int], int] = {}
+        self._spare: np.ndarray | None = None  # see _gather
 
     # ---------------- public submit surface ----------------
     def submit_encode(self, engine: str | None, data: np.ndarray,
                       n_parity: int, timeout: float = 120.0) -> np.ndarray:
         """(B, N, S) data -> (B, M, S) parity, coalesced with every
-        concurrent submission of the same (N, M, S, engine)."""
-        key, coeff, arr = self._prep_encode(engine, data, n_parity)
-        return self._enqueue(key, coeff, arr, timeout).result(timeout)
+        concurrent submission of the same (N, M, engine) whose S lies
+        in the same width rung."""
+        return self.submit_encode_async(
+            engine, data, n_parity, timeout).result(timeout)
 
     def submit_apply(self, engine: str | None, coeff: np.ndarray,
                      shards: np.ndarray, timeout: float = 120.0
                      ) -> np.ndarray:
         """(R, C) GF matrix x (B, C, S) shards -> (B, R, S), coalesced
         with concurrent submissions sharing the identical matrix."""
-        key, coeff, arr = self._prep_apply(engine, coeff, shards)
-        return self._enqueue(key, coeff, arr, timeout).result(timeout)
+        return self.submit_apply_async(
+            engine, coeff, shards, timeout).result(timeout)
 
     def submit_encode_async(self, engine: str | None, data: np.ndarray,
-                            n_parity: int, timeout: float = 120.0
-                            ) -> CodecFuture:
+                            n_parity: int, timeout: float = 120.0,
+                            width: int | None = None) -> CodecFuture:
         """submit_encode that parks and returns immediately: collect
         with .result(). A caller pipelining K submissions before its
         first collect keeps K stripes continuously admitted — the
-        sleep/wake cycle per stripe disappears and step width rises."""
+        sleep/wake cycle per stripe disappears and step width rises.
+
+        ``width``: a caller that built ``data`` at its width rung
+        (rs_kernel.rung_width, zeros past its shard size) says how many
+        columns are payload: a step of that submission alone takes the
+        array as it is, and the rows come back ``[:, :, :width]``."""
         key, coeff, arr = self._prep_encode(engine, data, n_parity)
-        return self._enqueue(key, coeff, arr, timeout)
+        return self._enqueue(key, coeff, arr, timeout, width)
 
     def submit_apply_async(self, engine: str | None, coeff: np.ndarray,
-                           shards: np.ndarray, timeout: float = 120.0
-                           ) -> CodecFuture:
+                           shards: np.ndarray, timeout: float = 120.0,
+                           width: int | None = None) -> CodecFuture:
         """submit_apply that parks and returns immediately."""
         key, coeff, arr = self._prep_apply(engine, coeff, shards)
-        return self._enqueue(key, coeff, arr, timeout)
+        return self._enqueue(key, coeff, arr, timeout, width)
 
     # ---------------- admission ----------------
     def _prep_encode(self, engine, data, n_parity):
@@ -212,7 +248,7 @@ class BatchCodec:
         if data.ndim != 3:
             raise ValueError(f"submit_encode takes (B, N, S), got "
                              f"{data.shape}")
-        n, s = int(data.shape[1]), int(data.shape[2])
+        n, s = int(data.shape[1]), rs_kernel.rung_width(data.shape[2])
         return ("encode", engine or "", n, int(n_parity), s), None, data
 
     def _prep_apply(self, engine, coeff, shards):
@@ -221,12 +257,14 @@ class BatchCodec:
             raise ValueError(f"submit_apply takes (B, C, S), got "
                              f"{shards.shape}")
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
-        c, s = int(shards.shape[1]), int(shards.shape[2])
+        c, s = int(shards.shape[1]), rs_kernel.rung_width(shards.shape[2])
         return ("apply", engine or "", coeff.tobytes(), c, s), coeff, shards
 
     def _enqueue(self, key: tuple, coeff: np.ndarray | None,
-                 arr: np.ndarray, timeout: float) -> CodecFuture:
-        sub = CodecFuture(self, key, arr)
+                 arr: np.ndarray, timeout: float,
+                 width: int | None = None) -> CodecFuture:
+        sub = CodecFuture(self, key, arr, int(
+            arr.shape[2] if width is None else width))
         with self._lock:
             # backpressure: block only while a drain in flight will
             # free space — the submitter who finds everything idle
@@ -325,13 +363,15 @@ class BatchCodec:
         # admitted-stripe accounting lands here, once per swap — per-
         # submission counter locks are measurable at this call rate
         metrics.codec_batch_submissions.inc(total, op=op)
-        # input bytes per stripe are constant across the key (geometry
-        # is the key): encode (.., n, m, s) reads n*s, apply
-        # (.., coeff, c, s) reads c*s
-        per_stripe = (int(key[3]) if op == "apply" else int(key[2])) \
-            * int(key[4])
-        stripe_cap = min(self.max_batch,
-                         max(1, self.max_step_bytes // max(1, per_stripe)))
+        # the key carries the geometry: encode (.., n, m, rung), apply
+        # (.., coeff, c, rung); the cap is reckoned on the rung, the
+        # width every stripe of the step goes up at
+        geometry = (int(key[3]) if op == "apply" else int(key[2]),
+                    int(key[4]))
+        stripe_cap = self._caps.get(geometry)
+        if stripe_cap is None:
+            stripe_cap = self._caps[geometry] = rs_kernel.batch_cap(
+                *geometry, self.max_step_bytes, self.max_batch)
         try:
             step: list[CodecFuture] = []
             stripes = 0
@@ -361,11 +401,22 @@ class BatchCodec:
 
     def _one_step(self, key: tuple, coeff: np.ndarray | None,
                   step: list[CodecFuture]) -> None:
+        """One engine call at the smallest rung that holds the step: a
+        zeroed (B_rung, rows, S_rung) array with every submission's rows
+        copied in at its own width — unless the step is one submission
+        that is rung-shaped already (a PUT's data rows, a repair task's
+        survivors), which goes up as it is."""
         op = key[0]
         gather_t0 = time.perf_counter()
-        arr = (step[0].arr if len(step) == 1
-               else np.concatenate([s.arr for s in step], axis=0))
-        n_stripes = int(arr.shape[0])
+        first = step[0].arr
+        cols = int(first.shape[1])
+        n_stripes = sum(sub.stripes for sub in step)
+        rung_b, rung_s = rs_kernel.step_shape(cols, n_stripes, int(key[4]))
+        shape = (rung_b, cols, rung_s)
+        gathered = len(step) > 1 or first.shape != shape
+        arr = self._gather(step, shape) if gathered else first
+        payload = cols * sum(sub.stripes * sub.width for sub in step)
+        pad = arr.nbytes - payload
         wait_now = time.perf_counter()
         metrics.codec_batch_wait.observe_many(
             [wait_now - sub.enq_t for sub in step], op=op)
@@ -376,6 +427,8 @@ class BatchCodec:
             links=[s.ref for s in step if s.ref is not None])
         span.set_tag("stage", "codec_step").set_tag("op", op)
         span.set_tag("stripes", n_stripes)
+        span.set_tag("rung_b", shape[0]).set_tag("rung_s", shape[2])
+        span.set_tag("pad_bytes", pad)
         with span:
             try:
                 out, served = self._engine_call(key, coeff, arr)
@@ -384,22 +437,50 @@ class BatchCodec:
                     sub.resolve(None, e)
                 return
             span.set_tag("engine", served)
+        if gathered and arr.nbytes > SPARE_MIN_BYTES:
+            self._spare = arr  # the engine has its own copy by now
         tracelib.observe_stage("codec_step", span.path,
                                time.perf_counter() - wait_now)
         if tracelib.enabled():
             metrics.codec_engine_phase.observe(
-                wait_now - gather_t0 if len(step) > 1 else 0.0,
+                wait_now - gather_t0 if gathered else 0.0,
                 engine=served, op=op, phase="gather")
+            count_payload, count_pad, widths = _step_series(op)
+            count_payload(payload)
+            count_pad(pad)
+            widths.observe(len({sub.width for sub in step}))
         metrics.codec_batch_stripes.observe(n_stripes, op=op)
         off = 0
         for sub in step:  # resolve inlined: this is the hottest loop
             end = off + sub.stripes
-            sub.value = out[off:end]
+            sub.value = out[off:end, :, :sub.width]
             sub.done = True  # write order: done before the event read
             ev = sub.event
             if ev is not None:
                 ev.set()
             off = end
+
+    def _gather(self, step: list[CodecFuture], shape: tuple) -> np.ndarray:
+        """The step's rung-shaped array: every submission's rows at its
+        own width, zeros round them. A large one is kept for the next
+        step of its shape: each page of a fresh mapping is a fault at
+        first touch (~0.9 s for a repair step's 554 MB on the chip's
+        host, PERF.md section 6), whatever the memory bandwidth."""
+        with self._lock:
+            arr, self._spare = self._spare, None
+        kept = arr is not None and arr.shape == shape
+        if not kept:
+            arr = np.zeros(shape, dtype=np.uint8)
+        off = 0
+        for sub in step:
+            end, width = off + sub.stripes, sub.arr.shape[2]
+            arr[off:end, :, :width] = sub.arr
+            if kept:
+                arr[off:end, :, width:] = 0
+            off = end
+        if kept:
+            arr[off:] = 0
+        return arr
 
     # ---------------- device step ----------------
     def _engine_call(self, key: tuple, coeff: np.ndarray | None,
@@ -441,6 +522,8 @@ class BatchCodec:
         devs = jax.devices()
         if len(devs) < 2:
             return None
+        if name in _dead_engines or name in _drilled_dead():
+            return None  # a lost device serves no step, sharded or not
         try:
             if coeff is None:
                 from ..ops import gf256
@@ -527,8 +610,10 @@ class AdmittedEngine:
             self.label, data.reshape(-1, *data.shape[-2:]), n_parity)
         return out.reshape(*lead, *out.shape[-2:])
 
-    def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray
-                     ) -> np.ndarray:
+    def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray,
+                     width: int | None = None) -> np.ndarray:
+        """``width``: payload columns of a (B, C, S) array its caller
+        built at the width rung (submit_encode_async's contract)."""
         shards = np.asarray(shards)
         if shards.ndim < 2:
             raise ValueError(
@@ -537,7 +622,8 @@ class AdmittedEngine:
             return self.batcher.submit_apply(
                 self.label, coeff, shards[None])[0]
         if shards.ndim == 3:
-            return self.batcher.submit_apply(self.label, coeff, shards)
+            return self.batcher.submit_apply_async(
+                self.label, coeff, shards, width=width).result()
         lead = shards.shape[:-2]
         out = self.batcher.submit_apply(
             self.label, coeff, shards.reshape(-1, *shards.shape[-2:]))

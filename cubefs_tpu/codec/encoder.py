@@ -121,6 +121,39 @@ class Encoder:
         per = -(-data_len // self.t.n)
         return max(per, self.t.min_shard_size)
 
+    def row_width(self, shard_size: int) -> int:
+        """The width to build data rows of `shard_size` bytes at, zeros
+        past the shard: the rung their step runs at
+        (rs_kernel.rung_width), so encode_rows_async(rows, shard_size)
+        takes the array as it is. Stored shards stay `shard_size`."""
+        return rs_kernel.rung_width(shard_size)
+
+    def ready(self, lo: int, hi: int, stripes: int = 1) -> int:
+        """Build every program that encodes of blobs of `lo`..`hi`
+        payload bytes, up to `stripes` blobs a call, can ask the
+        device for: one zero step through the encoder's own door at
+        every rung of the ladder that its batcher's bounds can reach
+        (a device engine builds a rung's decode program with its
+        encode). What a deployment does once, before its first request;
+        returns the number of steps."""
+        batcher = self._batcher()
+        if batcher is None:
+            return 0
+        cols, lo_w, hi_w = self._step_geometry(lo, hi)
+        shapes = rs_kernel.ladder(cols, lo_w, hi_w, batcher.max_step_bytes,
+                                  batcher.max_batch, stripes)
+        for b, width in shapes:
+            self._ready_step(b, width)
+        return len(shapes)
+
+    def _step_geometry(self, lo: int, hi: int) -> tuple[int, int, int]:
+        """(rows of a step, its least width, its largest)."""
+        return self.t.n, self.shard_size(lo), self.shard_size(hi)
+
+    def _ready_step(self, b: int, width: int) -> None:
+        self.encode_rows_async(
+            np.zeros((b, self.t.n, width), dtype=np.uint8)).wait()
+
     # -- reference Encoder interface ------------------------------------
     def encode(self, shards: np.ndarray) -> np.ndarray:
         """Fill parity rows from data rows; returns the same array."""
@@ -129,20 +162,31 @@ class Encoder:
         shards[..., n:, :] = self._finish_rows(shards[..., :n, :], None, 0.0)
         return shards
 
-    def encode_rows_async(self, data: np.ndarray) -> PendingEncode:
+    def encode_rows_async(self, data: np.ndarray,
+                          shard_size: int | None = None) -> PendingEncode:
         """Admit the encode of C-contiguous data rows (B, n, S) as they
         are and return immediately; wait() returns the parity rows
         (B, total - n, S). With a batcher-admitted engine the device
         step runs (coalesced with concurrent submissions) while the
         caller overlaps allocation or IO; engines without an admission
         surface degrade to an inline encode. The caller keeps `data`
-        unchanged until wait() has returned."""
+        unchanged until wait() has returned.
+
+        `shard_size`: the rows were built row_width(shard_size) wide,
+        zeros past the shard; the parity rows come back `shard_size`
+        wide."""
         data = self._check(data, total=self.t.n)
         if data.ndim != 3:
             raise ECError(f"data rows must be (B, n, S), got {data.shape}")
-        fut = self._submit_rows(data)
+        if shard_size is None:
+            shard_size = int(data.shape[2])
+        elif data.shape[2] < shard_size:
+            raise ECError(f"rows of {shard_size} B shards are "
+                          f"{data.shape[2]} wide")
+        fut = self._submit_rows(data, shard_size)
         if fut is None:
-            return PendingEncode(self._finish_rows(data, None, 0.0))
+            return PendingEncode(
+                self._finish_rows(data, None, 0.0)[..., :shard_size])
         return PendingEncode(
             None, lambda timeout: self._finish_rows(data, fut, timeout), fut)
 
@@ -166,14 +210,15 @@ class Encoder:
         """The engine's admission surface; None for a raw engine."""
         return getattr(self.engine, "batcher", None)
 
-    def _submit_rows(self, data: np.ndarray):
+    def _submit_rows(self, data: np.ndarray, shard_size: int):
         """The one way from an encoder to the batcher: the future of
         the step that needs `data`, or None where there is no step to
         wait for (no parity, or no admission surface)."""
         batcher = self._batcher()
         if batcher is None or not self.t.m:
             return None
-        return batcher.submit_encode_async(self.engine.label, data, self.t.m)
+        return batcher.submit_encode_async(self.engine.label, data, self.t.m,
+                                           width=shard_size)
 
     def _finish_rows(self, data: np.ndarray, fut, timeout: float
                      ) -> np.ndarray:
@@ -186,8 +231,8 @@ class Encoder:
         return self._verified(data, parity)
 
     def _verified(self, data: np.ndarray, parity: np.ndarray) -> np.ndarray:
-        if self.cfg.enable_verify and not self.verify(
-                np.concatenate([data, parity], axis=-2)):
+        if self.cfg.enable_verify and not self.verify(np.concatenate(
+                [data[..., :parity.shape[-1]], parity], axis=-2)):
             raise VerifyError("parity verify failed after encode")
         return parity
 
@@ -289,7 +334,20 @@ class MsrEncoder(Encoder):
         t = self.t
         return rs_kernel.msr_encode_rows(t.n, t.n + t.m, t.d)
 
-    def _submit_rows(self, data: np.ndarray):
+    def row_width(self, shard_size: int) -> int:
+        """Rows are cut into alpha sub-shards before the step, so they
+        stay `shard_size` wide; the batcher pads the sub-shards."""
+        return shard_size
+
+    def _step_geometry(self, lo: int, hi: int) -> tuple[int, int, int]:
+        return (self.t.n * self.alpha, self.shard_size(lo) // self.alpha,
+                self.shard_size(hi) // self.alpha)
+
+    def _ready_step(self, b: int, width: int) -> None:
+        self.encode_rows_async(np.zeros(
+            (b, self.t.n, width * self.alpha), dtype=np.uint8)).wait()
+
+    def _submit_rows(self, data: np.ndarray, shard_size: int):
         batcher = self._batcher()
         if batcher is None:
             return None
@@ -350,11 +408,14 @@ class LrcEncoder(Encoder):
         parity (cheap, depends on the global rows) is computed here,
         after the step lands."""
         t = self.t
+        glob = (fut.result(timeout) if fut is not None
+                else self.engine.encode_parity(data, t.m))
+        # the step hands back the shard's own width: rows built at the
+        # width rung are read up to it
+        data = data[..., : glob.shape[-1]]
         parity = np.empty(data.shape[:-2] + (t.m + t.l, data.shape[-1]),
                           dtype=np.uint8)
-        parity[..., : t.m, :] = (
-            fut.result(timeout) if fut is not None
-            else self.engine.encode_parity(data, t.m))
+        parity[..., : t.m, :] = glob
         ln, lm = self._local_nm
         for az in range(t.az_count):
             stripe_idx, _, _ = t.local_stripe_in_az(az)

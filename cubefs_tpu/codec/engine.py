@@ -32,6 +32,7 @@ next one down; ``CUBEFS_CODEC_DEAD`` declares legs lost for a drill.
 from __future__ import annotations
 
 import logging
+import math
 import os
 import time
 from typing import Protocol
@@ -130,12 +131,13 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
 
 def ready_decode(n: int, s: int) -> None:
     """Called by the device engine after an encode of geometry (n data
-    shards of s bytes): the first time, one zero stripe goes through the
-    geometry's decode shape — n rows solved from n survivors,
+    shards at the width rung s): the first time, one zero stripe goes
+    through the rung's decode shape — n rows solved from n survivors,
     (1, n, s), what codec/encoder.py's reconstruct always asks for — so
     its program is compiled (and its Pallas gate paid) with the encode's
-    and a hedged or degraded GET never compiles inside a request. Where
-    n == m that is the encode's own program and nothing is built."""
+    and a hedged or degraded GET of any size in the rung never compiles
+    inside a request. Where n == m that is the encode's own program and
+    nothing is built."""
     def build() -> bool:
         coeff = np.eye(n, dtype=np.uint8)
         planes, program = rs_kernel.plan(coeff, (1, n, s))
@@ -156,17 +158,32 @@ class JaxEngine:
         data = np.asarray(data)
         n, s = int(data.shape[-2]), int(data.shape[-1])
         out = self._apply("encode", gf256.parity_matrix(n, n_parity), data)
-        ready_decode(n, s)
+        ready_decode(n, rs_kernel.rung_width(s))
         return out
 
     def _apply(self, op: str, coeff: np.ndarray, shards: np.ndarray
                ) -> np.ndarray:
+        """One device call at the step's rung (rs_kernel.step_shape):
+        programs exist for rung shapes alone. The batcher hands over
+        rung-shaped steps and they go up as they are; any other caller's
+        shards are copied into a zeroed rung-shaped array here and its
+        rows sliced back."""
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         shards = np.asarray(shards)
-        planes, program = rs_kernel.plan(coeff, shards.shape)
-        return device_call(
+        lead, (c, s) = shards.shape[:-2], shards.shape[-2:]
+        b = math.prod(lead)
+        rung = rs_kernel.step_shape(c, b, s)
+        step = shards
+        if shards.shape != (rung[0], c, rung[1]):
+            step = np.zeros((rung[0], c, rung[1]), dtype=np.uint8)
+            step[:b, :, :s] = shards.reshape(b, c, s)
+        planes, program = rs_kernel.plan(coeff, step.shape)
+        out = device_call(
             self, op, lambda: rs_kernel.device_bits(coeff, planes, op),
-            program, shards)
+            program, step)
+        if step is not shards:
+            out = out[:b, :, :s].reshape(*lead, coeff.shape[0], s)
+        return out
 
 
 class CppEngine:

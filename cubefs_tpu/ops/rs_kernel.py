@@ -33,7 +33,7 @@ import numpy as np
 
 from ..utils import metrics
 from ..utils import trace as tracelib
-from . import bitlin, gf256, msr, progcache
+from . import bitlin, gf256, msr, pallas_gf, progcache
 
 _BITS = (1 << np.arange(8)).astype(np.int32)
 _log = logging.getLogger("cubefs.codec")
@@ -48,13 +48,13 @@ def _use_pallas() -> bool:
 
 
 def _pallas_profitable(s: int) -> bool:
-    """Pallas pads S up to a tile multiple: only dispatch when the pad
-    waste is bounded (exact multiple, or >=4 tiles so waste <= 25%) —
-    small/tiny-extent shards stay on the jnp path, which is exact in S."""
-    from . import pallas_gf
-
-    tile = pallas_gf.DEFAULT_TILE
-    return s % tile == 0 or s >= 4 * tile
+    """The fused program serves shards of four tiles and more. Below,
+    a step is microseconds of device work behind milliseconds of host
+    dispatch, and the jnp program's dispatch is the cheaper one (one
+    jitted call; the fused program reshapes and vmaps in Python on
+    every call): `put-small` lost 17% of its `op_rate` with its 64 KiB
+    class on the fused program (PERF.md section 6, PR 34)."""
+    return s >= 4 * pallas_gf.DEFAULT_TILE
 
 
 # Programs the gate refused this process: (rows, cols, tile) -> cause.
@@ -242,6 +242,101 @@ def gf_apply_bits(
             y = jax.lax.psum(y, psum_axis)
     with jax.named_scope("gf256.bits.pack"):
         return pack_bits(y & 1)
+
+
+# ---------------- the ladder of step shapes -----------------------------
+# A program is keyed by its step's shape, and an object store's clients
+# PUT whatever sizes they have: so every step runs at the smallest RUNG
+# (B_rung, S_rung) that holds it. GF apply is independent per byte
+# column and per stripe, so pad columns and pad stripes change nothing
+# of what is sliced back. The rungs are few enough to build before the
+# first request (codec/encoder.py: Encoder.ready), so a size nobody
+# warmed costs no compile, and PUTs of different sizes meet in one
+# queue of the batcher. What the rungs cost is in PERF.md section 6.
+#
+# Width rungs are whole tiles of the fused kernel, so the fused program
+# (four tiles and up) never pads on the device and the jnp program below
+# it is 128-lane aligned: 1..7 tiles, then {9, 11, 14} x 2^k — a step
+# of at most 2/9 between neighbours, chosen so that 22 tiles is a rung:
+# the shard of a full 8 MiB blob over 12 (and of 4 MiB over 6) is
+# 699,051 B, 21.33 tiles.
+_TILE_MANTISSAS = (9, 11, 14)
+STEP_BATCH = 8  # stripes a coalesced step may hold (BatchCodec.max_batch)
+
+
+def rung_width(s: int) -> int:
+    """The smallest width rung >= ``s`` bytes of shard: a whole number
+    of tiles (128-lane aligned whatever program serves it). A row's pad
+    is under one tile up to 4 tiles, then under a quarter of the row,
+    from 7 tiles on under 2/9. A rung's rung is itself."""
+    tile = pallas_gf.DEFAULT_TILE
+    tiles = -(-s // tile)
+    if tiles <= 7:
+        return tile if tiles < 1 else tiles * tile
+    k = 0
+    while True:
+        for m in _TILE_MANTISSAS:
+            if m << k >= tiles:
+                return (m << k) * tile
+        k += 1
+
+
+def rung_batch(b: int) -> int:
+    """The smallest stripe-count rung >= ``b``: 1, 2, 4, 8 up to
+    STEP_BATCH (a coalesced step of small stripes may double), then
+    {4, 5, 6, 7} x 2^k (only a single submission of many stripes gets
+    there, a repair task's 64: it grows by at most a quarter)."""
+    b = max(1, int(b))
+    if b <= STEP_BATCH:
+        return 1 << (b - 1).bit_length()
+    k = max(0, b.bit_length() - 3)
+    return -(-b >> k) << k
+
+
+def step_shape(cols: int, b: int, s: int) -> tuple[int, int]:
+    """(B_rung, S_rung): the step shape that ``b`` stripes of ``cols``
+    rows of ``s`` bytes run at. The one function every step shape goes
+    through: the batcher keys its queues and gathers its steps by it,
+    the front door sizes a PUT's data rows by it, the device engine
+    pads what reaches it in any other shape."""
+    del cols  # no rung depends on the row count today; the key has it
+    return rung_batch(b), rung_width(s)
+
+
+def batch_cap(cols: int, s_rung: int, max_step_bytes: int,
+              max_batch: int = STEP_BATCH) -> int:
+    """The most stripes a COALESCED step of this width may hold: the
+    largest stripe-count rung within ``max_batch`` stripes and
+    ``max_step_bytes`` input bytes, both reckoned on the rung (one
+    stripe always fits: a submission is never split)."""
+    cap = max(1, min(int(max_batch),
+                     int(max_step_bytes) // max(1, cols * s_rung)))
+    b = 1
+    while (nxt := rung_batch(b + 1)) <= cap:
+        b = nxt
+    return b
+
+
+def ladder(cols: int, s_lo: int, s_hi: int, max_step_bytes: int,
+           max_batch: int = STEP_BATCH, stripes: int = 1
+           ) -> list[tuple[int, int]]:
+    """Every (B_rung, S_rung) a step of ``cols`` rows can run at when
+    its submissions hold shards of ``s_lo``..``s_hi`` bytes and up to
+    ``stripes`` stripes each: sorted, finite — the rungs of
+    ``step_shape`` up to the coalescing bounds, plus the shape of one
+    submission alone where that is past them."""
+    out = []
+    s = rung_width(s_lo)
+    top = rung_width(s_hi)
+    while s <= top:
+        cap = max(batch_cap(cols, s, max_step_bytes, max_batch),
+                  rung_batch(stripes))
+        b = 1
+        while b <= cap:
+            out.append((b, s))
+            b = rung_batch(b + 1)
+        s = rung_width(s + 1)
+    return out
 
 
 @progcache.cached("rs_jit")

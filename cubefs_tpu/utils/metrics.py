@@ -342,6 +342,18 @@ codec_batch_stripes = DEFAULT.histogram(
     "cubefs_codec_batch_stripes_per_step",
     "stripes coalesced per drained device step (1 = uncontended)",
     ("op",), buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024))
+# a step runs at a rung of rs_kernel's ladder: `payload` is what its
+# submissions brought (stripes x rows x their own widths), `pad` the
+# zero columns and zero stripes up to the rung; widths counts the
+# distinct submission widths that met in one step
+codec_step_bytes = DEFAULT.counter(
+    "cubefs_codec_step_bytes_total",
+    "input bytes of drained device steps (payload / pad)",
+    ("op", "kind"))
+codec_batch_widths = DEFAULT.histogram(
+    "cubefs_codec_batch_widths_per_step",
+    "distinct submission widths coalesced per drained device step",
+    ("op",), buckets=(1, 2, 3, 4, 6, 8))
 codec_batch_wait = DEFAULT.histogram(
     "cubefs_codec_batch_wait_seconds",
     "submit-to-device-step admission wait", ("op",))
@@ -355,7 +367,8 @@ codec_batch_dp_steps = DEFAULT.counter(
     "cubefs_codec_batch_dp_steps_total",
     "device steps sharded dp-wise across the mesh", ("dp",))
 # where one drained step's host time goes: `gather` (the batcher's
-# concatenate; 0 for a single-submission step, so the mean is per step),
+# copy of its submissions into the rung-shaped array; 0 for a step that
+# is one rung-shaped submission, so the mean is per step),
 # then inside a device engine's call `matrix` (the step's bit matrix
 # from the device-resident cache: a lookup, or on a miss one bit
 # expansion and one upload), `h2d`, `launch` (Python dispatch until the

@@ -316,13 +316,15 @@ def test_a_mismatching_fused_program_is_refused_and_the_jnp_path_serves(
     monkeypatch.setattr(rs_kernel, "pallas_refusals", {})
     monkeypatch.setattr(rs_kernel, "_gate", {})
     rows = gf256.decode_matrix(6, 9, [0, 2, 3, 5, 6, 8])[:2]
-    data = rng.integers(0, 256, (3, 6, 512), dtype=np.uint8)
+    # 1500 B of shard run at the 6-tile rung, a shape of this test's own
+    data = rng.integers(0, 256, (3, 6, 1500), dtype=np.uint8)
     built = {k: metrics.codec_programs.value(kernel=k)
              for k in ("bits", "gf256_apply")}
     with caplog.at_level(logging.ERROR, logger="cubefs.codec"):
         got = get_engine("tpu").matrix_apply(rows, data)
     assert np.array_equal(got, get_engine("numpy").matrix_apply(rows, data))
-    assert rs_kernel.serves_fused(rows, 512) is (not planted)
+    assert rs_kernel.rung_width(1500) == 1536
+    assert rs_kernel.serves_fused(rows, 1536) is (not planted)
     if planted:
         assert list(rs_kernel.pallas_refusals) == [(2, 6, 256)]
         assert "mismatch" in rs_kernel.pallas_refusals[(2, 6, 256)]
@@ -377,8 +379,13 @@ def test_chip_smoke_phases_at_tiny_sizes(tmp_path, capsys):
     assert out["ok"] is True and out["claim"] is None
     assert list(out)[-1] == "claim"
     assert set(out["phases"]) == {"put", "get", "reference", "break_repair",
-                                  "sidecar", "two_loss", "device_proof",
-                                  "checkout_clean"}
+                                  "sidecar", "two_loss", "any_size",
+                                  "device_proof", "checkout_clean"}
+    # sizes nobody named, after the front door's ready: nothing built
+    any_size = out["phases"]["any_size"]
+    assert any_size["objects"] == 6 and any_size["ready_steps"] > 0
+    assert any_size["codemodes"] == ["EC12P4", "EC3P3", "EC6P6"]
+    assert any_size["programs_built_after_ready"] == 0
     assert out["phases"]["two_loss"]["matrices"] == 16 + 240
     assert out["phases"]["two_loss"]["compiles_after_first_step"] == 0
     # 256 (lost, also lost) pairs are 209 distinct matrices: a second

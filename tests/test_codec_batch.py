@@ -88,7 +88,7 @@ def test_concurrent_submits_bit_identical(rng):
 def test_async_pipeline_coalesces_into_one_step(rng):
     """Pipelined async submissions park until the first collector
     drains them — 10 submissions, ONE device step, bit-identical."""
-    bc = _CountingCodec()
+    bc = _CountingCodec(max_batch=1024)  # the shipped bound is 8 stripes
     n, m, s = 4, 2, 64
     inputs = [_stripes(rng, 3, n, s) for _ in range(10)]
     futs = [bc.submit_encode_async("numpy", d, m) for d in inputs]
@@ -394,11 +394,13 @@ def test_lrc_encode_async_matches_sync(rng):
 @pytest.mark.parametrize("admitted", [True, False], ids=["admitted", "raw"])
 @pytest.mark.parametrize("mode", ["EC6P3", "EC4P4L2", "EC4P4MSR"])
 def test_encode_rows_async_returns_the_parity_rows(rng, mode, admitted):
-    """encode_rows_async(data rows).wait() is the parity rows a blocking
-    encode() of the whole stripe lands, RS, LRC and MSR; the step reads
-    the caller's array itself (no copy on the way in) and leaves it as
-    it was. An encoder over a raw engine (no admission surface) has
-    encoded inline by the time the handle is back."""
+    """encode_rows_async(data rows, shard size).wait() is the parity
+    rows a blocking encode() of the whole stripe lands, RS, LRC and MSR;
+    rows built at the encoder's row_width (the step's width rung, zeros
+    past the shard) are read by the step as they are (no copy on the way
+    in) and left as they were. An encoder over a raw engine (no
+    admission surface) has encoded inline by the time the handle is
+    back."""
     from cubefs_tpu.codec.codemode import CodeMode
     from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
     from cubefs_tpu.codec.engine import NumpyEngine
@@ -419,19 +421,23 @@ def test_encode_rows_async_returns_the_parity_rows(rng, mode, admitted):
     enc.engine = (AdmittedEngine(Seeing(max_wait_ms=1.0), "numpy")
                   if admitted else SeeingRaw())
     t = enc.t
-    size = 12 * getattr(enc, "alpha", 1)
-    data = _stripes(rng, 3, t.n, size)
-    stripes = np.zeros((3, t.total, size), dtype=np.uint8)
-    stripes[:, : t.n, :] = data
+    # MSR rows are cut into alpha sub-shards, each a rung wide here
+    size = (32768 if mode == "EC4P4MSR" else 12) * getattr(enc, "alpha", 1)
+    data = np.zeros((4, t.n, enc.row_width(size)), dtype=np.uint8)
+    data[:, :, :size] = _stripes(rng, 4, t.n, size)
+    stripes = np.zeros((4, t.total, size), dtype=np.uint8)
+    stripes[:, : t.n, :] = data[:, :, :size]
     ref = enc.encode(stripes)
     seen.clear()
 
     before = data.copy()
-    pending = enc.encode_rows_async(data)
+    pending = enc.encode_rows_async(data, size)
     assert pending.resolved is (not admitted)
     parity = pending.wait()
-    assert parity.shape == (3, t.total - t.n, size)
+    assert parity.shape == (4, t.total - t.n, size)
     assert np.array_equal(parity, ref[:, t.n:, :])
     assert np.array_equal(data, before)
     assert np.shares_memory(seen[0], data)
     assert pending.wait() is parity  # collected once, kept
+    with pytest.raises(Exception, match="wide"):  # narrower than a shard
+        enc.encode_rows_async(data[:, :, :size - 1], size)

@@ -338,9 +338,9 @@ def test_a_zero_warm_up_covers_real_arrays_bare_and_phased(
     PR 28)."""
     eng = get_engine("tpu")
     monkeypatch.setattr(eng, "_phase_due", 0.0, raising=False)
-    # a process plans a shape once, so its decode is readied once: each
-    # program gets a shard size of its own here
-    s = {"bits": 1000, "fused": 1003}[program]
+    # a process plans a shape once, so its decode is readied once a
+    # width rung: each program gets a rung of its own here
+    s = {"bits": 20_000, "fused": 60_000}[program]
     eng.encode_parity(np.zeros((4, 12, s), dtype=np.uint8), 4)
     del compiles[:]
     for trial in range(4):
@@ -369,11 +369,14 @@ def test_plan_hands_the_device_engine_the_fused_program_off_the_chip(
 
     monkeypatch.setattr(rs_kernel, "serves_fused", lambda coeff, s: True)
     monkeypatch.setattr(pallas_gf, "DEFAULT_TILE", TILE)
-    s = 777  # three tiles and a pad; a size no other test uses
+    s = 3000  # its step runs at a rung no other test uses
+    rung_b, rung_s = rs_kernel.step_shape(6, 2, s)
+    shape = (rung_b, 6, rung_s)
+    assert shape[2] > s and shape[2] % TILE == 0  # whole tiles: no pad
     coeff = np.ascontiguousarray(gf256.parity_matrix(6, 3))
-    planes, program = rs_kernel.plan(coeff, (2, 6, s))
+    planes, program = rs_kernel.plan(coeff, shape)
     assert planes is True
-    assert program is pallas_gf._apply_fn(3, 6, (2, 6, s), TILE, True)
+    assert program is pallas_gf._apply_fn(3, 6, shape, TILE, True)
     eng = engine.JaxEngine()
     data = rng.integers(0, 256, (2, 6, s), dtype=np.uint8)
     before = programs()

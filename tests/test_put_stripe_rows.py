@@ -16,7 +16,7 @@ from cellbench import reference
 from cubefs_tpu.blob import access as access_mod
 from cubefs_tpu.blob.access import PutQuorumError
 from cubefs_tpu.codec import codemode as cmode
-from cubefs_tpu.ops import msr
+from cubefs_tpu.ops import msr, rs_kernel
 from cubefs_tpu.utils import metrics
 from test_blob_e2e import Cluster
 
@@ -257,25 +257,28 @@ def test_rows_are_dropped_while_a_write_may_still_read_them(
             raise TimeoutError("step")
 
     enc = acc._encoder(int(cmode.CodeMode.EC6P3))
-    monkeypatch.setattr(enc, "encode_rows_async", lambda rows: NeverEnds())
+    monkeypatch.setattr(enc, "encode_rows_async",
+                        lambda rows, shard_size: NeverEnds())
     with pytest.raises(TimeoutError):
         acc.put(data, codemode=cmode.CodeMode.EC6P3)
     assert acc._free_rows == []
 
 
 def test_free_list_never_exceeds_its_bound(cluster, rng, monkeypatch):
-    """Shapes that never repeat: the list stays under its bound in
-    bytes, the oldest array goes first."""
+    """Shapes that never repeat (a width rung each: the list keys by
+    the rung shape): the list stays under its bound in bytes, the
+    oldest array goes first."""
     acc = cluster.access
-    monkeypatch.setattr(access_mod, "STRIPE_ROWS_KEPT_BYTES", 100_000)
-    sizes = [30_000, 31_000, 32_000, 33_000, 34_000, 35_000]
-    for size in sizes:
-        data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+    monkeypatch.setattr(access_mod, "STRIPE_ROWS_KEPT_BYTES", 2_200_000)
+    blobs = [1, 2, 3, 4, 5, 6]
+    for k in blobs:
+        data = rng.integers(0, 256, k * BLOB, dtype=np.uint8).tobytes()
         acc.put(data, codemode=cmode.CodeMode.EC6P3)
-        assert sum(r.nbytes for r in acc._free_rows) <= 100_000
-    # 6 rows of S = ceil(size / 6): the newest arrays that fit are kept
-    assert [r.shape[2] for r in acc._free_rows] == [
-        -(-s // 6) for s in sizes[-2:]]
+        assert sum(r.nbytes for r in acc._free_rows) <= 2_200_000
+    # k stripes of 6 rows at the width rung of ceil(BLOB / 6) bytes,
+    # one tile: the newest arrays that fit are kept
+    assert [r.shape for r in acc._free_rows] == [
+        (k, 6, rs_kernel.rung_width(-(-BLOB // 6))) for k in blobs[-2:]]
     # one array larger than the bound is not kept at all
     monkeypatch.setattr(access_mod, "STRIPE_ROWS_KEPT_BYTES", 10)
     acc.put(b"x" * 1000, codemode=cmode.CodeMode.EC6P3)

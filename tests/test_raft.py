@@ -115,6 +115,11 @@ def test_follower_rejects_propose_with_redirect():
     try:
         leader = wait_leader(members)
         follower = next(m for m in members.values() if m is not leader)
+        # the redirect hint comes with the leader's first heartbeat: on
+        # a loaded machine the election can be seen before it lands
+        deadline = time.time() + 5.0
+        while follower.node.leader is None and time.time() < deadline:
+            time.sleep(0.02)
         with pytest.raises(raft.NotLeaderError) as ei:
             follower.node.propose({"x": 1})
         assert ei.value.leader == leader.node.me
