@@ -168,39 +168,71 @@ class AccessHandler:
                     "alloc_bids", {"count": n_blobs,
                                    "op_id": uuid.uuid4().hex})
                 min_bid = meta["start"]
-        # the stage is the RESIDUAL admission wait left on the critical
-        # path after overlapping allocation; admitted->done wall time
-        # rides as a tag on the stage span
-        with tracelib.stage("encode_admission") as st:
-            parity = pending.wait()
-            if getattr(st, "span", None) is not None:
-                st.span.set_tag(
-                    "encode_total_ms",
-                    round((time.monotonic() - encode_admitted) * 1000, 3))
-
-        # ---- quorum writes ----
+        # ---- the fork: nothing that does not read the parity rows
+        # waits for them. The data shards are views of `rows`, ready
+        # since stripe_fill, and the location's CRC reads the client's
+        # bytes: both go to the pool before the wait and run under the
+        # device step; only the parity writes follow it.
+        # Guarantees as in the sequential order: the PUT is acknowledged
+        # only after every bid has its put quorum of acknowledged shard
+        # writes, data and parity counted together, and every write of
+        # both groups has ended; what is stored is byte for byte the
+        # same. If the encode raises, the data shards already written
+        # belong to bids no Location names — the state a failed quorum
+        # leaves.
         quorum = self.cfg.put_quorum_override or t.put_quorum
-        with tracelib.stage("quorum_write"):
-            futures = []
-            for i in range(n_blobs):
-                bid = min_bid + i
-                for u in vol.units:
-                    shard = (rows[i, u.index, :shard_size] if u.index < t.n
-                             else parity[i, u.index - t.n])
-                    futures.append(
-                        self._submit(self._write_shard, vol, u, bid, shard)
-                    )
-            fails: list[tuple[int, int]] = []  # (bid, unit index)
-            ok_per_bid = {min_bid + i: 0 for i in range(n_blobs)}
-            for f in futures:  # every one, stragglers past quorum too
-                bid, idx, err = f.result()
-                if err is None:
-                    ok_per_bid[bid] += 1
-                else:
-                    fails.append((bid, idx))
+        bids = range(min_bid, min_bid + n_blobs)
+        data_units = [u for u in vol.units if u.index < t.n]
+        parity_units = [u for u in vol.units if u.index >= t.n]
+        futures = []
+        try:
+            # this thread drains the codec queue when it waits (codec/
+            # batcher.py: a step starts at result()), so all that stands
+            # here delays the step: submits only, the CRC on the pool.
+            # Both halves are the stage `quorum_write`: its seconds sum.
+            with tracelib.stage("quorum_write"):
+                for i, bid in enumerate(bids):
+                    for u in data_units:
+                        futures.append(self._submit(
+                            self._write_shard, vol, u, bid,
+                            rows[i, u.index, :shard_size]))
+                crc_task = self._submit(zlib.crc32, data)
+            # the RESIDUAL admission wait left on the critical path
+            # after overlapping allocation and the data writes;
+            # admitted->done wall time rides as a tag on the stage span
+            with tracelib.stage("encode_admission") as st:
+                parity = pending.wait()
+                if getattr(st, "span", None) is not None:
+                    st.span.set_tag(
+                        "encode_total_ms",
+                        round((time.monotonic() - encode_admitted) * 1000, 3))
+            if tracelib.current() is not None:
+                early = sum(f.done() for f in futures)
+                late = len(futures) - early + n_blobs * len(parity_units)
+                metrics.access_shard_writes.inc(early, when="under_encode")
+                metrics.access_shard_writes.inc(late, when="after_encode")
+            with tracelib.stage("quorum_write"):
+                for i, bid in enumerate(bids):
+                    for u in parity_units:
+                        futures.append(self._submit(
+                            self._write_shard, vol, u, bid,
+                            parity[i, u.index - t.n]))
+                fails: list[tuple[int, int]] = []  # (bid, unit index)
+                ok_per_bid = dict.fromkeys(bids, 0)
+                for f in futures:  # every one, stragglers past quorum too
+                    bid, idx, err = f.result()
+                    if err is None:
+                        ok_per_bid[bid] += 1
+                    else:
+                        fails.append((bid, idx))
+        except BaseException:
+            # no way out leaves a write of this PUT running; the step
+            # may still read `rows` (a wait that timed out), so the
+            # array is dropped and not kept
+            wait(futures)
+            raise
         # the step and every shard write of this PUT have ended: nothing
-        # but this thread can read `rows` now. On every way out above,
-        # where one may still run, the array is dropped and not kept.
+        # but this thread can read `rows` now
         self._return_stripe_rows(rows)
         for bid, n_ok in ok_per_bid.items():
             if n_ok < quorum:
@@ -220,7 +252,7 @@ class AccessHandler:
                 )
 
         with tracelib.stage("location_crc"):
-            crc = zlib.crc32(data)
+            crc = crc_task.result()  # computed under the device step
         return Location(
             cluster_id=1,
             codemode=mode,

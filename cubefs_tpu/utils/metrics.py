@@ -329,6 +329,14 @@ access_stripe_buffers = DEFAULT.counter(
     "cubefs_access_stripe_buffers_total",
     "data-row arrays PUTs filled, by where the array came from "
     "(reused / fresh)", ("result",))
+# a PUT's shard writes (blob/access.py): the data shards are submitted
+# before the wait for the codec step, the parity shards after it;
+# counted once a PUT, when the wait returns
+access_shard_writes = DEFAULT.counter(
+    "cubefs_access_shard_writes_total",
+    "shard writes of PUTs by whether the write had ended when the PUT's "
+    "encode did (under_encode) or not yet, parity writes among them "
+    "(after_encode)", ("when",))
 
 # batched codec admission (codec/batcher.py): device-sized steps
 codec_batch_submissions = DEFAULT.counter(

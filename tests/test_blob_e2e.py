@@ -637,13 +637,20 @@ def test_put_admits_encode_before_alloc(cluster, rng):
     loc = cluster.access.put(data, codemode=cmode.CodeMode.EC6P3)
     root = next(s for s in tracelib.finished_spans()
                 if s["op"] == "access.put")
-    st = {s["tags"]["stage"]: s
-          for s in tracelib.finished_spans(root["trace_id"])
-          if s["parent_id"] == root["span_id"]}
+    children = sorted((s for s in tracelib.finished_spans(root["trace_id"])
+                       if s["parent_id"] == root["span_id"]),
+                      key=lambda s: s["start"])
+    st = {s["tags"]["stage"]: s for s in children}  # the last of a name
     end = lambda s: s["start"] + s["duration"]
     assert (st["encode_submit"]["start"] <= st["bid_alloc"]["start"]
             <= end(st["bid_alloc"]) <= end(st["encode_admission"])
             <= st["quorum_write"]["start"])
+    # since PR 35 the stage is entered twice: the data shards go out
+    # after the allocation and before the wait, the parity after it
+    first = next(s for s in children if s["tags"]["stage"] == "quorum_write")
+    assert first is not st["quorum_write"]
+    assert (end(st["bid_alloc"]) <= first["start"] <= end(first)
+            <= st["encode_admission"]["start"])
     assert st["encode_admission"]["tags"]["encode_total_ms"] >= \
         st["encode_admission"]["duration"] * 1000
     assert cluster.access.get(loc) == data

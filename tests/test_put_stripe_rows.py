@@ -231,10 +231,13 @@ def test_failed_quorum_returns_rows_only_after_every_write(
 
 def test_rows_are_dropped_while_a_write_may_still_read_them(
         cluster, rng, monkeypatch):
-    """A PUT that ends before its writes have (one write's future
-    raises, the others still run): the array is dropped, not kept."""
+    """A PUT that ends in an error while its writes may still read the
+    rows (one write's future raises, the others still run): the array
+    is dropped, not kept. Since PR 35 such a PUT raises only once the
+    writes it started have ended, so the gate opens on a timer."""
     acc = cluster.access
     write, release = acc._write_shard, threading.Event()
+    threading.Timer(0.2, release.set).start()
 
     def write_or_die(vol, unit, bid, shard):
         if unit.index == 0:
