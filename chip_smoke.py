@@ -78,6 +78,8 @@ class Sizes:
     crc_tiles: tuple[int, ...] = (128, 256, 512)
     two_loss: tuple[int, int] = (8, 699051)  # (stripes, shard bytes) a step
     any_size: tuple[int, int] = (32, 16 << 20)  # (objects, largest bytes)
+    repair_any_size: int = 24  # objects a codemode, in one volume
+    repair_stripes: int = 64  # the worker's batch_stripes
 
 
 FULL = Sizes()
@@ -86,7 +88,8 @@ TINY = Sizes(blob_size=1 << 20, put_threads=2,
              small=(3, 10_007), special_bytes=300_007, ref_stripes=1,
              sidecar_shard=8192, crc_blocks=8, crc_block_len=8192,
              crc_tiles=(8,), two_loss=(2, 300),
-             any_size=(6, (4 << 20) + 8192))
+             any_size=(6, (4 << 20) + 8192), repair_any_size=5,
+             repair_stripes=8)
 
 # what .gitignore lists: the only paths a run may create or change
 _IGNORED_DIRS = {".git", ".jax_cache", "chiprun_out", "__pycache__",
@@ -193,7 +196,8 @@ class Deployment:
                                delete_queue=self.delete_q,
                                node_pool=self.pool)
         self.worker = RepairWorker(rpc.Client(self.sched), self.cm_client,
-                                   self.pool, engine="tpu")
+                                   self.pool, engine="tpu",
+                                   batch_stripes=sizes.repair_stripes)
         self.sidecar = rpc.RpcServer(
             rpc.expose(CodecService(engine="tpu")), service="codec").start()
 
@@ -623,6 +627,111 @@ def phase_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
             "smoke_wall_s": round(time.perf_counter() - t0, 3)}
 
 
+def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
+                          ) -> dict:
+    """A volume of objects of any sizes per size-class codemode, one
+    unit of each rebuilt: after the worker's `ready` (the repair steps
+    its policies can reach, built once) the tasks group their bids by
+    width rung, not one program is built, and every rebuilt shard is the
+    reference stripe's row (cellbench/reference.py) at its exact size.
+    The PUTs go through a proxy allocator, so a codemode's objects share
+    a volume; the repair is queued as an operator's (`manual_migrate`):
+    the worker reads nothing of the unit it rebuilds."""
+    from cellbench import reference
+    from cubefs_tpu.blob.access import AccessHandler
+    from cubefs_tpu.blob.proxy import ProxyAllocator
+    from cubefs_tpu.codec import codemode as cm
+    from cubefs_tpu.utils import metrics, rpc
+
+    largest = sizes.any_size[1]
+    cfg = dep.access.cfg
+    built = lambda: sum(v for _, v in metrics.codec_programs.samples())
+    t0 = time.perf_counter()
+    before = built()
+    steps = dep.worker.ready(largest, cfg.policies, cfg.blob_size)
+    ready_s = time.perf_counter() - t0
+    dep.access.ready(largest)  # the fill's programs (phase any_size's)
+    at_ready, compiles = built(), clock.compiles
+    front = AccessHandler(
+        dep.cm_client, dep.pool, cfg, repair_queue=dep.repair_q,
+        delete_queue=dep.delete_q,
+        proxy_client=rpc.Client(ProxyAllocator(dep.cm_client)))
+    rng = np.random.default_rng([SEED, 36])
+    bounds = [4096] + [p.max_size for p in cfg.policies[:2]] + [largest]
+    tasks0 = dict(metrics.repair_steps_per_task.samples()).get(
+        (), {"count": 0, "sum": 0.0})
+    checked, by_mode = 0, {}
+    try:
+        for klass in range(3):
+            objects = []
+            for i in range(sizes.repair_any_size):
+                size = int(np.exp(rng.uniform(np.log(bounds[klass] + 1),
+                                              np.log(bounds[klass + 1]))))
+                data = payload(6, 100 * klass + i, size)
+                objects.append((data, front.put(data)))
+            loc = objects[0][1]
+            t = cm.tactic(loc.codemode)
+            vid = loc.slices[0].vid
+            if {o.slices[0].vid for _, o in objects} != {vid}:
+                raise RuntimeError(f"{sizes.repair_any_size} PUTs of one "
+                                   f"size class are not in one volume")
+            bad = (1, t.n + 1, t.n + t.m - 1)[klass]  # data, parity, last
+            old = dep.cm.get_volume(vid).units[bad]
+            dep.sched.manual_migrate(vid, bad)
+            for _ in range(dep.sched.MAX_ATTEMPTS + 1):
+                if not dep.worker.run_once():
+                    break
+            if dep.worker.failed:
+                errs = sorted({x.get("last_error", "") for x in
+                               dep.sched.tasks.values()
+                               if x.get("last_error")})
+                raise RuntimeError(f"vid {vid} unit {bad}: repair task "
+                                   f"runs failed: {errs[:3]}")
+            unit = dep.cm.get_volume(vid).units[bad]
+            if (unit.disk_id, unit.chunk_id) == (old.disk_id, old.chunk_id):
+                raise RuntimeError(f"vid {vid} unit {bad} was not rebuilt")
+            widths = set()
+            for data, o in objects:
+                sl = o.slices[0]
+                whole = data if sl.count == 1 else data.ljust(
+                    sl.count * sl.blob_size, b"\0")
+                for b in range(sl.count):
+                    ref = reference.stripe(
+                        whole[b * sl.blob_size:(b + 1) * sl.blob_size],
+                        t.n, t.m, t.min_shard_size)[bad]
+                    meta, got = dep.unit_call(unit, "get_shard",
+                                              sl.min_bid + b)
+                    if got != ref.tobytes():
+                        raise RuntimeError(
+                            f"{cm.CodeMode(o.codemode).name} bid "
+                            f"{sl.min_bid + b} unit {bad}: rebuilt shard "
+                            f"of {len(got)} B differs from the reference "
+                            f"stripe's {ref.shape[0]} B")
+                    if reference.crc32(got) != meta["crc"]:
+                        raise RuntimeError(f"bid {sl.min_bid + b}: stored "
+                                           f"crc is not zlib's")
+                    widths.add(len(got))
+                    checked += 1
+            by_mode[cm.CodeMode(loc.codemode).name] = len(widths)
+    finally:
+        front._pool.shutdown(wait=True)
+    if built() != at_ready or clock.compiles != compiles:
+        raise RuntimeError(
+            f"{built() - at_ready} codec programs built and "
+            f"{clock.compiles - compiles} programs compiled after ready, "
+            f"by the repair of three mixed-size volumes")
+    tasks = dict(metrics.repair_steps_per_task.samples())[()]
+    return {"ok": True, "objects_per_codemode": sizes.repair_any_size,
+            "distinct_shard_sizes": by_mode, "rebuilt_shards_checked": checked,
+            "decode_steps": int(tasks["sum"] - tasks0["sum"]),
+            "tasks": int(tasks["count"] - tasks0["count"]),
+            "ready_steps": int(steps),
+            "programs_built_at_ready": int(at_ready - before),
+            "programs_built_after_ready": 0,
+            "ready_wall_s": round(ready_s, 3),
+            "smoke_wall_s": round(time.perf_counter() - t0, 3)}
+
+
 def phase_device_proof(n_devices: int, device_checks: bool) -> dict:
     """Right answers are not enough: show where they were computed."""
     from cubefs_tpu.codec import engine
@@ -697,6 +806,7 @@ def run(sizes: Sizes, workdir: str, device_checks: bool) -> dict:
         phases["sidecar"] = phase_sidecar(dep, sizes)
         phases["two_loss"] = phase_two_loss(dep, sizes, clock)
         phases["any_size"] = phase_any_size(dep, sizes, clock)
+        phases["repair_any_size"] = phase_repair_any_size(dep, sizes, clock)
         phases["device_proof"] = phase_device_proof(
             device["count"], device_checks)
     finally:

@@ -5,9 +5,13 @@ worker_service.go:206; ShardRecover download-and-reconstruct at
 worker_slice_recover.go:458,865; CRC cross-check at :45).
 
 TPU-first redesign: instead of reconstructing blob-by-blob, a task's
-blobs are grouped by shard size and recovered as BATCHED stripe stacks
-(B, n, S) in one device call — the migrate fleet's throughput rides the
-batch dimension.
+blobs are grouped by the width rung of their shard size (ops/rs_kernel:
+the ladder of step shapes) and recovered as BATCHED stripe stacks
+(B_rung, n, S_rung) in one device call — every bid at its own size,
+zeros past it — so a volume of objects of any sizes repairs in a
+handful of device steps, at shapes whose programs `RepairWorker.ready`
+built before the first task. A rebuilt shard is cut to its bid's size
+before it is checked and written back: no stored shard carries pad.
 """
 
 from __future__ import annotations
@@ -59,6 +63,17 @@ def solve_and_wanted(subs: list[int], n_solve: int, bad_sub: int
     return list(subs[:n_solve]), wanted
 
 
+def repair_shard_sizes(t: cm.Tactic, lo: int, hi: int, blob_size: int
+                       ) -> tuple[int, int]:
+    """(least, largest) shard size of the blobs of objects of `lo`..`hi`
+    bytes under tactic `t`: every blob of a PUT is stored at its first
+    blob's shard size (blob/access.py)."""
+    def size(length):
+        return max(-(-min(length, blob_size) // t.n), t.min_shard_size)
+
+    return size(lo), size(hi)
+
+
 class RepairWorker:
     def __init__(self, scheduler_client: rpc.Client, cm_client: rpc.Client,
                  node_pool, engine: str | None = "auto",
@@ -76,6 +91,38 @@ class RepairWorker:
         self._thread: threading.Thread | None = None
         self.completed = 0
         self.failed = 0
+
+    def ready(self, max_object_bytes: int, policies=None,
+              blob_size: int | None = None) -> int:
+        """What a worker host does once at start-up, from what it knows:
+        the cluster's size-class policies and its largest object. Every
+        program the repair of a Reed-Solomon unit can ask the device for
+        — any sizes in the volume, any lost unit, any survivor set — is
+        built here: one zero step through the worker's own door at each
+        shape of `rs_kernel.repair_steps`, REPAIR_ROWS rows by the
+        codemode's n columns. Never implied by construction; returns the
+        number of steps. LRC and MSR volumes are not listed: a local
+        stripe's and a sub-shard decode's programs are still built by
+        their first step."""
+        from .access import AccessConfig
+
+        cfg = AccessConfig()
+        policies = cfg.policies if policies is None else policies
+        blob_size = cfg.blob_size if blob_size is None else blob_size
+        steps = 0
+        for p in policies:
+            lo, hi = max(1, p.min_size), min(p.max_size, max_object_bytes)
+            t = cm.tactic(cm.CodeMode[p.mode_name])
+            if not p.enable or lo > hi or t.l or t.is_msr():
+                continue
+            rows = np.zeros((rs_kernel.REPAIR_ROWS, t.n), dtype=np.uint8)
+            for b, width in rs_kernel.repair_steps(
+                    *repair_shard_sizes(t, lo, hi, blob_size),
+                    self.batch_stripes):
+                self.codec.matrix_apply(
+                    rows, np.zeros((b, t.n, width), dtype=np.uint8))
+                steps += 1
+        return steps
 
     # ---------------- loop ----------------
     def start(self, idle_wait: float = 0.5) -> None:
@@ -148,7 +195,8 @@ class RepairWorker:
         t = cm.tactic(vol.codemode)
         bad = int(task["unit_index"])
 
-        # discover the blob population from surviving units' chunk listings
+        # discover the blob population, bids and shard sizes, from a
+        # surviving unit's chunk listing
         bids = self._list_bids(vol, exclude=bad)
         dest = self.nodes.get(task["dest_addr"])
         if not bids:
@@ -156,7 +204,8 @@ class RepairWorker:
 
         if t.is_msr() and _msr_repair_enabled():
             try:
-                return self._execute_msr(task, vol, t, bad, bids, dest)
+                return self._execute_msr(task, vol, t, bad,
+                                         [b for b, _ in bids], dest)
             except MsrFallback as e:
                 # exactly-once degradation: the sub-shard path never
                 # wrote anything (reads and verification both precede
@@ -167,8 +216,8 @@ class RepairWorker:
         self._execute_conventional(task, vol, t, bad, bids, dest)
 
     def _execute_conventional(self, task: dict, vol: VolumeInfo,
-                              t: cm.Tactic, bad: int, bids: list[int],
-                              dest) -> None:
+                              t: cm.Tactic, bad: int,
+                              bids: list[tuple[int, int]], dest) -> None:
         # choose the read set: prefer the bad unit's local stripe peers
         # when an LRC local repair is possible (intra-AZ bandwidth). A
         # dark AZ (blackout) starves the local read set entirely — fall
@@ -199,6 +248,12 @@ class RepairWorker:
                 # read survivor set selects the decode matrix, so per-
                 # shard read failures mid-task are fine.
                 want = min(n_solve + 1, len(read_set))
+                # the groups, planned from the listing: a bid's step is
+                # the width rung of its size; which of the rung's groups
+                # it joins is decided by the survivors its reads return.
+                # An MSR stripe's rows are cut into sub-shards, which
+                # takes one size a step: those group by exact size.
+                exact = t.is_msr() and bad_sub < total_code
                 by_key: dict[tuple, list] = defaultdict(list)
                 # units found on a disk that does not serve (a second
                 # lost disk: a two-loss stripe) are skipped for the rest
@@ -206,13 +261,19 @@ class RepairWorker:
                 # units, the first n solve, the next one checks
                 lost: set[int] = set()
                 try:
-                    for bid in bids:
+                    for bid, size in bids:
                         subs, shards = self._read_survivors(
                             vol, read_set, code_pos, bid, need=n_solve,
                             want=want, failed_az=vol.units[bad].az,
                             lost=lost)
-                        by_key[(len(shards[0]), tuple(subs))].append(
-                            (bid, shards))
+                        if any(len(shard) != size for shard in shards):
+                            raise RuntimeError(
+                                f"bid {bid}: survivors hold "
+                                f"{sorted({len(x) for x in shards})} B, the "
+                                f"chunk listing says {size}")
+                        wide = size if exact else rs_kernel.rung_width(size)
+                        by_key[(wide, tuple(subs))].append(
+                            (bid, size, shards))
                 except RuntimeError:
                     if source != sources[-1]:
                         continue  # local stripe unreadable: widen global
@@ -220,15 +281,17 @@ class RepairWorker:
                 break
 
         self._decode_writeback(task, t, by_key, n_solve, total_code,
-                               bad_sub, dest)
+                               bad_sub, exact, dest)
 
     def _decode_writeback(self, task, t, by_key, n_solve, total_code,
-                          bad_sub, dest) -> None:
+                          bad_sub, exact, dest) -> None:
         writes: list[tuple[int, bytes]] = []
         with tracelib.stage("decode"):
-            self._decode_groups(t, by_key, n_solve, total_code, bad_sub,
-                                writes)
+            steps = self._decode_groups(t, by_key, n_solve, total_code,
+                                        bad_sub, exact, writes)
         self._write_back(task, dest, writes)
+        if tracelib.enabled():
+            metrics.repair_steps_per_task.observe(steps)
 
     def _write_back(self, task: dict, dest,
                     writes: list[tuple[int, bytes]]) -> None:
@@ -243,73 +306,132 @@ class RepairWorker:
                 if tracelib.enabled():
                     metrics.repair_bytes_rebuilt.inc(len(shard))
 
+    def _repair_rows(self, t, subs, n_solve, total_code, bad_sub
+                     ) -> tuple[np.ndarray, int, int | None]:
+        """(the group's matrix, the lost unit's row in it, the checking
+        survivor's row or None) for the survivors `subs` as read."""
+        solve_subs, wanted_out = solve_and_wanted(subs, n_solve, bad_sub)
+        if bad_sub >= total_code:
+            # global fallback for a LOCAL PARITY unit: its row lives
+            # outside the global code space, so compose the local
+            # encode row with the global solve
+            rows = rs_kernel.lrc_reconstruct_rows(
+                n_solve, total_code, t.ec_layout_by_az(),
+                (t.n + t.m) // t.az_count, solve_subs, wanted_out
+            )
+        elif t.is_msr():
+            # conventional decode of an MSR-coded stripe: k full
+            # shards solved with the product-matrix generator over
+            # the sub-shard space (this IS the CUBEFS_CODEC_MSR=0
+            # control path and the helper-failure fallback)
+            rows = rs_kernel.msr_reconstruct_rows(
+                n_solve, total_code, t.d,
+                tuple(solve_subs), tuple(wanted_out))
+        else:
+            rows = rs_kernel.reconstruct_rows(
+                n_solve, total_code, solve_subs, wanted_out
+            )
+        if len(rows) < rs_kernel.REPAIR_ROWS:
+            # no extra survivor: the lost unit's row twice, so the step
+            # still runs one of the programs `ready` built
+            rows = np.repeat(rows, rs_kernel.REPAIR_ROWS, axis=0)
+        verify_pos = (wanted_out.index(subs[n_solve])
+                      if len(subs) > n_solve else None)
+        return rows, wanted_out.index(bad_sub), verify_pos
+
+    def _step_array(self, shape: tuple) -> np.ndarray:
+        """The array of one decode step, to be filled and zeroed by its
+        caller: whatever it held before is nobody's business."""
+        return np.empty(shape, dtype=np.uint8)
+
     def _decode_groups(self, t, by_key, n_solve, total_code, bad_sub,
-                       writes) -> None:
-        for (size, subs), group in by_key.items():
-            solve_subs, wanted_out = solve_and_wanted(subs, n_solve, bad_sub)
-            if len(subs) > n_solve:  # reconstructed bad + the extra survivor
-                verify_pos = wanted_out.index(subs[n_solve])
-            if bad_sub >= total_code:
-                # global fallback for a LOCAL PARITY unit: its row lives
-                # outside the global code space, so compose the local
-                # encode row with the global solve
-                rows = rs_kernel.lrc_reconstruct_rows(
-                    n_solve, total_code, t.ec_layout_by_az(),
-                    (t.n + t.m) // t.az_count, solve_subs, wanted_out
-                )
-            elif t.is_msr():
-                # conventional decode of an MSR-coded stripe: k full
-                # shards solved with the product-matrix generator over
-                # the sub-shard space (this IS the CUBEFS_CODEC_MSR=0
-                # control path and the helper-failure fallback)
-                rows = rs_kernel.msr_reconstruct_rows(
-                    n_solve, total_code, t.d,
-                    tuple(solve_subs), tuple(wanted_out))
-            else:
-                rows = rs_kernel.reconstruct_rows(
-                    n_solve, total_code, solve_subs, wanted_out
-                )
-            out_pos = wanted_out.index(bad_sub)
+                       exact, writes) -> int:
+        """One device step per group and `batch_stripes` bids; returns
+        the number of steps."""
+        steps = 0
+        for (wide, subs), group in by_key.items():
+            rows, out_pos, verify_pos = self._repair_rows(
+                t, subs, n_solve, total_code, bad_sub)
             for start in range(0, len(group), self.batch_stripes):
                 chunk = group[start : start + self.batch_stripes]
-                msr_sub = t.is_msr() and bad_sub < total_code
-                with tracelib.stage("decode_stack"):
-                    # survivors land once, in the array the step takes
-                    # as it is: at the width rung, zeros past `size`
-                    # (MSR rows are cut into sub-shards first, so theirs
-                    # stay `size` wide and the batcher pads them)
-                    wide = size if msr_sub else rs_kernel.rung_width(size)
-                    batch = np.empty((len(chunk), n_solve, wide),
-                                     dtype=np.uint8)
-                    batch[:, :, size:] = 0
-                    for b, (_, shards) in enumerate(chunk):
-                        for r, shard in enumerate(shards[:n_solve]):
-                            batch[b, r, :size] = np.frombuffer(
-                                shard, dtype=np.uint8)
-                if msr_sub:
-                    if size % t.alpha:
-                        raise RuntimeError(
-                            f"shard size {size} not divisible by "
-                            f"alpha={t.alpha}: not MSR-encoded")
-                    sub = batch.reshape(
-                        len(chunk), n_solve * t.alpha, size // t.alpha)
-                    recovered = self.codec.matrix_apply(rows, sub).reshape(
-                        len(chunk), len(wanted_out), size)
-                else:
-                    recovered = self.codec.matrix_apply(rows, batch,
-                                                        width=size)
-                with tracelib.stage("decode_verify"):
-                    for (bid, shards), rec in zip(chunk, recovered):
-                        if len(subs) > n_solve:
-                            expect = np.frombuffer(shards[n_solve],
-                                                   dtype=np.uint8)
-                            if not np.array_equal(rec[verify_pos], expect):
+                sizes = [size for _, size, _ in chunk]
+                span = tracelib.start_span("stage:decode_step")
+                with span:
+                    batch = self._stack(t, wide, exact, n_solve, chunk,
+                                        sizes, span)
+                    if exact:
+                        recovered = self.codec.matrix_apply(
+                            rows, batch.reshape(
+                                len(chunk), n_solve * t.alpha,
+                                wide // t.alpha)).reshape(
+                                    len(chunk), -1, wide)
+                    else:
+                        recovered = self.codec.matrix_apply(rows, batch,
+                                                            width=sizes)
+                    steps += 1
+                    with tracelib.stage("decode_verify"):
+                        for (bid, size, shards), rec in zip(chunk,
+                                                            recovered):
+                            # cut to the bid's own size first: what is
+                            # checked and written back never holds pad
+                            if verify_pos is not None and not np.array_equal(
+                                    rec[verify_pos, :size], np.frombuffer(
+                                        shards[n_solve], dtype=np.uint8)):
                                 raise RuntimeError(
                                     f"bid {bid}: reconstruction disagrees "
                                     f"with extra survivor {subs[n_solve]} — "
                                     f"refusing writeback (crc-conflict role)"
                                 )
-                        writes.append((bid, rec[out_pos].tobytes()))
+                            writes.append(
+                                (bid, rec[out_pos, :size].tobytes()))
+                    # let go only now: unmapping half a gigabyte between
+                    # the step and the loop over its result slows that
+                    # loop by a fifth on the chip's host (PERF.md
+                    # section 6, PR 36), and two such arrays must never
+                    # be alive together
+                    del batch
+        return steps
+
+    def _stack(self, t, wide, exact, n_solve, chunk, sizes, span
+               ) -> np.ndarray:
+        """The array of one chunk of a group: survivors land once, each
+        bid at its own size and zeros past it, in a shape `ready` built
+        a program for — `rs_kernel.repair_step_shape`: the group's width
+        rung, zero stripes up to a stripe rung — which the batcher
+        passes whole."""
+        if exact:
+            # an MSR stripe, one exact size a step (the group's key):
+            # the rows are cut into alpha sub-shards each, and the
+            # batcher pads those
+            if wide % t.alpha:
+                raise RuntimeError(
+                    f"shard size {wide} not divisible by "
+                    f"alpha={t.alpha}: not MSR-encoded")
+            shape = (len(chunk), n_solve, wide)
+        else:
+            rung_b, rung_s = rs_kernel.repair_step_shape(
+                len(chunk), wide, self.batch_stripes)
+            shape = (rung_b, n_solve, rung_s)
+        with tracelib.stage("decode_stack"):
+            batch = self._step_array(shape)
+            # every zero first, in address order, then the survivors:
+            # on the chip's host a (64, 12, 720896) array fills in
+            # ~510 ms this way and ~580 ms with each bid's pad zeroed
+            # after its rows (PERF.md section 6, PR 36)
+            for b, size in enumerate(sizes):
+                batch[b, :, size:] = 0
+            batch[len(chunk):] = 0
+            for b, (_, size, shards) in enumerate(chunk):
+                for r, shard in enumerate(shards[:n_solve]):
+                    batch[b, r, :size] = np.frombuffer(shard,
+                                                       dtype=np.uint8)
+        pad = batch.nbytes - n_solve * sum(sizes)
+        span.set_tag("stage", "decode_step").set_tag("bids", len(chunk))
+        span.set_tag("rung_b", shape[0]).set_tag("rung_s", shape[2])
+        span.set_tag("widths", len(set(sizes))).set_tag("pad_bytes", pad)
+        if tracelib.enabled():
+            metrics.repair_widths_per_step.observe(len(set(sizes)))
+        return batch
 
     def _execute_msr(self, task: dict, vol: VolumeInfo, t: cm.Tactic,
                      bad: int, bids: list[int], dest) -> None:
@@ -440,7 +562,10 @@ class RepairWorker:
         except Exception:
             pass
 
-    def _list_bids(self, vol: VolumeInfo, exclude: int) -> list[int]:
+    def _list_bids(self, vol: VolumeInfo, exclude: int
+                   ) -> list[tuple[int, int]]:
+        """(bid, shard size) of every blob of the volume, from the
+        chunk listing of the first unit that gives one."""
         for u in vol.units:
             if u.index == exclude:
                 continue
@@ -448,7 +573,7 @@ class RepairWorker:
                 meta, _ = self.nodes.get(u.node_addr).call(
                     "list_chunk", {"disk_id": u.disk_id, "chunk_id": u.chunk_id}
                 )
-                return [b for b, _, _ in meta["shards"]]
+                return [(b, size) for b, size, _ in meta["shards"]]
             except rpc.RpcError:
                 continue
         raise RuntimeError(f"vid {vol.vid}: no unit listable")

@@ -45,6 +45,7 @@ import logging
 import os
 import threading
 import time
+from collections.abc import Sequence
 
 import numpy as np
 
@@ -80,15 +81,18 @@ class CodecFuture:
     never need one (Event allocation and signalling are the admission
     layer's hottest per-submission costs)."""
 
-    __slots__ = ("arr", "stripes", "width", "value", "exc", "done",
-                 "event", "enq_t", "ref", "_batcher", "_key")
+    __slots__ = ("arr", "stripes", "widths", "width", "value", "exc",
+                 "done", "event", "enq_t", "ref", "_batcher", "_key")
 
     def __init__(self, batcher: "BatchCodec", key: tuple, arr: np.ndarray,
-                 width: int):
+                 widths: tuple):
         self.arr = arr
         self.stripes = int(arr.shape[0])
-        # payload columns: the rows handed back are [:, :, :width]
-        self.width = width
+        # payload columns of each live stripe, the array's first
+        # len(widths); the rest of it is pad. The rows handed back are
+        # [:len(widths), :, :width]
+        self.widths = widths
+        self.width = max(widths)
         self.value = None
         self.exc: BaseException | None = None
         self.done = False
@@ -222,7 +226,8 @@ class BatchCodec:
 
     def submit_encode_async(self, engine: str | None, data: np.ndarray,
                             n_parity: int, timeout: float = 120.0,
-                            width: int | None = None) -> CodecFuture:
+                            width: int | Sequence[int] | None = None
+                            ) -> CodecFuture:
         """submit_encode that parks and returns immediately: collect
         with .result(). A caller pipelining K submissions before its
         first collect keeps K stripes continuously admitted — the
@@ -231,13 +236,18 @@ class BatchCodec:
         ``width``: a caller that built ``data`` at its width rung
         (rs_kernel.rung_width, zeros past its shard size) says how many
         columns are payload: a step of that submission alone takes the
-        array as it is, and the rows come back ``[:, :, :width]``."""
+        array as it is, and the rows come back ``[:, :, :width]``. A
+        sequence gives each stripe's own count (a repair step's bids
+        have the sizes they have) and may be shorter than the array: the
+        stripes past it are pad too (zero stripes up to a stripe rung),
+        and the rows come back ``[:len(width), :, :max(width)]``."""
         key, coeff, arr = self._prep_encode(engine, data, n_parity)
         return self._enqueue(key, coeff, arr, timeout, width)
 
     def submit_apply_async(self, engine: str | None, coeff: np.ndarray,
                            shards: np.ndarray, timeout: float = 120.0,
-                           width: int | None = None) -> CodecFuture:
+                           width: int | Sequence[int] | None = None
+                           ) -> CodecFuture:
         """submit_apply that parks and returns immediately."""
         key, coeff, arr = self._prep_apply(engine, coeff, shards)
         return self._enqueue(key, coeff, arr, timeout, width)
@@ -262,9 +272,19 @@ class BatchCodec:
 
     def _enqueue(self, key: tuple, coeff: np.ndarray | None,
                  arr: np.ndarray, timeout: float,
-                 width: int | None = None) -> CodecFuture:
-        sub = CodecFuture(self, key, arr, int(
-            arr.shape[2] if width is None else width))
+                 width: int | Sequence[int] | None = None) -> CodecFuture:
+        b, s = int(arr.shape[0]), int(arr.shape[2])
+        if width is None:
+            widths = (s,) * b
+        elif isinstance(width, (int, np.integer)):
+            widths = (int(width),) * b
+        else:
+            widths = tuple(int(w) for w in width)
+        if not (0 < len(widths) <= b and 0 <= min(widths)
+                and max(widths) <= s):
+            raise ValueError(f"{key[0]}: payload widths {widths[:4]}.. of "
+                             f"{len(widths)} stripes do not fit {arr.shape}")
+        sub = CodecFuture(self, key, arr, widths)
         with self._lock:
             # backpressure: block only while a drain in flight will
             # free space — the submitter who finds everything idle
@@ -362,7 +382,8 @@ class BatchCodec:
         op = key[0]
         # admitted-stripe accounting lands here, once per swap — per-
         # submission counter locks are measurable at this call rate
-        metrics.codec_batch_submissions.inc(total, op=op)
+        metrics.codec_batch_submissions.inc(
+            sum(len(sub.widths) for sub in batch), op=op)
         # the key carries the geometry: encode (.., n, m, rung), apply
         # (.., coeff, c, rung); the cap is reckoned on the rung, the
         # width every stripe of the step goes up at
@@ -410,12 +431,16 @@ class BatchCodec:
         gather_t0 = time.perf_counter()
         first = step[0].arr
         cols = int(first.shape[1])
-        n_stripes = sum(sub.stripes for sub in step)
-        rung_b, rung_s = rs_kernel.step_shape(cols, n_stripes, int(key[4]))
+        rung_b, rung_s = rs_kernel.step_shape(
+            cols, sum(sub.stripes for sub in step), int(key[4]))
         shape = (rung_b, cols, rung_s)
         gathered = len(step) > 1 or first.shape != shape
         arr = self._gather(step, shape) if gathered else first
-        payload = cols * sum(sub.stripes * sub.width for sub in step)
+        # what the submissions brought, live stripe by live stripe; the
+        # rest of the rung-shaped array is pad
+        live = [w for sub in step for w in sub.widths]
+        n_stripes = len(live)
+        payload = cols * sum(live)
         pad = arr.nbytes - payload
         wait_now = time.perf_counter()
         metrics.codec_batch_wait.observe_many(
@@ -448,17 +473,16 @@ class BatchCodec:
             count_payload, count_pad, widths = _step_series(op)
             count_payload(payload)
             count_pad(pad)
-            widths.observe(len({sub.width for sub in step}))
+            widths.observe(len(set(live)))
         metrics.codec_batch_stripes.observe(n_stripes, op=op)
         off = 0
         for sub in step:  # resolve inlined: this is the hottest loop
-            end = off + sub.stripes
-            sub.value = out[off:end, :, :sub.width]
+            sub.value = out[off:off + len(sub.widths), :, :sub.width]
             sub.done = True  # write order: done before the event read
             ev = sub.event
             if ev is not None:
                 ev.set()
-            off = end
+            off += sub.stripes
 
     def _gather(self, step: list[CodecFuture], shape: tuple) -> np.ndarray:
         """The step's rung-shaped array: every submission's rows at its
@@ -611,9 +635,11 @@ class AdmittedEngine:
         return out.reshape(*lead, *out.shape[-2:])
 
     def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray,
-                     width: int | None = None) -> np.ndarray:
+                     width: int | Sequence[int] | None = None
+                     ) -> np.ndarray:
         """``width``: payload columns of a (B, C, S) array its caller
-        built at the width rung (submit_encode_async's contract)."""
+        built at the width rung, one count or one a live stripe
+        (submit_encode_async's contract)."""
         shards = np.asarray(shards)
         if shards.ndim < 2:
             raise ValueError(

@@ -339,6 +339,59 @@ def ladder(cols: int, s_lo: int, s_hi: int, max_step_bytes: int,
     return out
 
 
+# A repair step is one submission of up to a task's worth of stripes,
+# so it could ask for any of rung_batch's sixteen stripe rungs up to 64
+# at every width rung: hundreds of programs a codemode, each built by
+# the first task that meets it. The repair worker asks for few instead:
+# always REPAIR_ROWS rows (the lost unit's and the check's; the lost
+# one twice where no extra survivor was read) and a stripe rung of
+# ``repair_batches`` — zero stripes up to it — so the set is small
+# enough to build before the first task (blob/worker.py:
+# RepairWorker.ready). Each is a rung of rung_batch, so the batcher
+# passes the worker's array as it is.
+REPAIR_ROWS = 2
+
+
+def repair_batches(max_stripes: int) -> list[int]:
+    """The stripe rungs of a repair step of up to ``max_stripes``
+    stripes (the worker's batch_stripes): STEP_BATCH doubling, the rung
+    at three quarters of the top one, and the rung that holds
+    ``max_stripes`` — 8, 16, 32, 48, 64. Every zero stripe of a wide
+    rung is megabytes of fresh pages on the host and of transfer, and
+    past half the top rung doubling would add up to as many again as
+    the step holds (PERF.md section 6, PR 36)."""
+    top = rung_batch(max_stripes)
+    out, b = [], STEP_BATCH
+    while b < top:
+        out.append(b)
+        b <<= 1
+    mid = rung_batch(3 * top // 4)
+    if out and out[-1] < mid < top:
+        out.append(mid)
+    return out + [top]
+
+
+def repair_step_shape(b: int, s: int, max_stripes: int) -> tuple[int, int]:
+    """(B_rung, S_rung) of the repair step that holds ``b`` <=
+    ``max_stripes`` stripes whose widest shard is ``s`` bytes."""
+    return (next(r for r in repair_batches(max_stripes) if r >= b),
+            rung_width(s))
+
+
+def repair_steps(s_lo: int, s_hi: int, max_stripes: int
+                 ) -> list[tuple[int, int]]:
+    """Every (B_rung, S_rung) a repair step can run at when a volume's
+    shards are ``s_lo``..``s_hi`` bytes: sorted, finite. With the row
+    count fixed at REPAIR_ROWS and the columns by the codemode's n, a
+    program a shape."""
+    out = []
+    s, top = rung_width(s_lo), rung_width(s_hi)
+    while s <= top:
+        out += [(b, s) for b in repair_batches(max_stripes)]
+        s = rung_width(s + 1)
+    return out
+
+
 @progcache.cached("rs_jit")
 def _bits_fn(rows: int, cols: int, shape: tuple):
     """The jnp bit-matmul program for this shape: ``apply(w, shards)``,

@@ -351,16 +351,16 @@ codec_batch_stripes = DEFAULT.histogram(
     "stripes coalesced per drained device step (1 = uncontended)",
     ("op",), buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024))
 # a step runs at a rung of rs_kernel's ladder: `payload` is what its
-# submissions brought (stripes x rows x their own widths), `pad` the
+# submissions brought (rows x each live stripe's own width), `pad` the
 # zero columns and zero stripes up to the rung; widths counts the
-# distinct submission widths that met in one step
+# distinct payload widths that met in one step
 codec_step_bytes = DEFAULT.counter(
     "cubefs_codec_step_bytes_total",
     "input bytes of drained device steps (payload / pad)",
     ("op", "kind"))
 codec_batch_widths = DEFAULT.histogram(
     "cubefs_codec_batch_widths_per_step",
-    "distinct submission widths coalesced per drained device step",
+    "distinct payload widths coalesced per drained device step",
     ("op",), buckets=(1, 2, 3, 4, 6, 8))
 codec_batch_wait = DEFAULT.histogram(
     "cubefs_codec_batch_wait_seconds",
@@ -432,6 +432,17 @@ repair_bytes_pulled = DEFAULT.counter(
 repair_bytes_rebuilt = DEFAULT.counter(
     "cubefs_repair_bytes_rebuilt_total",
     "rebuilt shard bytes whose write-back the destination acknowledged")
+# the conventional decode groups a task's bids by (width rung, survivor
+# set): one device step a group and batch_stripes bids, whatever sizes
+# the bids have
+repair_steps_per_task = DEFAULT.histogram(
+    "cubefs_repair_steps_per_task",
+    "decode steps of one finished unit-repair task",
+    buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, 128))
+repair_widths_per_step = DEFAULT.histogram(
+    "cubefs_repair_widths_per_step",
+    "distinct shard sizes among the bids of one decode step",
+    buckets=(1, 2, 4, 8, 16, 32, 64))
 repair_subshard_reads = DEFAULT.counter(
     "cubefs_repair_subshard_reads_total",
     "beta-sized helper symbols served through read_subshard (one per "

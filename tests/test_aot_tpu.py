@@ -135,3 +135,31 @@ def test_pallas_crc_compiles_for_v5e(v5e_sharding):
         m_pl.temp_size_in_bytes, payload)
     assert m_pl.temp_size_in_bytes < m_jnp.temp_size_in_bytes / 2, (
         m_pl.temp_size_in_bytes, m_jnp.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("rows,cols,stripes,tiles", [
+    (2, 3, 64, 3),    # EC3P3's widest rung: the jnp program, 64 stripes
+    (2, 6, 8, 4),     # EC6P6: the fused program's narrowest rung
+    (2, 6, 32, 22),   # EC6P6 at a full 4 MiB object's shard
+    (2, 12, 16, 11),  # EC12P4's narrowest rung
+])
+def test_repair_step_programs_compile_for_v5e(v5e_sharding, rows, cols,
+                                              stripes, tiles):
+    """The repair worker's steps (PR 36): two rows by a codemode's n
+    columns at (stripe rung, n, width rung) — shapes `RepairWorker.ready`
+    builds on the chip before the first task. The program is the one
+    `rs_kernel.plan` picks there: fused from four tiles up."""
+    tile = pallas_gf.DEFAULT_TILE
+    shape = (stripes, cols, tiles * tile)
+    assert rs_kernel.repair_step_shape(
+        stripes - 1, shape[2] - 5, 64) == (stripes, shape[2])
+    if rs_kernel._pallas_profitable(shape[2]):
+        program = pallas_gf._apply_fn.__wrapped__(rows, cols, shape, tile,
+                                                  False)
+    else:
+        program = rs_kernel.gf_apply_bits
+    w = jax.ShapeDtypeStruct((8 * rows, 8 * cols), jnp.int8,
+                             sharding=v5e_sharding)
+    x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=v5e_sharding)
+    m = jax.jit(program).lower(w, x).compile().memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 4 * (1 << 30)

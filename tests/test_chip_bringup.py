@@ -380,7 +380,16 @@ def test_chip_smoke_phases_at_tiny_sizes(tmp_path, capsys):
     assert list(out)[-1] == "claim"
     assert set(out["phases"]) == {"put", "get", "reference", "break_repair",
                                   "sidecar", "two_loss", "any_size",
-                                  "device_proof", "checkout_clean"}
+                                  "repair_any_size", "device_proof",
+                                  "checkout_clean"}
+    # a mixed-size volume per size class, one unit of each rebuilt after
+    # the worker's ready: by width rung, and nothing built
+    repair = out["phases"]["repair_any_size"]
+    assert repair["tasks"] == 3 and repair["ready_steps"] == 9
+    assert repair["rebuilt_shards_checked"] >= 15
+    assert repair["programs_built_after_ready"] == 0
+    assert 3 <= repair["decode_steps"] < sum(
+        repair["distinct_shard_sizes"].values())
     # sizes nobody named, after the front door's ready: nothing built
     any_size = out["phases"]["any_size"]
     assert any_size["objects"] == 6 and any_size["ready_steps"] > 0
