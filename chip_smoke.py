@@ -376,24 +376,46 @@ def _capture_disk(dep: Deployment, disk_id: int) -> dict:
     return held
 
 
+def _second_disk(dep: Deployment, objects: dict, vid: int,
+                 spare: tuple) -> int:
+    """The disk under the first later unit of volume `vid` that is not
+    unit 0's and holds nothing of the volume of object `spare` (the MSR
+    one: with two units lost its sub-shard repair has too few helpers
+    and would fall back, which this phase treats as a miss)."""
+    units = dep.cm.get_volume(vid).units
+    avoid = {u.disk_id for u in dep.cm.get_volume(
+        objects[spare][1].slices[0].vid).units} | {units[0].disk_id}
+    for u in units[1:]:
+        if u.disk_id not in avoid:
+            return u.disk_id
+    raise RuntimeError(f"every disk of vid {vid} also holds a unit of "
+                       f"the MSR volume: no second disk to lose")
+
+
 def phase_break_repair(dep: Deployment, sizes: Sizes,
                        objects: dict) -> dict:
     """Break the disk under data shard 0 of an EC12P4, the LRC and the
     MSR volume (one disk where they share it, else one after another):
     degraded GET, scheduler -> worker repair, rebuilt shards compared
-    with the copies captured before the break, healthy GET after."""
+    with the copies captured before the break, healthy GET after. With
+    the EC12P4 volume's disk a second disk under another of its units
+    is lost too: the scheduler leases the volume's two tasks together
+    and the worker rebuilds both units from one read of the survivors
+    (`cubefs_repair_task_reads_total`: one `shared`)."""
     from cubefs_tpu.blob.types import DiskStatus
     from cubefs_tpu.utils import metrics
 
     targets = [(0, 0), (3, 0), (4, 0)]  # large, lrc, msr
     fallbacks0 = dict(metrics.repair_msr_fallbacks.samples())
+    shared0 = metrics.repair_task_reads.value(reads="shared")
     rounds = []
     bytes_rebuilt = 0
     pending = list(targets)
     while pending:
         # the disk under shard 0 of the first pending target, plus any
         # other pending target with a DATA shard on that same disk
-        vid0 = objects[pending[0]][1].slices[0].vid
+        pending0 = pending[0]
+        vid0 = objects[pending0][1].slices[0].vid
         disk = dep.cm.get_volume(vid0).units[0].disk_id
         hit = []
         for key in pending:
@@ -405,9 +427,14 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
         pending = [k for k in pending if k not in hit]
 
         t0 = time.perf_counter()
-        held = _capture_disk(dep, disk)
-        node = next(n for n in dep.nodes.values() if disk in n.disk_ids)
-        node.break_disk(disk)
+        disks = [disk]
+        if pending0 == targets[0]:
+            disks.append(_second_disk(dep, objects, vid0, targets[2]))
+        held = {}
+        for d in disks:
+            held.update(_capture_disk(dep, d))
+            node = next(n for n in dep.nodes.values() if d in n.disk_ids)
+            node.break_disk(d)
 
         recon0 = sum(v for _, v in metrics.reconstruct_reads.samples())
         phase_get(dep, sizes, objects, hit)
@@ -418,9 +445,9 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
                 f"disk {disk}: {len(hit)} degraded GETs but only "
                 f"{degraded} reconstruct reads counted")
 
-        n_tasks = dep.sched.mark_disk_broken(disk)
+        n_tasks = sum(dep.sched.mark_disk_broken(d) for d in disks)
         if n_tasks != len(held):
-            raise RuntimeError(f"disk {disk}: {len(held)} units held, "
+            raise RuntimeError(f"disks {disks}: {len(held)} units held, "
                                f"{n_tasks} repair tasks queued")
         for _ in range(n_tasks * (dep.sched.MAX_ATTEMPTS + 1)):
             if not dep.worker.run_once():
@@ -430,14 +457,15 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
                            dep.sched.tasks.values() if t.get("last_error")})
             raise RuntimeError(f"disk {disk}: {dep.worker.failed} repair "
                                f"task runs failed: {errs[:3]}")
-        if dep.cm.disks[disk].status != DiskStatus.REPAIRED:
-            raise RuntimeError(f"disk {disk} not REPAIRED after the drain")
+        if any(dep.cm.disks[d].status != DiskStatus.REPAIRED
+               for d in disks):
+            raise RuntimeError(f"disks {disks} not REPAIRED after the drain")
 
         for (vid, index), shards in held.items():
             u = dep.cm.get_volume(vid).units[index]
-            if u.disk_id == disk:
+            if u.disk_id in disks:
                 raise RuntimeError(f"vid {vid} unit {index} still on "
-                                   f"broken disk {disk}")
+                                   f"broken disk {u.disk_id}")
             for bid, want in shards.items():
                 if dep.unit_call(u, "get_shard", bid)[1] != want:
                     raise RuntimeError(
@@ -449,10 +477,10 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
         # bytes come back. (Not "zero reconstruct reads": a hedged GET
         # may legitimately decode from parity when a data read is slow.)
         phase_get(dep, sizes, objects, hit)
-        rounds.append({"disk": disk, "units": len(held),
+        rounds.append({"disk": disk, "disks": disks, "units": len(held),
                        "targets": [list(k) for k in hit],
                        "smoke_wall_s": round(time.perf_counter() - t0, 3)})
-        log(f"disk {disk}: {len(held)} units rebuilt bit-identical, "
+        log(f"disks {disks}: {len(held)} units rebuilt bit-identical, "
             f"targets {hit}, {rounds[-1]['smoke_wall_s']} s "
             f"(smoke wall time)")
     fallbacks = {k: v for k, v in metrics.repair_msr_fallbacks.samples()
@@ -460,7 +488,14 @@ def phase_break_repair(dep: Deployment, sizes: Sizes,
     if fallbacks:
         raise RuntimeError(f"MSR sub-shard repair fell back to the "
                            f"conventional decode: {fallbacks}")
+    shared = metrics.repair_task_reads.value(reads="shared") - shared0
+    if shared < 1:
+        raise RuntimeError(
+            f"two units of vid {objects[targets[0]][1].slices[0].vid} "
+            f"were lost together and no task was decoded from its "
+            f"sibling's read of the survivors")
     return {"ok": True, "rounds": rounds, "bytes_rebuilt": bytes_rebuilt,
+            "shared_reads": int(shared),
             "repair_decode_legs": {
                 engine: v for (op, engine), v
                 in metrics.codec_batch_steps.samples() if op == "apply"}}
@@ -627,17 +662,48 @@ def phase_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
             "smoke_wall_s": round(time.perf_counter() - t0, 3)}
 
 
+def _check_rebuilt_unit(dep: Deployment, objects: list, t, unit,
+                        bad: int) -> list[int]:
+    """Every shard of rebuilt unit `bad` against the reference stripe's
+    row of the blob that was PUT there, and its stored CRC against
+    zlib's; returns the shards' lengths."""
+    from cellbench import reference
+    from cubefs_tpu.codec import codemode as cm
+
+    lengths = []
+    for data, o in objects:
+        sl = o.slices[0]
+        whole = data if sl.count == 1 else data.ljust(
+            sl.count * sl.blob_size, b"\0")
+        for b in range(sl.count):
+            ref = reference.stripe(
+                whole[b * sl.blob_size:(b + 1) * sl.blob_size],
+                t.n, t.m, t.min_shard_size)[bad]
+            meta, got = dep.unit_call(unit, "get_shard", sl.min_bid + b)
+            if got != ref.tobytes():
+                raise RuntimeError(
+                    f"{cm.CodeMode(o.codemode).name} bid {sl.min_bid + b} "
+                    f"unit {bad}: rebuilt shard of {len(got)} B differs "
+                    f"from the reference stripe's {ref.shape[0]} B")
+            if reference.crc32(got) != meta["crc"]:
+                raise RuntimeError(f"bid {sl.min_bid + b} unit {bad}: "
+                                   f"stored crc is not zlib's")
+            lengths.append(len(got))
+    return lengths
+
+
 def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
                           ) -> dict:
-    """A volume of objects of any sizes per size-class codemode, one
-    unit of each rebuilt: after the worker's `ready` (the repair steps
-    its policies can reach, built once) the tasks group their bids by
-    width rung, not one program is built, and every rebuilt shard is the
-    reference stripe's row (cellbench/reference.py) at its exact size.
-    The PUTs go through a proxy allocator, so a codemode's objects share
-    a volume; the repair is queued as an operator's (`manual_migrate`):
-    the worker reads nothing of the unit it rebuilds."""
-    from cellbench import reference
+    """A volume of objects of any sizes per size-class codemode, two
+    units of each rebuilt (a data and a parity one): after the worker's
+    `ready` (the repair steps its policies can reach, built once) the
+    volume's two tasks come in one lease and share one read of the
+    survivors, they group their bids by width rung, not one program is
+    built, and every rebuilt shard is the reference stripe's row
+    (cellbench/reference.py) at its exact size. The PUTs go through a
+    proxy allocator, so a codemode's objects share a volume; the repairs
+    are queued as an operator's (`manual_migrate`): the worker reads
+    nothing of the units it rebuilds."""
     from cubefs_tpu.blob.access import AccessHandler
     from cubefs_tpu.blob.proxy import ProxyAllocator
     from cubefs_tpu.codec import codemode as cm
@@ -660,6 +726,7 @@ def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
     bounds = [4096] + [p.max_size for p in cfg.policies[:2]] + [largest]
     tasks0 = dict(metrics.repair_steps_per_task.samples()).get(
         (), {"count": 0, "sum": 0.0})
+    shared0 = metrics.repair_task_reads.value(reads="shared")
     checked, by_mode = 0, {}
     try:
         for klass in range(3):
@@ -675,9 +742,11 @@ def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
             if {o.slices[0].vid for _, o in objects} != {vid}:
                 raise RuntimeError(f"{sizes.repair_any_size} PUTs of one "
                                    f"size class are not in one volume")
-            bad = (1, t.n + 1, t.n + t.m - 1)[klass]  # data, parity, last
-            old = dep.cm.get_volume(vid).units[bad]
-            dep.sched.manual_migrate(vid, bad)
+            # data + parity, parity + data, last + data
+            bads = ((1, t.n), (t.n + 1, 0), (t.n + t.m - 1, 3))[klass]
+            old = [dep.cm.get_volume(vid).units[bad] for bad in bads]
+            for bad in bads:
+                dep.sched.manual_migrate(vid, bad)
             for _ in range(dep.sched.MAX_ATTEMPTS + 1):
                 if not dep.worker.run_once():
                     break
@@ -685,33 +754,18 @@ def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
                 errs = sorted({x.get("last_error", "") for x in
                                dep.sched.tasks.values()
                                if x.get("last_error")})
-                raise RuntimeError(f"vid {vid} unit {bad}: repair task "
+                raise RuntimeError(f"vid {vid} units {bads}: repair task "
                                    f"runs failed: {errs[:3]}")
-            unit = dep.cm.get_volume(vid).units[bad]
-            if (unit.disk_id, unit.chunk_id) == (old.disk_id, old.chunk_id):
-                raise RuntimeError(f"vid {vid} unit {bad} was not rebuilt")
             widths = set()
-            for data, o in objects:
-                sl = o.slices[0]
-                whole = data if sl.count == 1 else data.ljust(
-                    sl.count * sl.blob_size, b"\0")
-                for b in range(sl.count):
-                    ref = reference.stripe(
-                        whole[b * sl.blob_size:(b + 1) * sl.blob_size],
-                        t.n, t.m, t.min_shard_size)[bad]
-                    meta, got = dep.unit_call(unit, "get_shard",
-                                              sl.min_bid + b)
-                    if got != ref.tobytes():
-                        raise RuntimeError(
-                            f"{cm.CodeMode(o.codemode).name} bid "
-                            f"{sl.min_bid + b} unit {bad}: rebuilt shard "
-                            f"of {len(got)} B differs from the reference "
-                            f"stripe's {ref.shape[0]} B")
-                    if reference.crc32(got) != meta["crc"]:
-                        raise RuntimeError(f"bid {sl.min_bid + b}: stored "
-                                           f"crc is not zlib's")
-                    widths.add(len(got))
-                    checked += 1
+            for bad, was in zip(bads, old):
+                unit = dep.cm.get_volume(vid).units[bad]
+                if (unit.disk_id, unit.chunk_id) == (was.disk_id,
+                                                     was.chunk_id):
+                    raise RuntimeError(
+                        f"vid {vid} unit {bad} was not rebuilt")
+                lengths = _check_rebuilt_unit(dep, objects, t, unit, bad)
+                widths.update(lengths)
+                checked += len(lengths)
             by_mode[cm.CodeMode(loc.codemode).name] = len(widths)
     finally:
         front._pool.shutdown(wait=True)
@@ -720,11 +774,17 @@ def phase_repair_any_size(dep: Deployment, sizes: Sizes, clock: CompileClock
             f"{built() - at_ready} codec programs built and "
             f"{clock.compiles - compiles} programs compiled after ready, "
             f"by the repair of three mixed-size volumes")
+    shared = metrics.repair_task_reads.value(reads="shared") - shared0
+    if shared != 3:
+        raise RuntimeError(f"three volumes of two moving units each: "
+                           f"{shared} tasks shared their sibling's read "
+                           f"of the survivors, not one a volume")
     tasks = dict(metrics.repair_steps_per_task.samples())[()]
     return {"ok": True, "objects_per_codemode": sizes.repair_any_size,
             "distinct_shard_sizes": by_mode, "rebuilt_shards_checked": checked,
             "decode_steps": int(tasks["sum"] - tasks0["sum"]),
             "tasks": int(tasks["count"] - tasks0["count"]),
+            "shared_reads": int(shared),
             "ready_steps": int(steps),
             "programs_built_at_ready": int(at_ready - before),
             "programs_built_after_ready": 0,

@@ -899,20 +899,36 @@ class Scheduler:
 
     # ---------------- task leasing (worker API) ----------------
     def acquire_task(self, worker_id: str) -> dict | None:
+        """Lease the first pending task to `worker_id`; returns
+        {"task": ..., "siblings": [...]} or None. Where the task repairs
+        a unit of a volume, the volume's other pending unit repairs are
+        leased with it, under the same lock — a worker reads a volume's
+        survivors once for all of them (blob/worker.py). Each keeps its
+        own attempts, lease and record, is completed or failed alone,
+        and an expired lease queues its task again alone."""
         now = time.time()
         with self._lock:
+            lease: list[dict] = []
             for t in self.tasks.values():
                 if t["state"] == "leased" and t["lease_until"] < now:
                     t["state"] = "pending"  # lease expired -> requeue
-                if t["state"] == "pending":
-                    t["state"] = "leased"
-                    t["worker"] = worker_id
-                    t["attempts"] += 1
-                    t["lease_until"] = now + self.LEASE_SECONDS
-                    self._record(t["task_id"], "leased", worker=worker_id,
-                                 attempt=t["attempts"])
-                    return dict(t)
-            return None
+                if t["state"] != "pending":
+                    continue
+                if lease and (t["type"] != "unit_repair"
+                              or t["vid"] != lease[0]["vid"]):
+                    continue  # not a sibling of the unit repair leased
+                t["state"] = "leased"
+                t["worker"] = worker_id
+                t["attempts"] += 1
+                t["lease_until"] = now + self.LEASE_SECONDS
+                self._record(t["task_id"], "leased", worker=worker_id,
+                             attempt=t["attempts"])
+                lease.append(dict(t))
+                if t["type"] != "unit_repair":
+                    break
+            if not lease:
+                return None
+            return {"task": lease[0], "siblings": lease[1:]}
 
     def renew_task(self, task_id: str, worker_id: str) -> bool:
         with self._lock:
@@ -1020,8 +1036,8 @@ class Scheduler:
 
     # ---------------- RPC surface ----------------
     def rpc_acquire_task(self, args, body):
-        t = self.acquire_task(args["worker_id"])
-        return {"task": t}
+        return (self.acquire_task(args["worker_id"])
+                or {"task": None, "siblings": []})
 
     def rpc_renew_task(self, args, body):
         return {"ok": self.renew_task(args["task_id"], args["worker_id"])}

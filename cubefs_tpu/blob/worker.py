@@ -12,10 +12,15 @@ zeros past it — so a volume of objects of any sizes repairs in a
 handful of device steps, at shapes whose programs `RepairWorker.ready`
 built before the first task. A rebuilt shard is cut to its bid's size
 before it is checked and written back: no stored shard carries pad.
+The scheduler leases a volume's pending unit repairs together, and those
+of a plain Reed-Solomon volume are decoded from ONE read of its
+survivors (`units_per_read`): each step array is filled once, and one
+decode step a lost unit runs over it.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import threading
 import time
@@ -61,6 +66,30 @@ def solve_and_wanted(subs: list[int], n_solve: int, bad_sub: int
     if len(subs) > n_solve:
         wanted = sorted({bad_sub, subs[n_solve]})
     return list(subs[:n_solve]), wanted
+
+
+def units_per_read(t: cm.Tactic) -> int:
+    """How many unit repairs of one volume one read of its survivors
+    serves. The read leaves out every unit it rebuilds from the start,
+    so it is of the global stripe of a plain Reed-Solomon volume (an LRC
+    unit's local stripe and an MSR unit's helpers are that unit's own),
+    and of as many units as leave n + 1 to read: each keeps the checking
+    survivor it would have had alone."""
+    if t.l or t.is_msr():
+        return 1
+    return max(1, t.m - 1)
+
+
+@dataclasses.dataclass
+class _Unit:
+    """One lost unit of a shared read: its task, its row in the solving
+    code's shard space, where it goes, and what its decode steps gave."""
+    task: dict
+    sub: int
+    dest: object
+    writes: list = dataclasses.field(default_factory=list)
+    steps: int = 0
+    error: Exception | None = None
 
 
 def repair_shard_sizes(t: cm.Tactic, lo: int, hi: int, blob_size: int
@@ -137,75 +166,110 @@ class RepairWorker:
         self._stop.set()
 
     def run_once(self) -> bool:
-        """Acquire and execute one task; returns True if one was run."""
+        """Acquire and execute one lease: a task and, where it repairs a
+        unit of a volume, the volume's other pending unit repairs, which
+        the scheduler leases with it. Each task is completed or failed
+        alone; returns True if a lease was run."""
         meta, _ = self.sched.call("acquire_task", {"worker_id": self.worker_id})
-        task = meta.get("task")
-        if not task:
+        if not meta.get("task"):
             return False
-        try:
-            self.execute(task)
-            self.sched.call("complete_task",
-                            {"task_id": task["task_id"], "worker_id": self.worker_id})
-            self.completed += 1
-            metrics.repair_tasks.inc(state="completed")
-        except Exception as e:
-            self.sched.call(
-                "fail_task",
-                {"task_id": task["task_id"], "worker_id": self.worker_id,
-                 "error": f"{type(e).__name__}: {e}"},
-            )
+        tasks = [meta["task"], *meta.get("siblings", ())]
+        errors = self.execute(tasks)
+        for task in tasks:
+            who = {"task_id": task["task_id"], "worker_id": self.worker_id}
+            e = errors.get(task["task_id"])
+            if e is None:
+                try:
+                    self.sched.call("complete_task", who)
+                    self.completed += 1
+                    metrics.repair_tasks.inc(state="completed")
+                    continue
+                except Exception as raised:  # the move was not recorded
+                    e = raised
+            self.sched.call("fail_task",
+                            {**who, "error": f"{type(e).__name__}: {e}"})
             self.failed += 1
             metrics.repair_tasks.inc(state="failed")
         return True
 
     # ---------------- execution ----------------
-    def execute(self, task: dict) -> None:
-        # renew the lease on a timer for the whole execution: survivor
+    def execute(self, tasks: list[dict]) -> dict[str, Exception]:
+        """Run the tasks of one lease; returns what failed, by task id."""
+        # renew the leases on a timer for the whole execution: survivor
         # downloads for a large chunk can exceed one lease period long
         # before the first batch writes back
         renew_stop = threading.Event()
 
         def renew_loop():
             while not renew_stop.wait(10.0):
-                try:
-                    self.sched.call("renew_task",
-                                    {"task_id": task["task_id"],
-                                     "worker_id": self.worker_id})
-                except Exception:
-                    pass
+                for task in tasks:
+                    try:
+                        self.sched.call("renew_task",
+                                        {"task_id": task["task_id"],
+                                         "worker_id": self.worker_id})
+                    except Exception:
+                        pass
 
         renewer = threading.Thread(target=renew_loop, daemon=True)
         renewer.start()
         try:
-            self._execute(task)
+            return self._execute(tasks)
         finally:
             renew_stop.set()
 
-    def _execute(self, task: dict) -> None:
-        with tracelib.path_span("blob.repair", "worker.repair") as sp:
-            sp.set_tag("svc", "worker").set_tag("task", task["type"])
-            self._execute_traced(task, sp)
+    def _execute(self, tasks: list[dict]) -> dict[str, Exception]:
+        # as many tasks at a time as one read of the survivors serves:
+        # one, for a task of another kind and for a volume whose units
+        # each read their own (`units_per_read`)
+        width = 1
+        if tasks[0]["type"] == "unit_repair":
+            width = units_per_read(cm.tactic(tasks[0]["codemode"]))
+        failed: dict[str, Exception] = {}
+        for i in range(0, len(tasks), width):
+            sharing = tasks[i:i + width]
+            try:
+                with tracelib.path_span("blob.repair", "worker.repair") as sp:
+                    sp.set_tag("svc", "worker").set_tag("task",
+                                                       sharing[0]["type"])
+                    sp.set_tag("units", len(sharing))
+                    errors = self._execute_traced(sharing, sp)
+                    if errors:  # a unit's own failure does not raise
+                        e = next(iter(errors.values()))
+                        sp.set_tag("error", f"{type(e).__name__}: {e}")
+            except Exception as e:  # what the tasks share failed
+                errors = {task["task_id"]: e for task in sharing}
+            failed.update(errors)
+            if tracelib.enabled() and sharing[0]["type"] == "unit_repair":
+                for j, task in enumerate(sharing):
+                    if task["task_id"] not in errors:
+                        metrics.repair_task_reads.inc(
+                            reads="shared" if j else "own")
+        return failed
 
-    def _execute_traced(self, task: dict, sp) -> None:
+    def _execute_traced(self, tasks: list[dict], sp
+                        ) -> dict[str, Exception]:
+        task = tasks[0]
         if task["type"] in ("shard_repair", "shard_migrate"):
-            return self._execute_shard_swap(task)
+            self._execute_shard_swap(task)
+            return {}
         vol = VolumeInfo.from_dict(
             self.cm.call("get_volume", {"vid": task["vid"]})[0]["volume"]
         )
         t = cm.tactic(vol.codemode)
-        bad = int(task["unit_index"])
+        bads = [int(x["unit_index"]) for x in tasks]
 
         # discover the blob population, bids and shard sizes, from a
         # surviving unit's chunk listing
-        bids = self._list_bids(vol, exclude=bad)
-        dest = self.nodes.get(task["dest_addr"])
+        bids = self._list_bids(vol, exclude=bads)
+        dests = [self.nodes.get(x["dest_addr"]) for x in tasks]
         if not bids:
-            return  # empty chunk: nothing to rebuild
+            return {}  # empty chunk: nothing to rebuild
 
         if t.is_msr() and _msr_repair_enabled():
             try:
-                return self._execute_msr(task, vol, t, bad,
-                                         [b for b, _ in bids], dest)
+                self._execute_msr(task, vol, t, bads[0],
+                                  [b for b, _ in bids], dests[0])
+                return {}
             except MsrFallback as e:
                 # exactly-once degradation: the sub-shard path never
                 # wrote anything (reads and verification both precede
@@ -213,18 +277,22 @@ class RepairWorker:
                 # from scratch
                 metrics.repair_msr_fallbacks.inc(reason=e.reason)
                 sp.set_tag("msr_fallback", e.reason)
-        self._execute_conventional(task, vol, t, bad, bids, dest)
+        return self._execute_conventional(tasks, vol, t, bads, bids, dests)
 
-    def _execute_conventional(self, task: dict, vol: VolumeInfo,
-                              t: cm.Tactic, bad: int,
-                              bids: list[tuple[int, int]], dest) -> None:
+    def _execute_conventional(self, tasks: list[dict], vol: VolumeInfo,
+                              t: cm.Tactic, bads: list[int],
+                              bids: list[tuple[int, int]], dests: list
+                              ) -> dict[str, Exception]:
         # choose the read set: prefer the bad unit's local stripe peers
         # when an LRC local repair is possible (intra-AZ bandwidth). A
         # dark AZ (blackout) starves the local read set entirely — fall
         # back to the global stripe, which can also re-encode a lost
         # LOCAL PARITY through its stripe members (lrc_reconstruct_rows).
         # code_pos maps unit index -> index within the solving code's
-        # shard space.
+        # shard space. Several units are of a plain RS volume
+        # (`units_per_read`): the global stripe less every one of them.
+        bad = bads[0]
+        failed_azs = {vol.units[i].az for i in bads}
         local_idx, ln, lm = t.local_stripe(bad) if t.l else ([], 0, 0)
         sources = (["local", "global"] if local_idx and bad in local_idx
                    else ["global"])
@@ -234,12 +302,12 @@ class RepairWorker:
                     read_set = [i for i in local_idx if i != bad]
                     n_solve, total_code = ln, ln + lm
                     code_pos = {u: s for s, u in enumerate(local_idx)}
-                    bad_sub = code_pos[bad]
+                    bad_subs = [code_pos[bad]]
                 else:
-                    read_set = [i for i in range(t.n + t.m) if i != bad]
+                    read_set = [i for i in range(t.n + t.m) if i not in bads]
                     n_solve, total_code = t.n, t.n + t.m
                     code_pos = {u: u for u in read_set}
-                    bad_sub = bad
+                    bad_subs = bads
 
                 # per-bid survivor reads (one EXTRA when available: the
                 # extra is reconstructed from the first n and compared,
@@ -253,19 +321,18 @@ class RepairWorker:
                 # it joins is decided by the survivors its reads return.
                 # An MSR stripe's rows are cut into sub-shards, which
                 # takes one size a step: those group by exact size.
-                exact = t.is_msr() and bad_sub < total_code
+                exact = t.is_msr() and bad_subs[0] < total_code
                 by_key: dict[tuple, list] = defaultdict(list)
-                # units found on a disk that does not serve (a second
-                # lost disk: a two-loss stripe) are skipped for the rest
-                # of the task — survivors in index order past both lost
-                # units, the first n solve, the next one checks
+                # units found on a disk that does not serve (a lost disk
+                # whose task is not among these) are skipped for the
+                # rest of the read — survivors in index order past every
+                # lost unit, the first n solve, the next one checks
                 lost: set[int] = set()
                 try:
                     for bid, size in bids:
                         subs, shards = self._read_survivors(
                             vol, read_set, code_pos, bid, need=n_solve,
-                            want=want, failed_az=vol.units[bad].az,
-                            lost=lost)
+                            want=want, failed_azs=failed_azs, lost=lost)
                         if any(len(shard) != size for shard in shards):
                             raise RuntimeError(
                                 f"bid {bid}: survivors hold "
@@ -280,18 +347,21 @@ class RepairWorker:
                     raise
                 break
 
-        self._decode_writeback(task, t, by_key, n_solve, total_code,
-                               bad_sub, exact, dest)
-
-    def _decode_writeback(self, task, t, by_key, n_solve, total_code,
-                          bad_sub, exact, dest) -> None:
-        writes: list[tuple[int, bytes]] = []
+        units = [_Unit(task, sub, dest)
+                 for task, sub, dest in zip(tasks, bad_subs, dests)]
         with tracelib.stage("decode"):
-            steps = self._decode_groups(t, by_key, n_solve, total_code,
-                                        bad_sub, exact, writes)
-        self._write_back(task, dest, writes)
-        if tracelib.enabled():
-            metrics.repair_steps_per_task.observe(steps)
+            self._decode_groups(t, by_key, n_solve, total_code, units, exact)
+        for unit in units:
+            if unit.error is None:
+                try:
+                    self._write_back(unit.task, unit.dest, unit.writes)
+                except Exception as e:
+                    unit.error = e
+                    continue
+                if tracelib.enabled():
+                    metrics.repair_steps_per_task.observe(unit.steps)
+        return {u.task["task_id"]: u.error for u in units
+                if u.error is not None}
 
     def _write_back(self, task: dict, dest,
                     writes: list[tuple[int, bytes]]) -> None:
@@ -344,53 +414,65 @@ class RepairWorker:
         caller: whatever it held before is nobody's business."""
         return np.empty(shape, dtype=np.uint8)
 
-    def _decode_groups(self, t, by_key, n_solve, total_code, bad_sub,
-                       exact, writes) -> int:
-        """One device step per group and `batch_stripes` bids; returns
-        the number of steps."""
-        steps = 0
+    def _decode_groups(self, t, by_key, n_solve, total_code,
+                       units: list[_Unit], exact) -> None:
+        """One step array per group and `batch_stripes` bids, and over
+        it one device step a lost unit. A unit whose check fails keeps
+        the error and takes no further step; the others go on."""
         for (wide, subs), group in by_key.items():
-            rows, out_pos, verify_pos = self._repair_rows(
-                t, subs, n_solve, total_code, bad_sub)
+            plans = [(unit, *self._repair_rows(t, subs, n_solve, total_code,
+                                               unit.sub)) for unit in units]
             for start in range(0, len(group), self.batch_stripes):
+                live = [p for p in plans if p[0].error is None]
+                if not live:
+                    return
                 chunk = group[start : start + self.batch_stripes]
                 sizes = [size for _, size, _ in chunk]
                 span = tracelib.start_span("stage:decode_step")
                 with span:
                     batch = self._stack(t, wide, exact, n_solve, chunk,
                                         sizes, span)
-                    if exact:
-                        recovered = self.codec.matrix_apply(
-                            rows, batch.reshape(
-                                len(chunk), n_solve * t.alpha,
-                                wide // t.alpha)).reshape(
-                                    len(chunk), -1, wide)
-                    else:
-                        recovered = self.codec.matrix_apply(rows, batch,
-                                                            width=sizes)
-                    steps += 1
-                    with tracelib.stage("decode_verify"):
-                        for (bid, size, shards), rec in zip(chunk,
-                                                            recovered):
-                            # cut to the bid's own size first: what is
-                            # checked and written back never holds pad
-                            if verify_pos is not None and not np.array_equal(
-                                    rec[verify_pos, :size], np.frombuffer(
-                                        shards[n_solve], dtype=np.uint8)):
-                                raise RuntimeError(
-                                    f"bid {bid}: reconstruction disagrees "
-                                    f"with extra survivor {subs[n_solve]} — "
-                                    f"refusing writeback (crc-conflict role)"
-                                )
-                            writes.append(
-                                (bid, rec[out_pos, :size].tobytes()))
+                    for unit, rows, out_pos, verify_pos in live:
+                        try:
+                            recovered = self._apply(t, rows, batch, sizes,
+                                                    exact)
+                            unit.steps += 1
+                            self._check_and_cut(unit, recovered, chunk,
+                                                out_pos, verify_pos, subs,
+                                                n_solve)
+                        except Exception as e:
+                            unit.error = e
                     # let go only now: unmapping half a gigabyte between
-                    # the step and the loop over its result slows that
+                    # a step and the loop over its result slows that
                     # loop by a fifth on the chip's host (PERF.md
                     # section 6, PR 36), and two such arrays must never
                     # be alive together
                     del batch
-        return steps
+
+    def _apply(self, t, rows, batch, sizes, exact) -> np.ndarray:
+        """One decode step over a chunk's array."""
+        if exact:
+            b, n_solve, wide = batch.shape
+            return self.codec.matrix_apply(
+                rows, batch.reshape(b, n_solve * t.alpha, wide // t.alpha)
+            ).reshape(b, -1, wide)
+        return self.codec.matrix_apply(rows, batch, width=sizes)
+
+    def _check_and_cut(self, unit: _Unit, recovered, chunk, out_pos,
+                       verify_pos, subs, n_solve) -> None:
+        with tracelib.stage("decode_verify"):
+            for (bid, size, shards), rec in zip(chunk, recovered):
+                # cut to the bid's own size first: what is checked and
+                # written back never holds pad
+                if verify_pos is not None and not np.array_equal(
+                        rec[verify_pos, :size], np.frombuffer(
+                            shards[n_solve], dtype=np.uint8)):
+                    raise RuntimeError(
+                        f"bid {bid}: reconstruction disagrees "
+                        f"with extra survivor {subs[n_solve]} — "
+                        f"refusing writeback (crc-conflict role)"
+                    )
+                unit.writes.append((bid, rec[out_pos, :size].tobytes()))
 
     def _stack(self, t, wide, exact, n_solve, chunk, sizes, span
                ) -> np.ndarray:
@@ -562,12 +644,12 @@ class RepairWorker:
         except Exception:
             pass
 
-    def _list_bids(self, vol: VolumeInfo, exclude: int
+    def _list_bids(self, vol: VolumeInfo, exclude: list[int]
                    ) -> list[tuple[int, int]]:
         """(bid, shard size) of every blob of the volume, from the
         chunk listing of the first unit that gives one."""
         for u in vol.units:
-            if u.index == exclude:
+            if u.index in exclude:
                 continue
             try:
                 meta, _ = self.nodes.get(u.node_addr).call(
@@ -580,14 +662,14 @@ class RepairWorker:
 
     def _read_survivors(
         self, vol: VolumeInfo, read_set: list[int], code_pos: dict[int, int],
-        bid: int, need: int, want: int | None = None, failed_az: str = "",
-        *, lost: set[int],
+        bid: int, need: int, want: int | None = None,
+        *, failed_azs: set[str], lost: set[int],
     ) -> tuple[list[int], list[bytes]]:
         """Read up to `want` survivors for bid (at least `need`, which is
         fatal to miss; the extras enable pre-writeback verification).
         Returns (code-space indices actually read, payloads), ascending.
         A unit whose disk answers 503 (broken, not serving) is added to
-        `lost` (the caller's set, one a task), and the units in `lost`
+        `lost` (the caller's set, one a read), and the units in `lost`
         are not asked."""
         want = want or need
         subs: list[int] = []
@@ -609,7 +691,7 @@ class RepairWorker:
                 continue
             metrics.repair_bytes_pulled.inc(
                 len(payload),
-                scope="az_local" if u.az == failed_az else "cross_az")
+                scope="az_local" if u.az in failed_azs else "cross_az")
             subs.append(code_pos[idx])
             shards.append(payload)
         if len(shards) < need:

@@ -439,6 +439,13 @@ repair_steps_per_task = DEFAULT.histogram(
     "cubefs_repair_steps_per_task",
     "decode steps of one finished unit-repair task",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, 128))
+# the unit repairs of one volume leased together are decoded from one
+# read of its survivors: `own` is the task whose lease made the read,
+# `shared` each further task decoded from it
+repair_task_reads = DEFAULT.counter(
+    "cubefs_repair_task_reads_total",
+    "finished unit-repair tasks, by whose read of the survivors they "
+    "were decoded from (own / shared)", ("reads",))
 repair_widths_per_step = DEFAULT.histogram(
     "cubefs_repair_widths_per_step",
     "distinct shard sizes among the bids of one decode step",

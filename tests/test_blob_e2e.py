@@ -124,11 +124,11 @@ def test_disk_repair_end_to_end(cluster, rng):
 @pytest.mark.parametrize("lost", [(0, 3), (2, 7), (6, 8)],
                          ids=["data+data", "data+parity", "parity+parity"])
 def test_two_disks_lost_both_units_rebuilt_bit_identical(cluster, rng, lost):
-    """A second disk fails before the first is rebuilt: each of the two
-    tasks of the volume rebuilds one unit from survivors in index order
-    past BOTH lost units (the first n solve, the next one is the check
-    before write-back); a disk that does not serve is asked once a task,
-    not once a bid."""
+    """A second disk fails before the first is rebuilt: the volume's
+    two tasks are leased together (since PR 38) and each unit is rebuilt
+    from one read of the survivors in index order past BOTH lost units
+    (the first n solve, the next one is the check before write-back); a
+    disk whose unit the lease rebuilds is not asked at all."""
     data = payload(rng, 250_000)  # 4 blobs of 64 KiB
     loc = cluster.access.put(data, codemode=cmode.CodeMode.EC6P3)
     vid = loc.slices[0].vid
@@ -164,9 +164,8 @@ def test_two_disks_lost_both_units_rebuilt_bit_identical(cluster, rng, lost):
         node = cluster.node_of(unit.node_addr)
         for bid, blob in original[idx].items():
             assert node.get_shard(unit.disk_id, unit.chunk_id, bid)[0] == blob
-    # the first task met the other lost unit once, not once per bid; the
-    # second found it rebuilt
-    assert 1 <= len(asked) <= 1 + len(lost), asked
+    # both lost units were left out of the reads from the start
+    assert asked == [] and cluster.worker.completed == queued
     assert all(cluster.cm.disks[d].status == DiskStatus.REPAIRED
                for d in broken)
     assert cluster.access.get(loc) == data

@@ -382,14 +382,22 @@ def test_chip_smoke_phases_at_tiny_sizes(tmp_path, capsys):
                                   "sidecar", "two_loss", "any_size",
                                   "repair_any_size", "device_proof",
                                   "checkout_clean"}
-    # a mixed-size volume per size class, one unit of each rebuilt after
-    # the worker's ready: by width rung, and nothing built
+    # a mixed-size volume per size class, two units of each rebuilt
+    # after the worker's ready, from one read of the survivors a volume:
+    # by width rung, and nothing built
     repair = out["phases"]["repair_any_size"]
-    assert repair["tasks"] == 3 and repair["ready_steps"] == 9
-    assert repair["rebuilt_shards_checked"] >= 15
+    assert repair["tasks"] == 6 and repair["ready_steps"] == 9
+    assert repair["shared_reads"] == 3
+    assert repair["rebuilt_shards_checked"] >= 30
     assert repair["programs_built_after_ready"] == 0
-    assert 3 <= repair["decode_steps"] < sum(
+    assert 6 <= repair["decode_steps"] < 2 * sum(
         repair["distinct_shard_sizes"].values())
+    # two disks under one EC12P4 volume lost together: its two tasks
+    # came in one lease and the second was decoded from the first's read
+    broken = out["phases"]["break_repair"]
+    assert len(broken["rounds"][0]["disks"]) == 2
+    assert all(len(r["disks"]) == 1 for r in broken["rounds"][1:])
+    assert broken["shared_reads"] >= 1
     # sizes nobody named, after the front door's ready: nothing built
     any_size = out["phases"]["any_size"]
     assert any_size["objects"] == 6 and any_size["ready_steps"] > 0
