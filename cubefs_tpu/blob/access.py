@@ -74,6 +74,12 @@ class AccessConfig:
     qos_gate: object | None = None
 
 
+def _after_wait(queued_at: float, fn, *args):
+    """A pool task: (the seconds it waited for a thread, fn's result)."""
+    waited = time.perf_counter() - queued_at
+    return waited, fn(*args)
+
+
 class AccessHandler:
     """One handler per process; thread-safe."""
 
@@ -94,13 +100,24 @@ class AccessHandler:
         self._lock = lockwitness.make_lock("AccessHandler._lock")
 
     def _submit(self, fn, *args):
+        """A future of (seconds the task waited for a pool thread, fn's
+        result): the request thread that collects a request's shard
+        futures observes their waits at once (`_observe_pool_waits`), so
+        the pool's queue keeps its account with no lock a write."""
         # carry the request's trace context into pool workers, else the
         # shard RPCs lose their X-Trace linkage
         ctx = contextvars.copy_context()
-        return self._pool.submit(ctx.run, fn, *args)
+        return self._pool.submit(ctx.run, _after_wait, time.perf_counter(),
+                                 fn, *args)
 
     def _map(self, fn, items):
-        return [f.result() for f in [self._submit(fn, i) for i in items]]
+        return [f.result()[1]
+                for f in [self._submit(fn, i) for i in items]]
+
+    @staticmethod
+    def _observe_pool_waits(op: str, waits: list[float]) -> None:
+        if tracelib.current() is not None:  # the door: see trace.stage
+            metrics.access_pool_wait.observe_many(waits, op=op)
 
     def _encoder(self, mode: int):
         with self._lock:
@@ -219,12 +236,15 @@ class AccessHandler:
                             parity[i, u.index - t.n]))
                 fails: list[tuple[int, int]] = []  # (bid, unit index)
                 ok_per_bid = dict.fromkeys(bids, 0)
+                waits = []
                 for f in futures:  # every one, stragglers past quorum too
-                    bid, idx, err = f.result()
+                    waited, (bid, idx, err) = f.result()
+                    waits.append(waited)
                     if err is None:
                         ok_per_bid[bid] += 1
                     else:
                         fails.append((bid, idx))
+                self._observe_pool_waits("put_shard", waits)
         except BaseException:
             # no way out leaves a write of this PUT running; the step
             # may still read `rows` (a wait that timed out), so the
@@ -252,7 +272,7 @@ class AccessHandler:
                 )
 
         with tracelib.stage("location_crc"):
-            crc = crc_task.result()  # computed under the device step
+            crc = crc_task.result()[1]  # computed under the device step
         return Location(
             cluster_id=1,
             codemode=mode,
@@ -399,15 +419,18 @@ class AccessHandler:
             # in-flight
             got: dict[int, bytes] = {}
             errs: dict[int, object] = {}
+            waits = []
             remaining = set(pending_map)
             while remaining and len(got) < t.n:
                 done, remaining = wait(remaining, return_when=FIRST_COMPLETED)
                 for f in done:
-                    i, p, err = f.result()
+                    waited, (i, p, err) = f.result()
+                    waits.append(waited)
                     if err is None:
                         got[i] = p
                     else:
                         errs[i] = err
+            self._observe_pool_waits("get_shard", waits)
         if all(i in got for i in range(t.n)):  # got may also hold hedged parity
             data = b"".join(got[i] for i in range(t.n))
             return data[:payload_len]
@@ -418,7 +441,7 @@ class AccessHandler:
         # we drain in-flight reads (no duplicate RPCs) and fetch extras.
         if len(got) < t.n:
             for f in remaining:
-                i, p, err = f.result()
+                i, p, err = f.result()[1]
                 if err is None:
                     got[i] = p
                 else:

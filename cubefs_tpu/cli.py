@@ -179,6 +179,46 @@ def _codec_view(text: str) -> dict:
             "errors_fanned_back":
                 total("cubefs_codec_batch_errors_total", op=op),
         }
+        # the drainer's streak: who ran the queue, and for how long
+        # after its own submission was resolved
+        drains = total("cubefs_codec_drain_steps_count", op=op)
+        collects = {how: total("cubefs_codec_collects_total", op=op, how=how)
+                    for how in ("ready", "waited", "drained")}
+        if drains or any(collects.values()):
+            def drain_ms(part):
+                return round(1000 * total(
+                    "cubefs_codec_drain_seconds_sum", op=op, part=part)
+                    / drains, 3) if drains else None
+
+            view[op]["drains"] = {
+                "drains": drains,
+                "steps_per_drain_avg": round(total(
+                    "cubefs_codec_drain_steps_sum", op=op) / drains, 2)
+                if drains else None,
+                "own_avg_ms": drain_ms("own"),
+                "others_avg_ms": drain_ms("others"),
+                "collects": collects,
+            }
+    # the engine seam: of the wall time since the batcher was made, how
+    # much had an engine call in flight (busy), how much had a caller
+    # inside the codec and no call (handoff), how much had nobody there
+    seam = {state: total("cubefs_codec_engine_seconds_total", state=state)
+            for state in ("busy", "handoff", "starved")}
+    if any(seam.values()):
+        whole = sum(seam.values())
+        view["engine_seam"] = {
+            "seconds": {k: round(v, 3) for k, v in seam.items()},
+            "share_pct": {k: round(100 * v / whole, 2)
+                          for k, v in seam.items()}}
+    # the front door's thread pool: a shard task's wait for a thread
+    pool = {}
+    for op in ("put_shard", "get_shard"):
+        cnt = total("cubefs_access_pool_wait_seconds_count", op=op)
+        if cnt:
+            pool[op] = {"tasks": cnt, "wait_avg_ms": round(1000 * total(
+                "cubefs_access_pool_wait_seconds_sum", op=op) / cnt, 3)}
+    if pool:
+        view["access_pool"] = pool
     engines = sorted({lb.get("engine") for n, lb, _ in series
                       if n == "cubefs_codec_batch_steps_total"} - {None})
     view["steps_by_engine"] = {

@@ -41,6 +41,7 @@ call byte for byte (asserted in tests/test_codec_batch.py).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -120,8 +121,10 @@ class CodecFuture:
     def result(self, timeout: float = 120.0) -> np.ndarray:
         """Block until resolved; return the stripes or raise the
         per-submission error. Drains the queue first if nobody is."""
+        how = "ready"
         if not self.done:
-            self._batcher._drain_if_idle(self._key)
+            how = ("drained" if self._batcher._drain_if_idle(self._key, self)
+                   else "waited")
             if not self.done:
                 ev = self.event
                 if ev is None:
@@ -132,6 +135,8 @@ class CodecFuture:
                     raise CodecAdmissionError(
                         f"{self._key[0]}: submission not drained within "
                         f"{timeout:.1f}s")
+        if tracelib.enabled():
+            _count_collect(self._key[0], how)
         if self.exc is not None:
             raise self.exc
         return self.value
@@ -158,9 +163,37 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
+class _Drain:
+    """What one drain learns of itself, for the drainer's streak: the
+    submission its caller came to collect, when the caller became the
+    drainer, when that submission was resolved, the steps run."""
+
+    __slots__ = ("own", "t0", "own_t", "steps")
+
+    def __init__(self, own: CodecFuture, t0: float):
+        self.own = own
+        self.t0 = t0
+        self.own_t = t0 if own.done else None
+        self.steps = 0
+
+    def step_ran(self, clock) -> None:
+        """After a step's results (or its failure) were fanned back."""
+        self.steps += 1
+        if self.own_t is None and self.own.done:
+            self.own_t = clock()
+
+
 # a gathered step's array above this size is kept for the next one
 SPARE_MIN_BYTES = 32 << 20
+# what a drain and a gathered step's copy are called in a profile, in
+# the style of codec/engine.py's _PHASE_SPANS
+_DRAIN_SPAN = f"{tracelib.PROFILE_PREFIX}codec.drain"
+_GATHER_SPAN = f"{tracelib.PROFILE_PREFIX}codec.gather"
+_NO_SPAN = contextlib.nullcontext()
+_SEAM_STATES = ("busy", "handoff", "starved")
 _STEP_SERIES: dict[str, tuple] = {}
+_DRAIN_SERIES: dict[str, tuple] = {}
+_COLLECTS: dict[tuple[str, str], object] = {}
 
 
 def _step_series(op: str) -> tuple:
@@ -175,6 +208,27 @@ def _step_series(op: str) -> tuple:
     return bound
 
 
+def _drain_series(op: str) -> tuple:
+    """(steps a drain, its `own` seconds, its `others` seconds) of
+    `op`, the label lookups done once: this runs once a drain."""
+    bound = _DRAIN_SERIES.get(op)
+    if bound is None:
+        bound = _DRAIN_SERIES[op] = (
+            metrics.codec_drain_steps.bind(op=op),
+            metrics.codec_drain_seconds.bind(op=op, part="own"),
+            metrics.codec_drain_seconds.bind(op=op, part="others"))
+    return bound
+
+
+def _count_collect(op: str, how: str) -> None:
+    """One result() of `op`: `ready`, `waited` or `drained`."""
+    inc = _COLLECTS.get((op, how))
+    if inc is None:
+        inc = _COLLECTS[(op, how)] = metrics.codec_collects.bind(
+            op=op, how=how)
+    inc()
+
+
 class BatchCodec:
     """The submit surface. One instance per process is the norm
     (module-level DEFAULT below); tests construct private ones."""
@@ -182,7 +236,8 @@ class BatchCodec:
     def __init__(self, max_batch: int = rs_kernel.STEP_BATCH,
                  max_wait_ms: float = 0.0,
                  max_pending: int = 4096,
-                 max_step_bytes: int | None = None):
+                 max_step_bytes: int | None = None,
+                 clock=time.perf_counter):
         # stripes per coalesced step: with max_step_bytes it bounds the
         # rungs a step can reach, so the programs a geometry can ask for
         self.max_batch = max_batch
@@ -206,6 +261,15 @@ class BatchCodec:
         # (rows, width rung) -> stripes a coalesced step may hold
         self._caps: dict[tuple[int, int], int] = {}
         self._spare: np.ndarray | None = None  # see _gather
+        # the engine seam's account, all of it under self._lock: what
+        # decides the state, when it last changed, and the seconds of
+        # each state not yet moved into metrics.codec_engine_seconds
+        # (settle). `clock` times it and the drains; tests inject one.
+        self._clock = clock
+        self._calls = 0  # engine calls in flight
+        self._open = 0  # submissions admitted and not yet resolved
+        self._since = clock()
+        self._seam = dict.fromkeys(_SEAM_STATES, 0.0)
 
     # ---------------- public submit surface ----------------
     def submit_encode(self, engine: str | None, data: np.ndarray,
@@ -306,31 +370,82 @@ class BatchCodec:
                 q = self._queues[key] = _GeometryQueue(coeff)
             q.subs.append(sub)
             self._pending += sub.stripes
+            self._seam_tick()
+            self._open += 1
         return sub
 
-    def _drain_if_idle(self, key: tuple) -> None:
+    # ---------------- the engine seam's account ----------------
+    def _seam_tick(self) -> None:
+        """Book the seconds since the last change to the state the seam
+        is in. The caller holds self._lock and changes `_calls` or
+        `_open` next: a submission admitted, an engine call's start or
+        end, the last resolve of a swap."""
+        now = self._clock()
+        state = ("busy" if self._calls else
+                 "handoff" if self._open else "starved")
+        self._seam[state] += now - self._since
+        self._since = now
+
+    def settle(self) -> None:
+        """Move the account into cubefs_codec_engine_seconds_total{state},
+        booked up to now (dropped instead while CUBEFS_TRACE=0). The
+        registry calls this for DEFAULT before every render, so a delta
+        between two scrapes is exact however long a state lasts."""
+        with self._lock:
+            self._seam_tick()
+            seam, self._seam = self._seam, dict.fromkeys(_SEAM_STATES, 0.0)
+        if tracelib.enabled():
+            for state, seconds in seam.items():
+                if seconds:
+                    metrics.codec_engine_seconds.inc(seconds, state=state)
+
+    def _drain_if_idle(self, key: tuple, own: CodecFuture) -> bool:
         """Become the drainer for `key` unless one is already running
-        (collector-drains; called from CodecFuture.result)."""
+        (collector-drains; called from CodecFuture.result, which hands
+        in the submission it came for). True if this caller drained."""
         q = self._queues.get(key)
         # unlocked peek: a True `busy` is authoritative enough — the
         # running drainer only exits once the queue is empty, so any
         # parked submission it hasn't taken yet, it will. Skipping the
         # lock here keeps collectors off the drainer's neck.
         if q is not None and q.busy:
-            return
+            return False
         with self._lock:
             q = self._queues.get(key)
             if q is None or q.busy or not q.subs:
-                return
+                return False
             q.busy = True
             self._n_busy += 1
-        if self.max_wait > 0:
-            # optional linger: trade first-collector latency for width
-            # when arrivals are sparse but steady
-            time.sleep(self.max_wait)
-        self._drain(key, q)
+        on = tracelib.enabled()
+        drain = _Drain(own, self._clock())
+        with tracelib.annotation(_DRAIN_SPAN) if on else _NO_SPAN:
+            if self.max_wait > 0:
+                # optional linger: trade first-collector latency for
+                # width when arrivals are sparse but steady
+                time.sleep(self.max_wait)
+            self._drain(key, q, drain)
+        if on:
+            self._record_drain(key[0], drain)
+        return True
 
-    def _drain(self, key: tuple, q: _GeometryQueue) -> None:
+    def _record_drain(self, op: str, drain: _Drain) -> None:
+        """The streak of a drain that ran to its end: how many steps,
+        how long until the drainer's own submission was resolved, how
+        long it then served the others; the last two also as tags of
+        the drainer's span (a PUT's stage:encode_admission, the repair
+        worker's stage:decode), which `cubefs-cli trace slow` shows."""
+        end = self._clock()
+        own_t = drain.own_t if drain.own_t is not None else end
+        steps, own, others = _drain_series(op)
+        steps.observe(drain.steps)
+        own.observe(own_t - drain.t0)
+        others.observe(end - own_t)
+        span = tracelib.current()
+        if span is not None:
+            span.set_tag("drain_steps", drain.steps)
+            span.set_tag("drain_others_ms", round((end - own_t) * 1e3, 3))
+
+    def _drain(self, key: tuple, q: _GeometryQueue, drain: _Drain) -> None:
         """First-caller-drains loop: swap the queue out and land each
         swap as one (or a few, size-bounded) device steps. Submissions
         arriving during a step ride the next swap — the step duration
@@ -351,10 +466,12 @@ class BatchCodec:
                     q.subs = []
                 total = sum(s.stripes for s in batch)
                 try:
-                    self._run_steps(key, q.coeff, batch, total)
+                    self._run_steps(key, q.coeff, batch, total, drain)
                 finally:
                     with self._lock:
                         self._pending -= total
+                        self._seam_tick()
+                        self._open -= len(batch)
                         self._cond.notify_all()
         except BaseException as e:
             # a dying drainer (MemoryError, interrupt) must not strand
@@ -364,6 +481,8 @@ class BatchCodec:
                 orphans = q.subs
                 q.subs = []
                 self._pending -= sum(s.stripes for s in orphans)
+                self._seam_tick()
+                self._open -= len(orphans)
                 q.busy = False
                 self._n_busy -= 1
                 self._cond.notify_all()
@@ -374,7 +493,8 @@ class BatchCodec:
             raise
 
     def _run_steps(self, key: tuple, coeff: np.ndarray | None,
-                   batch: list[CodecFuture], total: int) -> None:
+                   batch: list[CodecFuture], total: int,
+                   drain: _Drain) -> None:
         """Validate, chunk, execute, and fan results back. Every
         submission is resolved exactly once, even when the device call
         fails or a batch-mate is malformed. One fused pass — this loop
@@ -408,12 +528,12 @@ class BatchCodec:
                         f"{sub.arr.dtype}"))
                     continue
                 if step and stripes + sub.stripes > stripe_cap:
-                    self._one_step(key, coeff, step)
+                    self._one_step(key, coeff, step, drain)
                     step, stripes = [], 0
                 step.append(sub)
                 stripes += sub.stripes
             if step:
-                self._one_step(key, coeff, step)
+                self._one_step(key, coeff, step, drain)
         finally:
             for sub in batch:  # belt-and-braces: nobody waits forever
                 if not sub.done:
@@ -421,13 +541,14 @@ class BatchCodec:
                         f"{op}: drain failed before this submission"))
 
     def _one_step(self, key: tuple, coeff: np.ndarray | None,
-                  step: list[CodecFuture]) -> None:
+                  step: list[CodecFuture], drain: _Drain) -> None:
         """One engine call at the smallest rung that holds the step: a
         zeroed (B_rung, rows, S_rung) array with every submission's rows
         copied in at its own width — unless the step is one submission
         that is rung-shaped already (a PUT's data rows, a repair task's
         survivors), which goes up as it is."""
         op = key[0]
+        on = tracelib.enabled()
         gather_t0 = time.perf_counter()
         first = step[0].arr
         cols = int(first.shape[1])
@@ -435,7 +556,10 @@ class BatchCodec:
             cols, sum(sub.stripes for sub in step), int(key[4]))
         shape = (rung_b, cols, rung_s)
         gathered = len(step) > 1 or first.shape != shape
-        arr = self._gather(step, shape) if gathered else first
+        arr = first
+        if gathered:
+            with tracelib.annotation(_GATHER_SPAN) if on else _NO_SPAN:
+                arr = self._gather(step, shape)
         # what the submissions brought, live stripe by live stripe; the
         # rest of the rung-shaped array is pad
         live = [w for sub in step for w in sub.widths]
@@ -455,18 +579,26 @@ class BatchCodec:
         span.set_tag("rung_b", shape[0]).set_tag("rung_s", shape[2])
         span.set_tag("pad_bytes", pad)
         with span:
+            with self._lock:
+                self._seam_tick()
+                self._calls += 1
             try:
                 out, served = self._engine_call(key, coeff, arr)
             except BaseException as e:  # fan the step's failure back
                 for sub in step:
                     sub.resolve(None, e)
+                drain.step_ran(self._clock)
                 return
+            finally:
+                with self._lock:
+                    self._seam_tick()
+                    self._calls -= 1
             span.set_tag("engine", served)
         if gathered and arr.nbytes > SPARE_MIN_BYTES:
             self._spare = arr  # the engine has its own copy by now
         tracelib.observe_stage("codec_step", span.path,
                                time.perf_counter() - wait_now)
-        if tracelib.enabled():
+        if on:
             metrics.codec_engine_phase.observe(
                 wait_now - gather_t0 if gathered else 0.0,
                 engine=served, op=op, phase="gather")
@@ -483,6 +615,7 @@ class BatchCodec:
             if ev is not None:
                 ev.set()
             off += sub.stripes
+        drain.step_ran(self._clock)
 
     def _gather(self, step: list[CodecFuture], shape: tuple) -> np.ndarray:
         """The step's rung-shaped array: every submission's rows at its
@@ -657,6 +790,9 @@ class AdmittedEngine:
 
 
 DEFAULT = BatchCodec()
+# the one process-wide account is DEFAULT's (looked up at each render:
+# a test that swaps DEFAULT gets its own settled)
+metrics.DEFAULT.before_render(lambda: DEFAULT.settle())
 
 
 def admit(engine: str | None = None,

@@ -111,12 +111,13 @@ class Histogram(_Metric):
             if s is None:
                 s = {"count": 0, "sum": 0.0, "buckets": [0] * len(self.BUCKETS)}
                 self._series[k] = s
+            s["count"] += len(values)
+            s["sum"] += sum(values)
+            bounds, buckets = self.BUCKETS, s["buckets"]
             for value in values:
-                s["count"] += 1
-                s["sum"] += value
-                i = bisect.bisect_left(self.BUCKETS, value)
-                if i < len(self.BUCKETS):
-                    s["buckets"][i] += 1
+                i = bisect.bisect_left(bounds, value)
+                if i < len(buckets):
+                    buckets[i] += 1
 
     def time(self, **labels):
         metric = self
@@ -165,6 +166,14 @@ class Registry:
     def __init__(self):
         self._metrics: dict[str, _Metric] = {}
         self._lock = threading.Lock()
+        self._before_render: list = []
+
+    def before_render(self, hook) -> None:
+        """Call `hook()` at the start of every render_text(): for an
+        account kept as "seconds since the last change of state", which
+        is only exact in a scrape once settled up to the scrape."""
+        with self._lock:
+            self._before_render.append(hook)
 
     def _get(self, cls, name, help_, labels, **kw):
         with self._lock:
@@ -186,6 +195,10 @@ class Registry:
     def render_text(self) -> str:
         """Prometheus exposition format."""
         out = []
+        with self._lock:
+            hooks = list(self._before_render)
+        for hook in hooks:
+            hook()
         with self._lock:
             metrics = list(self._metrics.values())
         for m in metrics:
@@ -388,6 +401,44 @@ codec_engine_phase = DEFAULT.histogram(
     "host seconds per phase of one drained codec step",
     ("engine", "op", "phase"),
     buckets=(0.00005, 0.0002, 0.001, 0.005, 0.02, 0.1, 0.5, 2))
+# the three places a request waits between the front door and the
+# device, each with its own account (codec/batcher.py, blob/access.py).
+# The engine seam: wall seconds since the batcher was made, by what the
+# seam was doing — `busy` (at least one engine call in flight; calls of
+# two geometry queues that overlap count once), `handoff` (no call in
+# flight, a submission admitted and not yet resolved: parked with no
+# drainer, or its drainer gathers, fans results back, lingers or waits
+# for the GIL), `starved` (neither: every caller is outside the codec).
+# Settled up to the instant of every render_text()
+codec_engine_seconds = DEFAULT.counter(
+    "cubefs_codec_engine_seconds_total",
+    "wall seconds of the admission -> engine seam by state "
+    "(busy / handoff / starved); the three sum to wall time", ("state",))
+# the drainer's streak: one drain is a caller of result() that found its
+# queue idle and ran it until it was empty
+codec_drain_steps = DEFAULT.histogram(
+    "cubefs_codec_drain_steps",
+    "engine steps one drain ran before its queue was empty", ("op",),
+    buckets=(1, 2, 3, 4, 6, 8, 12, 16, 32, 64))
+codec_drain_seconds = DEFAULT.histogram(
+    "cubefs_codec_drain_seconds",
+    "seconds of one drain: `own` from becoming the drainer until its own "
+    "submission was resolved, `others` from then until the queue was "
+    "empty", ("op", "part"),
+    buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.03, 0.04, 0.05,
+             0.065, 0.08, 0.1, 0.15, 0.25, 0.5, 1, 2.5))
+codec_collects = DEFAULT.counter(
+    "cubefs_codec_collects_total",
+    "result() calls by how the submission came to be resolved: `ready` "
+    "(before it was asked for), `waited` (by another caller's drain), "
+    "`drained` (this caller drained the queue)", ("op", "how"))
+# the front door's thread pool: submit to the task's first line
+access_pool_wait = DEFAULT.histogram(
+    "cubefs_access_pool_wait_seconds",
+    "seconds a shard task waited for a thread of the access pool",
+    ("op",),
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 1))
 
 # shared compiled-program cache (ops/progcache.py): one process-wide
 # capped LRU behind the msr product-matrix rows, the jitted rs_kernel
@@ -493,12 +544,6 @@ slo_budget_remaining = DEFAULT.gauge(
     "fraction of the window's error budget still unspent (1 = no "
     "violations, 0 = budget exhausted)",
     ("path",))
-trace_spans_total = DEFAULT.counter(
-    "cubefs_trace_spans_total",
-    "spans finished into the in-memory collector")
-trace_evictions = DEFAULT.counter(
-    "cubefs_trace_evictions_total",
-    "whole traces evicted from the collector (oldest-root-first)")
 slow_traces = DEFAULT.counter(
     "cubefs_slow_traces_total",
     "root spans that exceeded CUBEFS_SLOW_MS and were captured to the "

@@ -548,8 +548,7 @@ def observe_stage(name: str, path: str, seconds) -> None:
         metrics.request_stage_seconds.observe_many(
             list(seconds), path=path, stage=name)
     else:
-        metrics.request_stage_seconds.observe(
-            seconds, path=path, stage=name)
+        _stage_seconds(path, name).observe(seconds)
 
 
 # ------------------------------------------------------------- collector
@@ -557,9 +556,6 @@ def observe_stage(name: str, path: str, seconds) -> None:
 def _heap_key(t: dict) -> float:
     rs = t["root_start"]
     return rs if rs is not None else float("inf")
-
-
-_count_span = metrics.trace_spans_total.bind()
 
 
 def _collect(span: Span) -> None:
@@ -584,7 +580,6 @@ def _collect(span: Span) -> None:
                 heapq.heappush(_evict_heap,
                                (t["root_start"], t["seq"], span.trace_id))
         _span_total += 1
-        _count_span()
         # evict WHOLE traces, oldest-root-first, so a reconstructed
         # tree is never torn by dropping only its early spans
         while _span_total > MAX_KEPT and len(_traces) > 1 and _evict_heap:
@@ -593,7 +588,6 @@ def _collect(span: Span) -> None:
             if vt is None or (_heap_key(vt), vt["seq"]) != (key, seq):
                 continue  # stale entry (evicted, or root_start improved)
             _span_total -= len(_traces.pop(victim)["spans"])
-            metrics.trace_evictions.inc()
 
 
 def finished_spans(trace_id: str | None = None) -> list[dict]:

@@ -127,8 +127,9 @@ def test_data_writes_and_crc_end_under_the_held_step(
         # the CRC ran off the client's thread — all before the step ran
         assert subs.writes(lambda u: u.index >= t.n) == []
         for fut in subs.writes():
-            assert fut.result()[2] is None
-        assert subs.others()[0].result() == reference.crc32(data)
+            # a pool future is (its wait for a thread, the task's result)
+            assert fut.result()[1][2] is None
+        assert subs.others()[0].result()[1] == reference.crc32(data)
         assert crc_threads and th.ident not in crc_threads
         assert not out  # the PUT has not ended
     finally:

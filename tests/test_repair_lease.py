@@ -581,7 +581,7 @@ def test_the_counter_and_its_layer_entry_resolve(tmp_path):
     bench = spec.load_benchmark()
     entries = [m for m in bench["per_layer"]
                if m["name"].startswith("repair.shared_read_share")]
-    assert entries == [bench["per_layer"][-1]] == [{
+    assert entries == [{
         "name": "repair.shared_read_share-2disk", "unit": "%",
         "better": "higher", "source": "program_counter", "layer": "repair",
         "moves": "repair_rate", "workloads": ["disk-repair-2disk"]}]
