@@ -15,12 +15,15 @@ before it is checked and written back: no stored shard carries pad.
 The scheduler leases a volume's pending unit repairs together, and those
 of a plain Reed-Solomon volume are decoded from ONE read of its
 survivors (`units_per_read`): each step array is filled once, and one
-decode step a lost unit runs over it.
+decode step a lost unit runs over it. The step arrays of a backlog are
+views of one buffer the worker keeps from its first step until it finds
+no task to lease (`_step_array`): pages touched once, not once a step.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import threading
 import time
@@ -120,6 +123,12 @@ class RepairWorker:
         self._thread: threading.Thread | None = None
         self.completed = 0
         self.failed = 0
+        # what every step array of a backlog is a view of (_step_array):
+        # the worker's alone, run by one thread (the loop's or the
+        # caller's of run_once), None while it has nothing to do — and
+        # how the last step's array came, `reused` or `fresh`
+        self._buffer: np.ndarray | None = None
+        self._came = "fresh"
 
     def ready(self, max_object_bytes: int, policies=None,
               blob_size: int | None = None) -> int:
@@ -158,6 +167,7 @@ class RepairWorker:
         def loop():
             while not self._stop.wait(0 if self.run_once() else idle_wait):
                 pass
+            self._buffer = None  # stopped mid-backlog: as when idle
 
         self._thread = threading.Thread(target=loop, daemon=True)
         self._thread.start()
@@ -169,9 +179,11 @@ class RepairWorker:
         """Acquire and execute one lease: a task and, where it repairs a
         unit of a volume, the volume's other pending unit repairs, which
         the scheduler leases with it. Each task is completed or failed
-        alone; returns True if a lease was run."""
+        alone; returns True if a lease was run. A worker that finds none
+        lets its step buffer go: an idle worker holds nothing."""
         meta, _ = self.sched.call("acquire_task", {"worker_id": self.worker_id})
         if not meta.get("task"):
+            self._buffer = None
             return False
         tasks = [meta["task"], *meta.get("siblings", ())]
         errors = self.execute(tasks)
@@ -411,14 +423,30 @@ class RepairWorker:
 
     def _step_array(self, shape: tuple) -> np.ndarray:
         """The array of one decode step, to be filled and zeroed by its
-        caller: whatever it held before is nobody's business."""
-        return np.empty(shape, dtype=np.uint8)
+        caller: a C-contiguous view of the first bytes of the one flat
+        buffer the worker owns, so what it holds is the last steps'
+        survivors, this volume's or another's. The buffer is made by the
+        first step of a backlog, replaced — the old one let go first,
+        two are never alive — by a step that does not fit, kept across
+        steps, units and tasks, and let go by the `run_once` that finds
+        no task. Its caller must be done with the last view by then:
+        one step's array at a time."""
+        size = math.prod(shape)
+        self._came = "reused"
+        if self._buffer is None or self._buffer.size < size:
+            self._buffer = None  # let go first
+            self._buffer = np.empty(size, dtype=np.uint8)
+            self._came = "fresh"  # pages never touched
+        return self._buffer[:size].reshape(shape)
 
     def _decode_groups(self, t, by_key, n_solve, total_code,
                        units: list[_Unit], exact) -> None:
         """One step array per group and `batch_stripes` bids, and over
         it one device step a lost unit. A unit whose check fails keeps
-        the error and takes no further step; the others go on."""
+        the error and takes no further step; the others go on. The
+        array is a view of the worker's buffer (`_step_array`), this
+        loop's from `_stack` until every unit's step over it has
+        returned its rows to the host; nothing below keeps it."""
         for (wide, subs), group in by_key.items():
             plans = [(unit, *self._repair_rows(t, subs, n_solve, total_code,
                                                unit.sub)) for unit in units]
@@ -442,11 +470,9 @@ class RepairWorker:
                                                 n_solve)
                         except Exception as e:
                             unit.error = e
-                    # let go only now: unmapping half a gigabyte between
-                    # a step and the loop over its result slows that
-                    # loop by a fifth on the chip's host (PERF.md
-                    # section 6, PR 36), and two such arrays must never
-                    # be alive together
+                    # the view goes before the next step asks for its
+                    # array: one that does not fit replaces the buffer,
+                    # and two such arrays must never be alive together
                     del batch
 
     def _apply(self, t, rows, batch, sizes, exact) -> np.ndarray:
@@ -480,7 +506,10 @@ class RepairWorker:
         bid at its own size and zeros past it, in a shape `ready` built
         a program for — `rs_kernel.repair_step_shape`: the group's width
         rung, zero stripes up to a stripe rung — which the batcher
-        passes whole."""
+        passes whole. The array comes holding the last steps' survivors
+        (`_step_array`), so every byte of it is written here, a
+        survivor's or a zero: that is the guarantee which keeps one
+        task's shards out of the next task's step, not a habit."""
         if exact:
             # an MSR stripe, one exact size a step (the group's key):
             # the rows are cut into alpha sub-shards each, and the
@@ -497,9 +526,9 @@ class RepairWorker:
         with tracelib.stage("decode_stack"):
             batch = self._step_array(shape)
             # every zero first, in address order, then the survivors:
-            # on the chip's host a (64, 12, 720896) array fills in
-            # ~510 ms this way and ~580 ms with each bid's pad zeroed
-            # after its rows (PERF.md section 6, PR 36)
+            # on the chip's host a (64, 12, 720896) array of fresh pages
+            # fills in ~510 ms this way and ~580 ms with each bid's pad
+            # zeroed after its rows (PERF.md section 6, PR 36)
             for b, size in enumerate(sizes):
                 batch[b, :, size:] = 0
             batch[len(chunk):] = 0
@@ -510,9 +539,11 @@ class RepairWorker:
         pad = batch.nbytes - n_solve * sum(sizes)
         span.set_tag("stage", "decode_step").set_tag("bids", len(chunk))
         span.set_tag("rung_b", shape[0]).set_tag("rung_s", shape[2])
+        span.set_tag("array", self._came)
         span.set_tag("widths", len(set(sizes))).set_tag("pad_bytes", pad)
         if tracelib.enabled():
             metrics.repair_widths_per_step.observe(len(set(sizes)))
+            metrics.repair_step_arrays.inc(result=self._came)
         return batch
 
     def _execute_msr(self, task: dict, vol: VolumeInfo, t: cm.Tactic,

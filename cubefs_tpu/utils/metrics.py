@@ -497,6 +497,14 @@ repair_task_reads = DEFAULT.counter(
     "cubefs_repair_task_reads_total",
     "finished unit-repair tasks, by whose read of the survivors they "
     "were decoded from (own / shared)", ("reads",))
+# a decode step's array (blob/worker.py:_step_array): `reused` is a view
+# of the buffer the worker keeps while its backlog lasts (pages touched
+# before), `fresh` a new buffer — a backlog's first step, or one larger
+# than any before it; one a step
+repair_step_arrays = DEFAULT.counter(
+    "cubefs_repair_step_arrays_total",
+    "arrays decode steps of repairs filled, by where the array came "
+    "from (reused / fresh)", ("result",))
 repair_widths_per_step = DEFAULT.histogram(
     "cubefs_repair_widths_per_step",
     "distinct shard sizes among the bids of one decode step",
