@@ -25,7 +25,11 @@ lacks that door cannot run the cell and fails here, at once. After the
 window: sampled PUTs of every doubling are read back, stored stripes of
 every codemode (a two-blob object among them) are compared shard by
 shard with ``cellbench/reference.py``, and the run is not ``correct`` if
-a PUT went unacknowledged or a codec program was built after ``ready``.
+a codec program was built after ``ready``. A PUT the window closed on is
+late, not wrong (``puts_late`` on the detail line): it is waited for, a
+failure of it counts in ``failed``, and the rate is what the window saw
+- its bytes over the time to its last acknowledgement - so a run the
+host stalled reads slow and stays ``correct``.
 """
 
 from __future__ import annotations
@@ -148,9 +152,6 @@ def verify(cell) -> tuple[bool, dict]:
     want = tr.get("verify", {})
     rng = np.random.default_rng([cell.seed, 4])
     faults: list[str] = []
-    if len(st.done) != len(st.order):
-        faults.append(f"{len(st.done)} of {len(st.order)} PUTs were "
-                      f"acknowledged inside the window")
     built = registry.total(cell.registry, PROGRAMS)
     if built:
         faults.append(f"{int(built)} codec programs were built after "
@@ -184,5 +185,6 @@ def verify(cell) -> tuple[bool, dict]:
                         "several_blob_objects_checked": int(two_blob),
                         "codemodes_checked": len(by_mode),
                         "puts_in_window": len(st.done),
+                        "puts_late": len(st.order) - len(st.done),
                         "programs_built_in_window": int(built),
                         "faults": faults[:10]}

@@ -21,8 +21,10 @@ the run is then not ``correct``. The window is ``repair_backlog.run``:
 ``worker.run_once()`` on the backlog, whole tasks only, one operation =
 one rebuilt shard written back (kind ``repair_shard``).
 
-One task rebuilds one unit, as upstream schedules it; the two tasks of a
-volume that lost two units each read their own survivors.
+One task rebuilds one unit, as upstream schedules it; the scheduler
+leases the two tasks of a volume that lost two units together, and the
+worker decodes both units from one read of the survivors (one
+``run_once`` of the window is such a lease).
 """
 
 from __future__ import annotations

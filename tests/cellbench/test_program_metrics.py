@@ -108,13 +108,17 @@ def test_counter_over_stage_seconds_is_work_per_second_worked():
 def test_the_new_entries_read_the_program_and_not_a_wrapper():
     """Every metric this file lists comes from the program's registry
     (`program_span` / `program_counter`), through a reader that takes no
-    benchmark span, and is reported in exactly the one cell it names."""
+    benchmark span, and is moved by the cell's own end-to-end metric: the
+    one entry lists the cell beside the others of its group."""
     bench = spec.load_benchmark()
     by_name = {m["name"]: m for m in bench["per_layer"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     for cell, names in NEW.items():
         for name in names:
             m = by_name[name]
-            assert m["workloads"] == [cell], name
+            assert cell in m["workloads"], name
+            assert set(m["workloads"]) <= set(
+                e2e[m["moves"]]["workloads"]), name
             want = ("program_counter" if name == "repair.rebuilt_rate"
                     else "program_span")
             assert m["source"] == want, name

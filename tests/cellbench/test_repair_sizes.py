@@ -51,22 +51,25 @@ def test_the_cell_repairs_mixed_volumes_and_is_correct():
     bench = spec.load_benchmark()
     want = {e["name"] for e in spec.metric_entries(
         bench, "disk-repair-randsize", "per_layer")}
-    # no device trace on the CPU: the three metrics that read it stay out
-    off_chip = {"kernel.busy_share_of_call-rsz", "gf_apply_roofline-rsz",
-                "pallas_gf_roofline-rsz"}
-    assert len(want) == 21 and want - set(m) <= off_chip | {
-        "engine.h2d_ms-rsz", "engine.d2h_ms-rsz", "engine.launch_ms-rsz"}
-    assert m["dispatch.compiles_in_window-rsz"] == 0
-    assert m["dispatch.device_step_share-rsz"] == 100
+    # no device trace on the CPU: the two metrics that read it stay out
+    off_chip = {"gf_apply_roofline-repair", "pallas_gf_roofline-repair"}
+    # an engine takes apart at most one call in PHASE_EVERY_S
+    sampled = {f"engine.{p}_ms-repair"
+               for p in ("matrix", "h2d", "launch", "wait", "d2h")}
+    assert len(want) == 27 and want - set(m) <= off_chip | sampled
+    assert m["dispatch.compiles_in_window-repair"] == 0
+    assert m["dispatch.device_step_share-repair"] == 100
     # volumes of four blobs: a task is a step or two of 1-4 bids of as
     # many sizes, each array zero stripes up to eight
-    assert 1 <= m["repair.steps_per_task-rsz"] <= 3
-    assert 1 <= m["repair.widths_per_step-rsz"] <= 4
-    assert m["repair.widths_per_step-rsz"] <= m[
-        "batcher.stripes_per_step-rsz"] <= 4
-    assert 50 < m["batcher.pad_share-rsz"] < 100
-    assert m["repair.rebuilt_rate-rsz"] > 0 and m["engine.call_ms-rsz"] > 0
-    shares = [m[f"repair.{s}_share-rsz"]
+    assert 1 <= m["repair.steps_per_task"] <= 3
+    assert 1 <= m["repair.widths_per_step"] <= 4
+    assert m["repair.widths_per_step"] <= m[
+        "batcher.stripes_per_step-repair"] <= 4
+    assert 50 < m["batcher.pad_share-repair"] < 100
+    assert m["repair.rebuilt_rate"] > 0 and m["engine.step_ms-repair"] > 0
+    # the kept step array (PR 40): the ramp's step made it
+    assert 0 <= m["repair.step_array_reuse_share"] <= 100
+    shares = [m[f"repair.{s}_share"]
               for s in ("read", "decode", "writeback")]
     assert 90 < sum(shares) <= 100.5
 
