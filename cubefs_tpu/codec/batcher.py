@@ -144,9 +144,10 @@ class CodecFuture:
 
 class _GeometryQueue:
     """Pending submissions for one (op, engine, geometry) key. An apply
-    key carries its matrix (a step has one matrix), and a worker meets
-    hundreds of survivor sets: the queue lives in the map only while it
-    holds submissions or a drain is in flight (``_drain`` drops it)."""
+    key, and an LRC encode's, carries its matrix (a step has one
+    matrix), and a worker meets hundreds of survivor sets: the queue
+    lives in the map only while it holds submissions or a drain is in
+    flight (``_drain`` drops it)."""
 
     __slots__ = ("subs", "busy", "coeff")
 
@@ -273,12 +274,15 @@ class BatchCodec:
 
     # ---------------- public submit surface ----------------
     def submit_encode(self, engine: str | None, data: np.ndarray,
-                      n_parity: int, timeout: float = 120.0) -> np.ndarray:
+                      n_parity: int, timeout: float = 120.0,
+                      rows: np.ndarray | None = None,
+                      local_rows: int = 0) -> np.ndarray:
         """(B, N, S) data -> (B, M, S) parity, coalesced with every
-        concurrent submission of the same (N, M, engine) whose S lies
-        in the same width rung."""
+        concurrent submission of the same (N, M, engine, rows) whose S
+        lies in the same width rung."""
         return self.submit_encode_async(
-            engine, data, n_parity, timeout).result(timeout)
+            engine, data, n_parity, timeout, rows=rows,
+            local_rows=local_rows).result(timeout)
 
     def submit_apply(self, engine: str | None, coeff: np.ndarray,
                      shards: np.ndarray, timeout: float = 120.0
@@ -290,8 +294,9 @@ class BatchCodec:
 
     def submit_encode_async(self, engine: str | None, data: np.ndarray,
                             n_parity: int, timeout: float = 120.0,
-                            width: int | Sequence[int] | None = None
-                            ) -> CodecFuture:
+                            width: int | Sequence[int] | None = None,
+                            rows: np.ndarray | None = None,
+                            local_rows: int = 0) -> CodecFuture:
         """submit_encode that parks and returns immediately: collect
         with .result(). A caller pipelining K submissions before its
         first collect keeps K stripes continuously admitted — the
@@ -304,8 +309,15 @@ class BatchCodec:
         sequence gives each stripe's own count (a repair step's bids
         have the sizes they have) and may be shorter than the array: the
         stripes past it are pad too (zero stripes up to a stripe rung),
-        and the rows come back ``[:len(width), :, :max(width)]``."""
-        key, coeff, arr = self._prep_encode(engine, data, n_parity)
+        and the rows come back ``[:len(width), :, :max(width)]``.
+
+        ``rows``: the (M, N) generator rows where they are not RS's
+        systematic parity rows — an LRC codemode's global rows and, the
+        last ``local_rows`` of them, its local rows composed through
+        the global ones (rs_kernel.lrc_encode_rows): both levels in one
+        step. A step's span carries ``local_rows``."""
+        key, coeff, arr = self._prep_encode(engine, data, n_parity, rows,
+                                            local_rows)
         return self._enqueue(key, coeff, arr, timeout, width)
 
     def submit_apply_async(self, engine: str | None, coeff: np.ndarray,
@@ -317,13 +329,21 @@ class BatchCodec:
         return self._enqueue(key, coeff, arr, timeout, width)
 
     # ---------------- admission ----------------
-    def _prep_encode(self, engine, data, n_parity):
+    def _prep_encode(self, engine, data, n_parity, rows=None,
+                     local_rows=0):
         data = np.asarray(data)
         if data.ndim != 3:
             raise ValueError(f"submit_encode takes (B, N, S), got "
                              f"{data.shape}")
         n, s = int(data.shape[1]), rs_kernel.rung_width(data.shape[2])
-        return ("encode", engine or "", n, int(n_parity), s), None, data
+        key = ("encode", engine or "", n, int(n_parity), s)
+        if rows is None:
+            return key, None, data
+        rows = np.ascontiguousarray(rows, dtype=np.uint8)
+        if rows.shape != (int(n_parity), n):
+            raise ValueError(f"submit_encode: {rows.shape} generator rows "
+                             f"for {n_parity} parity rows of {n}")
+        return key + (rows.tobytes(), int(local_rows)), rows, data
 
     def _prep_apply(self, engine, coeff, shards):
         shards = np.asarray(shards)
@@ -504,9 +524,9 @@ class BatchCodec:
         # submission counter locks are measurable at this call rate
         metrics.codec_batch_submissions.inc(
             sum(len(sub.widths) for sub in batch), op=op)
-        # the key carries the geometry: encode (.., n, m, rung), apply
-        # (.., coeff, c, rung); the cap is reckoned on the rung, the
-        # width every stripe of the step goes up at
+        # the key carries the geometry: encode (.., n, m, rung[, rows,
+        # local rows]), apply (.., coeff, c, rung); the cap is reckoned
+        # on the rung, the width every stripe of the step goes up at
         geometry = (int(key[3]) if op == "apply" else int(key[2]),
                     int(key[4]))
         stripe_cap = self._caps.get(geometry)
@@ -578,6 +598,8 @@ class BatchCodec:
         span.set_tag("stripes", n_stripes)
         span.set_tag("rung_b", shape[0]).set_tag("rung_s", shape[2])
         span.set_tag("pad_bytes", pad)
+        if op == "encode":  # an LRC key carries its rows and this count
+            span.set_tag("local_rows", key[6] if len(key) > 5 else 0)
         with span:
             with self._lock:
                 self._seam_tick()
@@ -652,9 +674,11 @@ class BatchCodec:
             name = engine_for(int(arr.nbytes)).name
         if op == "encode":
             m = int(key[3])
-            out = self._maybe_dp(name, None, arr, m)
-            if out is None:
+            out = self._maybe_dp(name, coeff, arr, m)
+            if out is None and coeff is None:
                 out, name = _dispatch(name, "encode_parity", arr, m)
+            elif out is None:  # an encode by generator rows of its own
+                out, name = _dispatch(name, "matrix_apply", coeff, arr)
         else:
             out = self._maybe_dp(name, coeff, arr, None)
             if out is None:
@@ -753,18 +777,22 @@ class AdmittedEngine:
         self.label = label
         self.name = label or os.environ.get("CUBEFS_TPU_EC_ENGINE", "tpu")
 
-    def encode_parity(self, data: np.ndarray, n_parity: int) -> np.ndarray:
+    def encode_parity(self, data: np.ndarray, n_parity: int,
+                      rows: np.ndarray | None = None,
+                      local_rows: int = 0) -> np.ndarray:
         data = np.asarray(data)
         if data.ndim < 2:
             raise ValueError(f"shards must be (..., N, S), got {data.shape}")
+        kw = {"rows": rows, "local_rows": local_rows}
         if data.ndim == 2:
             return self.batcher.submit_encode(
-                self.label, data[None], n_parity)[0]
+                self.label, data[None], n_parity, **kw)[0]
         if data.ndim == 3:
-            return self.batcher.submit_encode(self.label, data, n_parity)
+            return self.batcher.submit_encode(self.label, data, n_parity,
+                                              **kw)
         lead = data.shape[:-2]
         out = self.batcher.submit_encode(
-            self.label, data.reshape(-1, *data.shape[-2:]), n_parity)
+            self.label, data.reshape(-1, *data.shape[-2:]), n_parity, **kw)
         return out.reshape(*lead, *out.shape[-2:])
 
     def matrix_apply(self, coeff: np.ndarray, shards: np.ndarray,

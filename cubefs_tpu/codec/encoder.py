@@ -11,11 +11,15 @@ device kernels see large contiguous batches, never per-shard slices.
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 
 import numpy as np
 
 from ..ops import rs_kernel
+from ..utils import metrics
+from ..utils import trace as tracelib
 from . import codemode as cm
 from .batcher import admit
 from .engine import Engine
@@ -392,38 +396,64 @@ class MsrEncoder(Encoder):
         return shards
 
 
+@functools.cache
+def _lrc_rows(t: cm.Tactic) -> np.ndarray:
+    """The codemode's composed (m + l, n) parity rows, built once."""
+    stripes, ln, _ = t.all_local_stripes()
+    rows = rs_kernel.lrc_encode_rows(t.n, t.n + t.m, stripes, ln)
+    rows.flags.writeable = False
+    return rows
+
+
 class LrcEncoder(Encoder):
     """Two-level LRC codec: global RS(N+M) plus per-AZ local parity
     RS((N+M)/az, L/az). Local stripes allow intra-AZ reconstruction
-    without crossing the DCN (lrcencoder.go:133-186 semantics)."""
+    without crossing the DCN (lrcencoder.go:133-186 semantics). Both
+    levels are made by one apply of the composed (m + l, n) rows
+    (`rows`): one admitted step a PUT, and the one way encode, verify
+    and reconstruct make LRC parity."""
 
     @property
     def _local_nm(self) -> tuple[int, int]:
         t = self.t
         return (t.n + t.m) // t.az_count, t.l // t.az_count
 
+    @property
+    def rows(self) -> np.ndarray:
+        return _lrc_rows(self.t)
+
+    def _ready_step(self, b: int, width: int) -> None:
+        """The composed step's program and, once a rung, the (n, n)
+        decode of a degraded GET at it: an RS encode gets that from the
+        device engine (engine.ready_decode), a step of rows does not."""
+        super()._ready_step(b, width)
+        if b == 1:
+            n = self.t.n
+            self.engine.matrix_apply(np.eye(n, dtype=np.uint8),
+                                     np.zeros((1, n, width), dtype=np.uint8))
+
+    def _parity(self, data: np.ndarray) -> np.ndarray:
+        t = self.t
+        if self._batcher() is None:  # a raw engine
+            return self.engine.matrix_apply(self.rows, data)
+        return self.engine.encode_parity(data, t.m + t.l, rows=self.rows,
+                                         local_rows=t.l)
+
+    def _submit_rows(self, data: np.ndarray, shard_size: int):
+        batcher = self._batcher()
+        if batcher is None:
+            return None
+        t = self.t
+        return batcher.submit_encode_async(
+            self.engine.label, data, t.m + t.l, width=shard_size,
+            rows=self.rows, local_rows=t.l)
+
     def _finish_rows(self, data: np.ndarray, fut, timeout: float
                      ) -> np.ndarray:
-        """The global parity rides the admitted step; the per-AZ local
-        parity (cheap, depends on the global rows) is computed here,
-        after the step lands."""
-        t = self.t
-        glob = (fut.result(timeout) if fut is not None
-                else self.engine.encode_parity(data, t.m))
-        # the step hands back the shard's own width: rows built at the
-        # width rung are read up to it
-        data = data[..., : glob.shape[-1]]
-        parity = np.empty(data.shape[:-2] + (t.m + t.l, data.shape[-1]),
-                          dtype=np.uint8)
-        parity[..., : t.m, :] = glob
-        ln, lm = self._local_nm
-        for az in range(t.az_count):
-            stripe_idx, _, _ = t.local_stripe_in_az(az)
-            local_data = np.stack(
-                [data[..., i, :] if i < t.n else parity[..., i - t.n, :]
-                 for i in stripe_idx[:ln]], axis=-2)
-            parity[..., [i - t.n for i in stripe_idx[ln:]], :] = \
-                self.engine.encode_parity(local_data, lm)
+        parity = fut.result(timeout) if fut is not None else self._parity(data)
+        if tracelib.enabled():
+            metrics.codec_lrc_local.inc(math.prod(data.shape[:-2]),
+                                        how="in_step")
         return self._verified(data, parity)
 
     def verify(self, shards: np.ndarray) -> bool:
@@ -434,15 +464,8 @@ class LrcEncoder(Encoder):
             parity = self.engine.encode_parity(shards[..., :ln, :], lm)
             return bool(np.array_equal(parity, shards[..., ln:, :]))
         shards = self._check(shards)
-        parity = self.engine.encode_parity(shards[..., : t.n, :], t.m)
-        if not np.array_equal(parity, shards[..., t.n : t.n + t.m, :]):
-            return False
-        for az in range(t.az_count):
-            stripe_idx, _, _ = t.local_stripe_in_az(az)
-            local_parity = self.engine.encode_parity(shards[..., stripe_idx[:ln], :], lm)
-            if not np.array_equal(local_parity, shards[..., stripe_idx[ln:], :]):
-                return False
-        return True
+        return bool(np.array_equal(self._parity(shards[..., : t.n, :]),
+                                   shards[..., t.n:, :]))
 
     def reconstruct(self, shards: np.ndarray, bad_idx: list[int]) -> np.ndarray:
         shards = np.asarray(shards, dtype=np.uint8)
@@ -469,14 +492,12 @@ class LrcEncoder(Encoder):
             self._reconstruct(
                 shards[..., : t.n + t.m, :], global_bad, wanted=global_bad
             )
-        # local parities are recomputed from their (now complete) stripes
-        local_bad_azs = sorted(
-            {(i - t.n - t.m) * t.az_count // t.l for i in bad_idx if i >= t.n + t.m}
-        )
-        for az in local_bad_azs:
-            stripe_idx, _, _ = t.local_stripe_in_az(az)
-            local_data = shards[..., stripe_idx[:ln], :]
-            shards[..., stripe_idx[ln:], :] = self.engine.encode_parity(local_data, lm)
+        # lost local parities: their rows of `rows` over the (now
+        # complete) data shards
+        local_bad = sorted({i for i in bad_idx if i >= t.n + t.m})
+        if local_bad:
+            shards[..., local_bad, :] = self.engine.matrix_apply(
+                self.rows[[i - t.n for i in local_bad]], shards[..., : t.n, :])
         return shards
 
     def reconstruct_data(self, shards: np.ndarray, bad_idx: list[int]) -> np.ndarray:

@@ -481,6 +481,20 @@ def lrc_reconstruct_rows(
     return gf256.gf_matmul(rows, dec)
 
 
+def lrc_encode_rows(n_data: int, n_total: int, stripes: list[list[int]],
+                    ln: int) -> np.ndarray:
+    """The (m + l, n_data) rows that make ALL of a two-level LRC's
+    parity from its data shards in one apply: the global rows of the
+    systematic RS(n_data, n_total), then every local parity composed
+    through them — lrc_reconstruct_rows with every data shard present.
+    Local parity is linear in the data, so one step of these rows is
+    bit-identical to the global step followed by each AZ's own."""
+    total = n_total + sum(len(s) - ln for s in stripes)
+    return lrc_reconstruct_rows(n_data, n_total, stripes, ln,
+                                list(range(n_data)),
+                                list(range(n_data, total)))
+
+
 def reconstruct_stripes(
     surviving: jax.Array,
     present: list[int],

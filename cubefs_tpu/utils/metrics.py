@@ -470,6 +470,14 @@ codec_pallas_gate = DEFAULT.counter(
     "cubefs_codec_pallas_gate_total",
     "fused-kernel programs through the miscompile gate "
     "(blessed / refused)", ("result",))
+# LRC parity (codec/encoder.py: LrcEncoder): one a blob encoded, by how
+# its local parity was made — `in_step`, in the one admitted step with
+# the global rows (since PR 42), or `separate`, in steps of their own
+# after it (how the port made it until PR 42; no path does since)
+codec_lrc_local = DEFAULT.counter(
+    "cubefs_codec_lrc_local_total",
+    "LRC blobs encoded, by how their local parity was made "
+    "(in_step / separate)", ("how",))
 
 # repair-bandwidth observability (blob/worker.py): what a single-shard
 # repair actually pulls over the network, split by failure-domain scope

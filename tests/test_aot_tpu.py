@@ -163,3 +163,28 @@ def test_repair_step_programs_compile_for_v5e(v5e_sharding, rows, cols,
     x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=v5e_sharding)
     m = jax.jit(program).lower(w, x).compile().memory_analysis()
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 4 * (1 << 30)
+
+
+@pytest.mark.parametrize("stripes,tiles", [
+    (8, 18),  # a 64 MiB PUT: 8 blobs of 524,288 B shards at the 18-tile rung
+    (1, 4),   # the fused program's narrowest rung, one blob
+])
+def test_lrc_step_programs_compile_for_v5e(v5e_sharding, stripes, tiles):
+    """An EC16P20L2 PUT's one step (PR 42): the composed 22 x 16 rows,
+    global and both AZs' local parity, at the shapes
+    `AccessHandler.ready` builds on the chip — the benchmark's first
+    step bound by the chip's int8 rate rather than by HBM."""
+    from cubefs_tpu.codec import codemode as cm
+    from cubefs_tpu.codec.encoder import _lrc_rows
+
+    rows = _lrc_rows(cm.tactic(cm.CodeMode.EC16P20L2))
+    assert rows.shape == (22, 16)
+    tile = pallas_gf.DEFAULT_TILE
+    shape = (stripes, 16, tiles * tile)
+    assert rs_kernel._pallas_profitable(shape[2])
+    program = pallas_gf._apply_fn.__wrapped__(22, 16, shape, tile, False)
+    w = jax.ShapeDtypeStruct((8 * 22, 8 * 16), jnp.int8,
+                             sharding=v5e_sharding)
+    x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=v5e_sharding)
+    m = jax.jit(program).lower(w, x).compile().memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 4 * (1 << 30)

@@ -374,8 +374,8 @@ def test_encoder_encode_async_matches_sync(rng):
 
 
 def test_lrc_encode_async_matches_sync(rng):
-    """LRC: the global parity rides the batcher; the per-AZ local
-    parity is computed at wait() time on top of it."""
+    """LRC: the global and the per-AZ local parity ride the batcher
+    as one step of the composed rows (PR 42)."""
     from cubefs_tpu.codec.codemode import CodeMode
     from cubefs_tpu.codec.encoder import CodecConfig, new_encoder
 
