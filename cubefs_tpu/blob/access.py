@@ -25,7 +25,7 @@ import numpy as np
 
 from ..codec import codemode as cm
 from ..codec.encoder import CodecConfig, new_encoder
-from ..utils import lockwitness, metrics, qos, rpc
+from ..utils import hostmem, lockwitness, metrics, qos, rpc
 from ..utils import trace as tracelib
 from .types import Location, Slice, VolumeInfo
 
@@ -45,15 +45,9 @@ DEFAULT_POLICIES = [
 ]
 
 
-# glibc serves an allocation above this size (its
-# DEFAULT_MMAP_THRESHOLD_MAX on 64-bit) from a mapping of its own, every
-# time: each page of such an array is a fault at first touch (~5 us a
-# 4 KiB page where the host has no transparent huge pages, PERF.md §6),
-# whatever the memory bandwidth. Below it free() grows the heap's
-# threshold to the sizes the process frees, and malloc hands them back
-# mapped. So a PUT's data rows above it are kept for the next PUT of the
-# same shape, up to STRIPE_ROWS_KEPT_BYTES (then the oldest is freed).
-MALLOC_MMAP_MAX = 32 << 20
+# A PUT's data rows above malloc's mmap threshold (utils/hostmem.py) are
+# kept for the next PUT of the same shape, up to STRIPE_ROWS_KEPT_BYTES
+# (then the oldest is freed).
 STRIPE_ROWS_KEPT_BYTES = 512 << 20
 
 
@@ -307,7 +301,7 @@ class AccessHandler:
         shape on the free list, else a new one. Arrays malloc serves
         from its own heap are warm already and never enter the list."""
         rows = None
-        if shape[0] * shape[1] * shape[2] > MALLOC_MMAP_MAX:
+        if shape[0] * shape[1] * shape[2] > hostmem.MALLOC_MMAP_MAX:
             with self._lock:
                 free = self._free_rows
                 for k in range(len(free) - 1, -1, -1):
@@ -320,7 +314,7 @@ class AccessHandler:
         return rows if rows is not None else np.empty(shape, dtype=np.uint8)
 
     def _return_stripe_rows(self, rows: np.ndarray) -> None:
-        if rows.nbytes <= MALLOC_MMAP_MAX:
+        if rows.nbytes <= hostmem.MALLOC_MMAP_MAX:
             return
         with self._lock:
             free = self._free_rows

@@ -34,13 +34,15 @@ from __future__ import annotations
 import logging
 import math
 import os
+import sys
+import threading
 import time
 from typing import Protocol
 
 import numpy as np
 
 from ..ops import gf256, progcache, rs_kernel, xorprog
-from ..utils import metrics
+from ..utils import hostmem, metrics
 from ..utils import trace as tracelib
 
 _log = logging.getLogger("cubefs.codec")
@@ -91,6 +93,84 @@ _PHASE_SPANS = {p: f"{tracelib.PROFILE_PREFIX}codec.{p}"
 # small PUTs.
 PHASE_EVERY_S = 0.25
 
+# Result buffers the engine keeps, at most this many bytes of them (the
+# front door keeps its data rows to the same cap).
+RESULT_BUFFERS_KEPT_BYTES = 512 << 20
+
+
+class ResultBuffers:
+    """Host arrays a device result over malloc's mmap threshold lands in
+    (utils/hostmem.py): np.asarray would put it in a fresh mapping every
+    step, each page a fault at first touch. A buffer is handed out only
+    when nothing holds it — a caller's view, a future's slice or a PUT's
+    parity rows all keep a reference to it — and a shape whose buffers
+    are all held gets a new one, kept while the cap allows (the least
+    recently handed out go first)."""
+
+    def __init__(self, cap: int = RESULT_BUFFERS_KEPT_BYTES):
+        self.cap = cap
+        self._kept: list[np.ndarray] = []  # least recently handed out first
+        self._lock = threading.Lock()
+
+    def take(self, shape: tuple) -> tuple[np.ndarray, str]:
+        """(a uint8 array of `shape`, "reused" or "fresh")."""
+        with self._lock:  # two geometry queues' calls overlap
+            kept = self._kept
+            for k in range(len(kept)):
+                # 2: the list's reference and getrefcount's argument
+                if kept[k].shape == shape and sys.getrefcount(kept[k]) == 2:
+                    buf = kept.pop(k)
+                    kept.append(buf)
+                    return buf, "reused"
+            buf = np.empty(shape, dtype=np.uint8)
+            if buf.nbytes <= self.cap:
+                kept.append(buf)
+                while sum(b.nbytes for b in kept) > self.cap:
+                    del kept[0]
+            return buf, "fresh"
+
+
+RESULTS = ResultBuffers()
+
+
+def _splitter(shape: tuple):
+    """(one jitted program that cuts a device result of `shape` into
+    slices along the stripe axis, their stripe bounds): each slice holds
+    at most half the mmap threshold, so the two in flight fit under it
+    and malloc serves each from the pages the last one gave back."""
+    per = max(1, hostmem.MALLOC_MMAP_MAX // 2 // math.prod(shape[1:]))
+    bounds = [(a, min(a + per, shape[0])) for a in range(0, shape[0], per)]
+
+    def build():
+        import jax
+
+        return jax.jit(lambda y: tuple(y[a:z] for a, z in bounds)), bounds
+
+    return progcache.SHARED.get_or_build("result_split", (shape, per), build)
+
+
+def _to_host(y) -> np.ndarray:
+    """Device result `y` as a host array. Over the mmap threshold it
+    lands in a kept buffer: its slices come back one ahead of the copy
+    into the buffer, each into pages malloc just had back."""
+    if y.nbytes <= hostmem.MALLOC_MMAP_MAX:
+        return np.asarray(y)
+    buf, came = RESULTS.take(tuple(y.shape))
+    split, bounds = _splitter(tuple(y.shape))
+    pieces = list(split(y))
+    pieces[0].copy_to_host_async()
+    for k, (a, z) in enumerate(bounds):
+        if k + 1 < len(pieces):
+            pieces[k + 1].copy_to_host_async()
+        buf[a:z] = np.asarray(pieces[k])
+        pieces[k] = None  # its host copy goes back to malloc now
+    if tracelib.enabled():
+        metrics.codec_result_buffers.inc(result=came)
+        span = tracelib.current()
+        if span is not None:
+            span.set_tag("result_buffer", came)
+    return buf
+
 
 def device_call(eng, op: str, matrix, program, host_in: np.ndarray
                 ) -> np.ndarray:
@@ -102,13 +182,13 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
     `matrix`, `h2d` (device_put until the input is on the device),
     `launch` (`program(w, x)`: the Python dispatch, until it returns its
     not-yet-ready result), `wait` (until the result is ready), `d2h`
-    (np.asarray) — each one sample of
+    (`_to_host`) — each one sample of
     cubefs_codec_engine_phase_seconds{engine,op,phase} and one
     `cubefs:codec.<phase>` profiler annotation. Every other call, and
     every call with CUBEFS_TRACE=0, is the bare call."""
     now = time.perf_counter()
     if not tracelib.enabled() or now < getattr(eng, "_phase_due", 0.0):
-        return np.asarray(program(matrix(), host_in))
+        return _to_host(program(matrix(), host_in))
     eng._phase_due = now + PHASE_EVERY_S
     import jax
 
@@ -126,7 +206,7 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
     x = phase("h2d", lambda: jax.block_until_ready(jax.device_put(host_in)))
     y = phase("launch", program, w, x)
     phase("wait", jax.block_until_ready, y)
-    return phase("d2h", np.asarray, y)
+    return phase("d2h", _to_host, y)
 
 
 def ready_decode(n: int, s: int) -> None:
@@ -141,8 +221,8 @@ def ready_decode(n: int, s: int) -> None:
     def build() -> bool:
         coeff = np.eye(n, dtype=np.uint8)
         planes, program = rs_kernel.plan(coeff, (1, n, s))
-        np.asarray(program(rs_kernel.device_bits(coeff, planes),
-                           np.zeros((1, n, s), dtype=np.uint8)))
+        _to_host(program(rs_kernel.device_bits(coeff, planes),
+                         np.zeros((1, n, s), dtype=np.uint8)))
         return True
 
     progcache.SHARED.get_or_build("decode_ready", (n, s), build)

@@ -371,6 +371,14 @@ codec_step_bytes = DEFAULT.counter(
     "cubefs_codec_step_bytes_total",
     "input bytes of drained device steps (payload / pad)",
     ("op", "kind"))
+# a device result over malloc's mmap threshold (codec/engine.py:
+# _to_host): `reused` lands in a host buffer the engine kept from an
+# earlier step of its shape (pages touched before), `fresh` in a new
+# one; one a device call over the threshold, none under it
+codec_result_buffers = DEFAULT.counter(
+    "cubefs_codec_result_buffers_total",
+    "device results over malloc's mmap threshold, by the host buffer "
+    "they landed in (reused / fresh)", ("result",))
 codec_batch_widths = DEFAULT.histogram(
     "cubefs_codec_batch_widths_per_step",
     "distinct payload widths coalesced per drained device step",

@@ -17,7 +17,7 @@ from cubefs_tpu.blob import access as access_mod
 from cubefs_tpu.blob.access import PutQuorumError
 from cubefs_tpu.codec import codemode as cmode
 from cubefs_tpu.ops import msr, rs_kernel
-from cubefs_tpu.utils import metrics
+from cubefs_tpu.utils import hostmem, metrics
 from test_blob_e2e import Cluster
 
 BLOB = 64 << 10  # the test cluster's blob size
@@ -27,7 +27,7 @@ BLOB = 64 << 10  # the test cluster's blob size
 def cluster(tmp_path, monkeypatch):
     # every size is "above the allocator's own reuse": the free list
     # engages at the tests' sizes as it does for 64 MiB objects
-    monkeypatch.setattr(access_mod, "MALLOC_MMAP_MAX", 0)
+    monkeypatch.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
     c = Cluster(tmp_path, n_nodes=4, disks_per_node=4)  # 16 units: EC12P4
     c.cm.allow_colocated_units = True
     return c

@@ -18,7 +18,7 @@ from cubefs_tpu.codec import codemode as cmode
 from cubefs_tpu.codec.batcher import BatchCodec, admit
 from cubefs_tpu.codec.engine import get_engine
 from cubefs_tpu.ops import gf256, pallas_gf, rs_kernel
-from cubefs_tpu.utils import metrics, rpc
+from cubefs_tpu.utils import hostmem, metrics, rpc
 from test_blob_e2e import Cluster
 from test_put_stripe_rows import (BLOB, MODES, assert_stored_equals_reference)
 
@@ -227,7 +227,7 @@ def cluster(tmp_path_factory):
     """One cluster for every case below, its codec callers on the
     device engine; the free list engages at the tests' sizes."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(access_mod, "MALLOC_MMAP_MAX", 0)
+    mp.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
     c = Cluster(tmp_path_factory.mktemp("ladder"), n_nodes=4,
                 disks_per_node=4)
     c.cm.allow_colocated_units = True
