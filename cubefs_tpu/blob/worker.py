@@ -18,6 +18,11 @@ survivors (`units_per_read`): each step array is filled once, and one
 decode step a lost unit runs over it. The step arrays of a backlog are
 views of one buffer the worker keeps from its first step until it finds
 no task to lease (`_step_array`): pages touched once, not once a step.
+A lost unit of an LRC volume is rebuilt from its AZ's local stripe
+(upstream's recoverByLocalStripe), the global stripe the fallback; a
+local stripe of one local parity leaves no survivor to check with, so
+the step's second row derives the lost unit again through the global
+code from the same reads, and the two must agree before the write-back.
 """
 
 from __future__ import annotations
@@ -35,7 +40,7 @@ import numpy as np
 from ..ops import rs_kernel
 from ..codec import codemode as cm
 from ..codec.batcher import admit
-from ..utils import metrics, rpc
+from ..utils import hostmem, metrics, rpc
 from ..utils import trace as tracelib
 from . import topology
 from .types import VolumeInfo
@@ -64,7 +69,8 @@ def solve_and_wanted(subs: list[int], n_solve: int, bad_sub: int
     """(solving survivors, rows to rebuild) for the survivors actually
     read, ascending, lost units already skipped: the first `n_solve`
     solve; where one more was read it is rebuilt beside the lost unit
-    and compared with what was read — the pre-writeback check."""
+    and compared with what was read — the pre-writeback check. Where
+    none was, `RepairWorker._repair_rows` checks another way."""
     wanted = [bad_sub]
     if len(subs) > n_solve:
         wanted = sorted({bad_sub, subs[n_solve]})
@@ -74,10 +80,12 @@ def solve_and_wanted(subs: list[int], n_solve: int, bad_sub: int
 def units_per_read(t: cm.Tactic) -> int:
     """How many unit repairs of one volume one read of its survivors
     serves. The read leaves out every unit it rebuilds from the start,
-    so it is of the global stripe of a plain Reed-Solomon volume (an LRC
-    unit's local stripe and an MSR unit's helpers are that unit's own),
-    and of as many units as leave n + 1 to read: each keeps the checking
-    survivor it would have had alone."""
+    so it is of the global stripe of a plain Reed-Solomon volume, and of
+    as many units as leave n + 1 to read: each keeps the checking
+    survivor it would have had alone. An LRC unit reads its own AZ's
+    local stripe, which holds no unit past what the local code needs to
+    solve it (its check is a second derivation through the global code),
+    and an MSR unit its own helpers: one unit a read."""
     if t.l or t.is_msr():
         return 1
     return max(1, t.m - 1)
@@ -134,16 +142,20 @@ class RepairWorker:
               blob_size: int | None = None) -> int:
         """What a worker host does once at start-up, from what it knows:
         the cluster's size-class policies and its largest object. Every
-        program the repair of a Reed-Solomon unit can ask the device for
-        — any sizes in the volume, any lost unit, any survivor set — is
-        built here: one zero step through the worker's own door at each
-        shape of `rs_kernel.repair_steps`, REPAIR_ROWS rows by the
-        codemode's n columns. Never implied by construction; returns the
-        number of steps. LRC and MSR volumes are not listed: a local
-        stripe's and a sub-shard decode's programs are still built by
-        their first step."""
+        program the repair of a Reed-Solomon or an LRC unit can ask the
+        device for — any sizes in the volume, any lost unit, any
+        survivor set — is built here: one zero step through the worker's
+        own door at each shape of `rs_kernel.repair_steps`, REPAIR_ROWS
+        rows by the codemode's n columns (the global stripe) and, for an
+        LRC codemode, by its local stripe's ln columns too. Never
+        implied by construction; returns the number of steps. MSR
+        volumes are not listed: a sub-shard decode's programs are still
+        built by its first step. The host's heap keeps what a task frees
+        (`hostmem.keep_freed_heap`, process-wide): the next task's
+        survivors land in pages the last one touched."""
         from .access import AccessConfig
 
+        hostmem.keep_freed_heap()
         cfg = AccessConfig()
         policies = cfg.policies if policies is None else policies
         blob_size = cfg.blob_size if blob_size is None else blob_size
@@ -151,15 +163,17 @@ class RepairWorker:
         for p in policies:
             lo, hi = max(1, p.min_size), min(p.max_size, max_object_bytes)
             t = cm.tactic(cm.CodeMode[p.mode_name])
-            if not p.enable or lo > hi or t.l or t.is_msr():
+            if not p.enable or lo > hi or t.is_msr():
                 continue
-            rows = np.zeros((rs_kernel.REPAIR_ROWS, t.n), dtype=np.uint8)
-            for b, width in rs_kernel.repair_steps(
-                    *repair_shard_sizes(t, lo, hi, blob_size),
-                    self.batch_stripes):
-                self.codec.matrix_apply(
-                    rows, np.zeros((b, t.n, width), dtype=np.uint8))
-                steps += 1
+            for cols in sorted({t.n, t.local_stripe(0)[1] or t.n}):
+                rows = np.zeros((rs_kernel.REPAIR_ROWS, cols),
+                                dtype=np.uint8)
+                for b, width in rs_kernel.repair_steps(
+                        *repair_shard_sizes(t, lo, hi, blob_size),
+                        self.batch_stripes):
+                    self.codec.matrix_apply(
+                        rows, np.zeros((b, cols, width), dtype=np.uint8))
+                    steps += 1
         return steps
 
     # ---------------- loop ----------------
@@ -271,7 +285,7 @@ class RepairWorker:
         bads = [int(x["unit_index"]) for x in tasks]
 
         # discover the blob population, bids and shard sizes, from a
-        # surviving unit's chunk listing
+        # surviving unit's chunk listing (one of the lost unit's AZ)
         bids = self._list_bids(vol, exclude=bads)
         dests = [self.nodes.get(x["dest_addr"]) for x in tasks]
         if not bids:
@@ -289,11 +303,12 @@ class RepairWorker:
                 # from scratch
                 metrics.repair_msr_fallbacks.inc(reason=e.reason)
                 sp.set_tag("msr_fallback", e.reason)
-        return self._execute_conventional(tasks, vol, t, bads, bids, dests)
+        return self._execute_conventional(tasks, vol, t, bads, bids, dests,
+                                          sp)
 
     def _execute_conventional(self, tasks: list[dict], vol: VolumeInfo,
                               t: cm.Tactic, bads: list[int],
-                              bids: list[tuple[int, int]], dests: list
+                              bids: list[tuple[int, int]], dests: list, sp
                               ) -> dict[str, Exception]:
         # choose the read set: prefer the bad unit's local stripe peers
         # when an LRC local repair is possible (intra-AZ bandwidth). A
@@ -358,11 +373,16 @@ class RepairWorker:
                         continue  # local stripe unreadable: widen global
                     raise
                 break
+        sp.set_tag("source", source)
 
         units = [_Unit(task, sub, dest)
                  for task, sub, dest in zip(tasks, bad_subs, dests)]
+        # the local stripe's unit indices, by position in the local code:
+        # what a second derivation through the global code needs
+        stripe = tuple(local_idx) if source == "local" else None
         with tracelib.stage("decode"):
-            self._decode_groups(t, by_key, n_solve, total_code, units, exact)
+            self._decode_groups(t, by_key, n_solve, total_code, units, exact,
+                                stripe)
         for unit in units:
             if unit.error is None:
                 try:
@@ -372,6 +392,7 @@ class RepairWorker:
                     continue
                 if tracelib.enabled():
                     metrics.repair_steps_per_task.observe(unit.steps)
+                    metrics.repair_sources.inc(source=source)
         return {u.task["task_id"]: u.error for u in units
                 if u.error is not None}
 
@@ -388,10 +409,24 @@ class RepairWorker:
                 if tracelib.enabled():
                     metrics.repair_bytes_rebuilt.inc(len(shard))
 
-    def _repair_rows(self, t, subs, n_solve, total_code, bad_sub
-                     ) -> tuple[np.ndarray, int, int | None]:
-        """(the group's matrix, the lost unit's row in it, the checking
-        survivor's row or None) for the survivors `subs` as read."""
+    def _repair_rows(self, t, subs, n_solve, total_code, bad_sub,
+                     stripe=None) -> tuple[np.ndarray, int, int | None, str]:
+        """(the group's matrix, the lost unit's row in it, the row that
+        checks it or None, how: `survivor` / `derived` / `none`) for the
+        survivors `subs` as read. `survivor`: an extra survivor read is
+        rebuilt beside the lost unit, to be compared with what was read.
+        `derived`: a local stripe (`stripe`, its unit indices) read
+        without one — it holds one local parity — gives the lost unit a
+        second time through the global code, from the AZ's global units
+        already read (`rs_kernel.lrc_checked_rows`), to be compared with
+        the first. `none`: neither can be had; the lost unit's row twice,
+        so the step still runs one of the programs `ready` built."""
+        if stripe is not None and len(subs) == n_solve:
+            rows = rs_kernel.lrc_checked_rows(
+                t.n, t.n + t.m, tuple(map(tuple, t.ec_layout_by_az())),
+                n_solve, stripe, tuple(subs), bad_sub)
+            if rows is not None:
+                return rows, 0, 1, "derived"
         solve_subs, wanted_out = solve_and_wanted(subs, n_solve, bad_sub)
         if bad_sub >= total_code:
             # global fallback for a LOCAL PARITY unit: its row lives
@@ -413,13 +448,11 @@ class RepairWorker:
             rows = rs_kernel.reconstruct_rows(
                 n_solve, total_code, solve_subs, wanted_out
             )
-        if len(rows) < rs_kernel.REPAIR_ROWS:
-            # no extra survivor: the lost unit's row twice, so the step
-            # still runs one of the programs `ready` built
-            rows = np.repeat(rows, rs_kernel.REPAIR_ROWS, axis=0)
-        verify_pos = (wanted_out.index(subs[n_solve])
-                      if len(subs) > n_solve else None)
-        return rows, wanted_out.index(bad_sub), verify_pos
+        if len(subs) > n_solve:
+            return (rows, wanted_out.index(bad_sub),
+                    wanted_out.index(subs[n_solve]), "survivor")
+        return (np.repeat(rows, rs_kernel.REPAIR_ROWS, axis=0), 0, None,
+                "none")
 
     def _step_array(self, shape: tuple) -> np.ndarray:
         """The array of one decode step, to be filled and zeroed by its
@@ -440,7 +473,7 @@ class RepairWorker:
         return self._buffer[:size].reshape(shape)
 
     def _decode_groups(self, t, by_key, n_solve, total_code,
-                       units: list[_Unit], exact) -> None:
+                       units: list[_Unit], exact, stripe=None) -> None:
         """One step array per group and `batch_stripes` bids, and over
         it one device step a lost unit. A unit whose check fails keeps
         the error and takes no further step; the others go on. The
@@ -449,7 +482,8 @@ class RepairWorker:
         returned its rows to the host; nothing below keeps it."""
         for (wide, subs), group in by_key.items():
             plans = [(unit, *self._repair_rows(t, subs, n_solve, total_code,
-                                               unit.sub)) for unit in units]
+                                               unit.sub, stripe))
+                     for unit in units]
             for start in range(0, len(group), self.batch_stripes):
                 live = [p for p in plans if p[0].error is None]
                 if not live:
@@ -460,13 +494,15 @@ class RepairWorker:
                 with span:
                     batch = self._stack(t, wide, exact, n_solve, chunk,
                                         sizes, span)
-                    for unit, rows, out_pos, verify_pos in live:
+                    span.set_tag("check", ",".join(sorted(
+                        {p[4] for p in live})))
+                    for unit, rows, out_pos, check, how in live:
                         try:
                             recovered = self._apply(t, rows, batch, sizes,
                                                     exact)
                             unit.steps += 1
                             self._check_and_cut(unit, recovered, chunk,
-                                                out_pos, verify_pos, subs,
+                                                out_pos, check, how, subs,
                                                 n_solve)
                         except Exception as e:
                             unit.error = e
@@ -485,20 +521,30 @@ class RepairWorker:
         return self.codec.matrix_apply(rows, batch, width=sizes)
 
     def _check_and_cut(self, unit: _Unit, recovered, chunk, out_pos,
-                       verify_pos, subs, n_solve) -> None:
+                       check, how, subs, n_solve) -> None:
         with tracelib.stage("decode_verify"):
             for (bid, size, shards), rec in zip(chunk, recovered):
                 # cut to the bid's own size first: what is checked and
                 # written back never holds pad
-                if verify_pos is not None and not np.array_equal(
-                        rec[verify_pos, :size], np.frombuffer(
+                got = rec[out_pos, :size]
+                if how == "survivor" and not np.array_equal(
+                        rec[check, :size], np.frombuffer(
                             shards[n_solve], dtype=np.uint8)):
                     raise RuntimeError(
                         f"bid {bid}: reconstruction disagrees "
                         f"with extra survivor {subs[n_solve]} — "
                         f"refusing writeback (crc-conflict role)"
                     )
-                unit.writes.append((bid, rec[out_pos, :size].tobytes()))
+                if how == "derived" and not np.array_equal(
+                        rec[check, :size], got):
+                    raise RuntimeError(
+                        f"bid {bid}: reconstruction disagrees with its "
+                        f"derivation through the global code — refusing "
+                        f"writeback (crc-conflict role)"
+                    )
+                unit.writes.append((bid, got.tobytes()))
+        if tracelib.enabled():
+            metrics.repair_checks.inc(len(chunk), how=how)
 
     def _stack(self, t, wide, exact, n_solve, chunk, sizes, span
                ) -> np.ndarray:
@@ -678,8 +724,10 @@ class RepairWorker:
     def _list_bids(self, vol: VolumeInfo, exclude: list[int]
                    ) -> list[tuple[int, int]]:
         """(bid, shard size) of every blob of the volume, from the
-        chunk listing of the first unit that gives one."""
-        for u in vol.units:
+        chunk listing of the first unit that gives one, the lost unit's
+        AZ first: a repair inside an AZ asks nothing of another."""
+        home = vol.units[exclude[0]].az
+        for u in sorted(vol.units, key=lambda u: u.az != home):
             if u.index in exclude:
                 continue
             try:

@@ -24,6 +24,8 @@ reference byte-for-byte.
 from __future__ import annotations
 
 import collections
+import functools
+import itertools
 import logging
 import threading
 
@@ -343,8 +345,10 @@ def ladder(cols: int, s_lo: int, s_hi: int, max_step_bytes: int,
 # so it could ask for any of rung_batch's sixteen stripe rungs up to 64
 # at every width rung: hundreds of programs a codemode, each built by
 # the first task that meets it. The repair worker asks for few instead:
-# always REPAIR_ROWS rows (the lost unit's and the check's; the lost
-# one twice where no extra survivor was read) and a stripe rung of
+# always REPAIR_ROWS rows (the lost unit's and the check's: an extra
+# survivor rebuilt, or in a local stripe that leaves none the lost unit
+# derived again through the global code, `lrc_checked_rows`; the lost
+# one twice where neither can be had) and a stripe rung of
 # ``repair_batches`` — zero stripes up to it — so the set is small
 # enough to build before the first task (blob/worker.py:
 # RepairWorker.ready). Each is a rung of rung_batch, so the batcher
@@ -493,6 +497,39 @@ def lrc_encode_rows(n_data: int, n_total: int, stripes: list[list[int]],
     return lrc_reconstruct_rows(n_data, n_total, stripes, ln,
                                 list(range(n_data)),
                                 list(range(n_data, total)))
+
+
+@functools.lru_cache(maxsize=1024)
+def lrc_checked_rows(n_data: int, n_total: int,
+                     stripes: tuple[tuple[int, ...], ...], ln: int,
+                     stripe: tuple[int, ...], present: tuple[int, ...],
+                     lost: int) -> np.ndarray | None:
+    """(2, ln) rows that rebuild position ``lost`` of a local stripe
+    twice from the ``ln`` positions ``present`` as read (positions in
+    the local code, ``stripe`` their unit indices): row 0 through the
+    local code, row 1 through the global code, from ``n_data`` of the
+    global units among them (composed with the local encode row where
+    the lost unit is a local parity). A local stripe of one local parity
+    leaves no survivor to check with; the second derivation takes its
+    place without a read across AZs. The solving set is the first whose
+    row 1 differs from row 0 at every column: one wrong survivor then
+    always makes the two rows disagree (the difference of the rows is
+    its coefficient times the error, and GF(2^8) has no zero divisors),
+    which a fixed choice does not give for every lost position. None
+    where no solving set does that, as where the AZ holds fewer than
+    ``n_data`` global units besides the lost one."""
+    row0 = reconstruct_rows(ln, len(stripe), list(present), [lost])[0]
+    cols = [c for c, p in enumerate(present) if stripe[p] < n_total]
+    for solve in itertools.combinations(cols, n_data):
+        row1 = np.zeros_like(row0)
+        row1[list(solve)] = lrc_reconstruct_rows(
+            n_data, n_total, [list(s) for s in stripes], ln,
+            [stripe[present[c]] for c in solve], [stripe[lost]])[0]
+        if np.all(row0 ^ row1):
+            rows = np.stack([row0, row1])
+            rows.setflags(write=False)
+            return rows
+    return None
 
 
 def reconstruct_stripes(

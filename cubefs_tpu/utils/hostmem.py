@@ -10,6 +10,35 @@ threshold to the sizes the process frees, and malloc hands them back
 mapped. So an array above it that a hot path makes again and again is
 kept for the next use of its shape: a PUT's data rows
 (``blob/access.py``) and a device step's result (``codec/engine.py``).
+
+Left dynamic, free() also gives the top of the heap back to the system
+once more than twice that threshold lies free there (64 MiB at most). A
+repair task holds its survivors — a thousand reads of half a megabyte —
+until its step, and frees them together: whether they go back, and the
+next task faults every page in again, then depends on whether anything
+happens to sit above them. ``keep_freed_heap`` fixes both thresholds.
 """
 
+import ctypes
+
 MALLOC_MMAP_MAX = 32 << 20
+HEAP_KEPT_BYTES = 1 << 30
+
+# mallopt(3) parameters (malloc.h)
+M_TRIM_THRESHOLD = -1
+M_MMAP_THRESHOLD = -3
+
+
+def keep_freed_heap() -> bool:
+    """For the rest of the process: allocations up to ``MALLOC_MMAP_MAX``
+    come from the heap from the start (where the dynamic threshold ends
+    up), and free() gives the heap's top back only past
+    ``HEAP_KEPT_BYTES``, so what one request frees the next one reuses
+    without a fault. Returns whether the allocator took both; False
+    where it is not glibc's."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    return (mallopt(M_MMAP_THRESHOLD, MALLOC_MMAP_MAX) == 1
+            and mallopt(M_TRIM_THRESHOLD, HEAP_KEPT_BYTES) == 1)

@@ -525,6 +525,22 @@ repair_widths_per_step = DEFAULT.histogram(
     "cubefs_repair_widths_per_step",
     "distinct shard sizes among the bids of one decode step",
     buckets=(1, 2, 4, 8, 16, 32, 64))
+# where a repaired unit's survivors came from (blob/worker.py): its AZ's
+# local stripe (an LRC unit rebuilt inside its AZ) or the global stripe
+# (every Reed-Solomon unit, an LRC unit whose local stripe could not be
+# read); one a unit whose write-back ended
+repair_sources = DEFAULT.counter(
+    "cubefs_repair_sources_total",
+    "repaired units, by the stripe whose read served them "
+    "(local / global)", ("source",))
+# how a rebuilt shard was checked before its write-back: against an
+# extra survivor rebuilt beside it, against a second derivation of it
+# through the global code (a local stripe that leaves no extra
+# survivor), or not at all; one a rebuilt shard
+repair_checks = DEFAULT.counter(
+    "cubefs_repair_checks_total",
+    "rebuilt shards, by how they were checked before the write-back "
+    "(survivor / derived / none)", ("how",))
 repair_subshard_reads = DEFAULT.counter(
     "cubefs_repair_subshard_reads_total",
     "beta-sized helper symbols served through read_subshard (one per "

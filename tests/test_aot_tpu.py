@@ -142,6 +142,9 @@ def test_pallas_crc_compiles_for_v5e(v5e_sharding):
     (2, 6, 8, 4),     # EC6P6: the fused program's narrowest rung
     (2, 6, 32, 22),   # EC6P6 at a full 4 MiB object's shard
     (2, 12, 16, 11),  # EC12P4's narrowest rung
+    (2, 18, 64, 18),  # EC16P20L2's local stripe at a 64 MiB object's shard
+    (2, 16, 64, 18),  # ... and its global fallback
+    (2, 8, 8, 4),     # EC6P10L2's local stripe, the fused program's narrowest
 ])
 def test_repair_step_programs_compile_for_v5e(v5e_sharding, rows, cols,
                                               stripes, tiles):
