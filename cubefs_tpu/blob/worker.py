@@ -137,6 +137,9 @@ class RepairWorker:
         # how the last step's array came, `reused` or `fresh`
         self._buffer: np.ndarray | None = None
         self._came = "fresh"
+        # what the process's heap does with freed pages, as the last
+        # lease found it: `kept` or `dynamic` (hostmem.keep_freed_heap)
+        self._heap = "dynamic"
 
     def ready(self, max_object_bytes: int, policies=None,
               blob_size: int | None = None) -> int:
@@ -150,12 +153,10 @@ class RepairWorker:
         LRC codemode, by its local stripe's ln columns too. Never
         implied by construction; returns the number of steps. MSR
         volumes are not listed: a sub-shard decode's programs are still
-        built by its first step. The host's heap keeps what a task frees
-        (`hostmem.keep_freed_heap`, process-wide): the next task's
-        survivors land in pages the last one touched."""
+        built by its first step. What the host's heap keeps is set by
+        the first lease (`run_once`), not here."""
         from .access import AccessConfig
 
-        hostmem.keep_freed_heap()
         cfg = AccessConfig()
         policies = cfg.policies if policies is None else policies
         blob_size = cfg.blob_size if blob_size is None else blob_size
@@ -194,11 +195,18 @@ class RepairWorker:
         unit of a volume, the volume's other pending unit repairs, which
         the scheduler leases with it. Each task is completed or failed
         alone; returns True if a lease was run. A worker that finds none
-        lets its step buffer go: an idle worker holds nothing."""
+        lets its step buffer go: an idle worker holds nothing. The first
+        lease fixes the host's heap for the process
+        (`hostmem.keep_freed_heap`): what a task frees stays mapped, and
+        the next task's survivors land in pages the last one touched. A
+        process whose worker never leases keeps glibc's own."""
         meta, _ = self.sched.call("acquire_task", {"worker_id": self.worker_id})
         if not meta.get("task"):
             self._buffer = None
             return False
+        self._heap = "kept" if hostmem.keep_freed_heap() else "dynamic"
+        if tracelib.enabled():
+            metrics.repair_leases.inc(heap=self._heap)
         tasks = [meta["task"], *meta.get("siblings", ())]
         errors = self.execute(tasks)
         for task in tasks:
@@ -258,6 +266,7 @@ class RepairWorker:
                     sp.set_tag("svc", "worker").set_tag("task",
                                                        sharing[0]["type"])
                     sp.set_tag("units", len(sharing))
+                    sp.set_tag("heap", self._heap)
                     errors = self._execute_traced(sharing, sp)
                     if errors:  # a unit's own failure does not raise
                         e = next(iter(errors.values()))

@@ -506,6 +506,14 @@ repair_steps_per_task = DEFAULT.histogram(
     "cubefs_repair_steps_per_task",
     "decode steps of one finished unit-repair task",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, 128))
+# a repair worker's leases (blob/worker.py:run_once), by what the host's
+# heap does with freed pages once the lease came: `kept` where the
+# allocator took hostmem.keep_freed_heap's thresholds, `dynamic` where it
+# did not (not glibc's); one a lease
+repair_leases = DEFAULT.counter(
+    "cubefs_repair_leases_total",
+    "leases a repair worker ran, by the host heap's policy for freed "
+    "pages (kept / dynamic)", ("heap",))
 # the unit repairs of one volume leased together are decoded from one
 # read of its survivors: `own` is the task whose lease made the read,
 # `shared` each further task decoded from it
