@@ -53,8 +53,8 @@ import numpy as np
 from ..ops import progcache, rs_kernel
 from ..utils import metrics
 from ..utils import trace as tracelib
-from .engine import (Engine, _dead_engines, _dispatch, _drilled_dead,
-                     engine_for, get_engine)
+from .engine import (STEP_WIDTH, Engine, _dead_engines, _dispatch,
+                     _drilled_dead, engine_for, get_engine)
 
 _log = logging.getLogger("cubefs.codec")
 
@@ -604,6 +604,9 @@ class BatchCodec:
             with self._lock:
                 self._seam_tick()
                 self._calls += 1
+            # the rows handed back stop at the widest submission: the
+            # engine need not bring the columns past it back
+            width = STEP_WIDTH.set(max(live))
             try:
                 out, served = self._engine_call(key, coeff, arr)
             except BaseException as e:  # fan the step's failure back
@@ -612,6 +615,7 @@ class BatchCodec:
                 drain.step_ran(self._clock)
                 return
             finally:
+                STEP_WIDTH.reset(width)
                 with self._lock:
                     self._seam_tick()
                     self._calls -= 1

@@ -31,6 +31,7 @@ next one down; ``CUBEFS_CODEC_DEAD`` declares legs lost for a drill.
 
 from __future__ import annotations
 
+import contextvars
 import logging
 import math
 import os
@@ -133,49 +134,95 @@ class ResultBuffers:
 RESULTS = ResultBuffers()
 
 
-def _splitter(shape: tuple):
+# Column blocks of a large result in flight to the host ahead of the
+# one being copied into its kept buffer (PERF.md section 6: the micro-runs
+# that chose column blocks through host memory).
+D2H_AHEAD = 4
+
+# Payload columns of the step an engine call serves, where its caller
+# knows them (the batcher sets it round a step, codec/batcher.py): a
+# result's columns past it are pad, and a large result's blocks that lie
+# wholly in the pad are never brought back — those columns of the host
+# array are left as they were. The engine methods keep their signature.
+STEP_WIDTH: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "cubefs_codec_step_width", default=None)
+
+
+def _splitter(shape: tuple, order: tuple):
     """(one jitted program that cuts a device result of `shape` into
-    slices along the stripe axis, their stripe bounds): each slice holds
-    at most half the mmap threshold, so the two in flight fit under it
-    and malloc serves each from the pages the last one gave back."""
-    per = max(1, hostmem.MALLOC_MMAP_MAX // 2 // math.prod(shape[1:]))
-    bounds = [(a, min(a + per, shape[0])) for a in range(0, shape[0], per)]
+    blocks of its last (column) axis, their column bounds). `order` is
+    the result's layout, major to minor: each block comes out with its
+    axes in that order, so the cut is a strided copy in the layout the
+    device already keeps and the block a plain one (the TPU keeps a
+    (B, R, S) uint8 result rows-major: a cut along its stripes would be
+    a relayout). Blocks are a power of two of columns, at most an eighth
+    of the width, so a step whose payload stops short of its width rung
+    leaves whole blocks behind (the ladder's rungs lie 9/7, 11/9 and
+    14/11 apart), and at most half the mmap threshold where the width
+    allows, so the blocks in flight come back into pages malloc already
+    has."""
+    lead, s = math.prod(shape[:-1]), shape[-1]
+    cols = 1 << max(0, (s // 8).bit_length() - 1)
+    half = hostmem.MALLOC_MMAP_MAX // 2
+    while cols > max(128, s // 16) and lead * cols > half:
+        cols //= 2
+    bounds = [(a, min(a + cols, s)) for a in range(0, s, cols)]
 
     def build():
         import jax
 
-        return jax.jit(lambda y: tuple(y[a:z] for a, z in bounds)), bounds
+        def split(y):
+            y = y.transpose(order)
+            return tuple(y[..., a:z] for a, z in bounds)
 
-    return progcache.SHARED.get_or_build("result_split", (shape, per), build)
+        return jax.jit(split), bounds
+
+    return progcache.SHARED.get_or_build("result_split", (shape, order, cols),
+                                         build)
 
 
-def _to_host(y) -> np.ndarray:
+def _to_host(y, width: int | None = None) -> np.ndarray:
     """Device result `y` as a host array. Over the mmap threshold it
-    lands in a kept buffer: its slices come back one ahead of the copy
-    into the buffer, each into pages malloc just had back."""
+    lands in a kept buffer: its column blocks move to host memory
+    D2H_AHEAD ahead of the copy into the buffer, and a block that starts
+    at or past `width` (the payload's columns, where the caller knows
+    them) stays on the device — the buffer keeps whatever those columns
+    held."""
     if y.nbytes <= hostmem.MALLOC_MMAP_MAX:
         return np.asarray(y)
-    buf, came = RESULTS.take(tuple(y.shape))
-    split, bounds = _splitter(tuple(y.shape))
-    pieces = list(split(y))
-    pieces[0].copy_to_host_async()
-    for k, (a, z) in enumerate(bounds):
-        if k + 1 < len(pieces):
-            pieces[k + 1].copy_to_host_async()
-        buf[a:z] = np.asarray(pieces[k])
-        pieces[k] = None  # its host copy goes back to malloc now
+    import jax
+
+    shape = tuple(y.shape)
+    order = tuple(y.format.layout.major_to_minor)
+    back = tuple(int(k) for k in np.argsort(order))
+    buf, came = RESULTS.take(shape)
+    split, bounds = _splitter(shape, order)
+    pieces = split(y)
+    live = [k for k, (a, _) in enumerate(bounds)
+            if width is None or a < width]
+    host = jax.sharding.SingleDeviceSharding(
+        next(iter(y.devices())), memory_kind="unpinned_host")
+    moving = [jax.device_put(pieces[k], host) for k in live[:D2H_AHEAD]]
+    for i, k in enumerate(live):
+        if i + D2H_AHEAD < len(live):
+            moving.append(jax.device_put(pieces[live[i + D2H_AHEAD]], host))
+        a, z = bounds[k]
+        buf[..., a:z] = np.asarray(moving[i]).transpose(back)
+        moving[i] = None  # its host copy goes back now
     if tracelib.enabled():
         metrics.codec_result_buffers.inc(result=came)
         span = tracelib.current()
         if span is not None:
             span.set_tag("result_buffer", came)
+            span.set_tag("result_blocks", f"{len(live)}/{len(bounds)}")
     return buf
 
 
-def device_call(eng, op: str, matrix, program, host_in: np.ndarray
-                ) -> np.ndarray:
+def device_call(eng, op: str, matrix, program, host_in: np.ndarray,
+                width: int | None = None) -> np.ndarray:
     """One call of device engine `eng`: ``program(matrix(), host_in)``,
-    host array in, host array out. ``matrix()`` hands over the step's
+    host array in, host array out (past `width` columns, what
+    `_to_host` leaves). ``matrix()`` hands over the step's
     bit matrix, device-resident (rs_kernel.device_bits: a cache lookup,
     or one bit expansion + one upload for a matrix not seen before).
     At most once in PHASE_EVERY_S the call is taken as its five steps:
@@ -188,7 +235,7 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
     every call with CUBEFS_TRACE=0, is the bare call."""
     now = time.perf_counter()
     if not tracelib.enabled() or now < getattr(eng, "_phase_due", 0.0):
-        return _to_host(program(matrix(), host_in))
+        return _to_host(program(matrix(), host_in), width)
     eng._phase_due = now + PHASE_EVERY_S
     import jax
 
@@ -206,7 +253,7 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray
     x = phase("h2d", lambda: jax.block_until_ready(jax.device_put(host_in)))
     y = phase("launch", program, w, x)
     phase("wait", jax.block_until_ready, y)
-    return phase("d2h", _to_host, y)
+    return phase("d2h", _to_host, y, width)
 
 
 def ready_decode(n: int, s: int) -> None:
@@ -245,22 +292,22 @@ class JaxEngine:
                ) -> np.ndarray:
         """One device call at the step's rung (rs_kernel.step_shape):
         programs exist for rung shapes alone. The batcher hands over
-        rung-shaped steps and they go up as they are; any other caller's
-        shards are copied into a zeroed rung-shaped array here and its
-        rows sliced back."""
+        rung-shaped steps and they go up as they are, their payload
+        width in STEP_WIDTH; any other caller's shards are copied into a
+        zeroed rung-shaped array here and its rows sliced back."""
         coeff = np.ascontiguousarray(coeff, dtype=np.uint8)
         shards = np.asarray(shards)
         lead, (c, s) = shards.shape[:-2], shards.shape[-2:]
         b = math.prod(lead)
         rung = rs_kernel.step_shape(c, b, s)
-        step = shards
+        step, width = shards, STEP_WIDTH.get()
         if shards.shape != (rung[0], c, rung[1]):
-            step = np.zeros((rung[0], c, rung[1]), dtype=np.uint8)
+            step, width = np.zeros((rung[0], c, rung[1]), dtype=np.uint8), s
             step[:b, :, :s] = shards.reshape(b, c, s)
         planes, program = rs_kernel.plan(coeff, step.shape)
         out = device_call(
             self, op, lambda: rs_kernel.device_bits(coeff, planes, op),
-            program, step)
+            program, step, width)
         if step is not shards:
             out = out[:b, :, :s].reshape(*lead, coeff.shape[0], s)
         return out

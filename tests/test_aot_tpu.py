@@ -191,3 +191,40 @@ def test_lrc_step_programs_compile_for_v5e(v5e_sharding, stripes, tiles):
     x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=v5e_sharding)
     m = jax.jit(program).lower(w, x).compile().memory_analysis()
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 4 * (1 << 30)
+
+
+@pytest.mark.parametrize("rows,cols,stripes,tiles", [
+    (22, 16, 8, 18),  # ingest-lrc: a 64 MiB EC16P20L2 PUT's one step
+    (2, 12, 64, 22),  # disk-repair: 64 bids' two rows at the 22-tile rung
+])
+def test_a_large_results_blocks_are_cut_without_a_relayout_on_v5e(
+        v5e_sharding, rows, cols, stripes, tiles):
+    """The result leaves the step's program in the layout the chip
+    keeps for its shape (rows-major for these), and the splitter that
+    cuts it into column blocks for the host (`engine._splitter`, fed
+    that layout) compiles to one fusion of strided copies: no relayout,
+    no copy of its own."""
+    import re
+
+    from jax.experimental.layout import Format
+
+    from cubefs_tpu.codec import engine
+
+    tile = pallas_gf.DEFAULT_TILE
+    shape = (stripes, cols, tiles * tile)
+    program = pallas_gf._apply_fn.__wrapped__(rows, cols, shape, tile, False)
+    w = jax.ShapeDtypeStruct((8 * rows, 8 * cols), jnp.int8,
+                             sharding=v5e_sharding)
+    x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=v5e_sharding)
+    out = jax.jit(program).lower(w, x).compile().output_formats
+    order = tuple(out.layout.major_to_minor)
+    assert order == (1, 0, 2)
+    result = (stripes, rows, tiles * tile)
+    split, bounds = engine._splitter(result, order)
+    y = jax.ShapeDtypeStruct(result, jnp.uint8,
+                             sharding=Format(out.layout, v5e_sharding))
+    text = split.lower(y).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r" fusion\(", entry)) == 1
+    assert not re.findall(r" copy\(", entry)
+    assert len(bounds) >= 8

@@ -7,6 +7,7 @@ Run on the device engine on the CPU, with the threshold lowered so the
 tests' sizes engage the path as a 104 MB LRC result does."""
 
 import gc
+import math
 import os
 import sys
 import threading
@@ -170,3 +171,71 @@ def test_the_kept_bytes_never_exceed_the_cap(monkeypatch):
     assert came == "fresh" and buf.nbytes > small.cap
     assert all(kept is not buf for kept in small._kept)
     assert kept_bytes(small) <= small.cap
+
+
+# ---------------- the way a large result comes back ----------------
+
+# EC16P20L2's PUT step and a repair's two-row step, at a rung of 18
+# blocks of 1024 columns under the fixture's threshold
+LRC, REPAIR = (8, 22, 9 * 2048), (64, 2, 9 * 2048)
+
+
+def _on_device(shape, order, seed):
+    """(a device array of `shape` held in the layout `order`, major to
+    minor — the TPU keeps a (B, R, S) result rows-major, (1, 0, 2) —,
+    the host bytes it holds)."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    host = np.random.default_rng(seed).integers(0, 256, shape,
+                                                dtype=np.uint8)
+    where = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    return jax.device_put(host, Format(Layout(major_to_minor=order),
+                                       where)), host
+
+
+@pytest.mark.parametrize("cut", [None, 1.0, 8 / 9, 0.51, 0.5, 0.0],
+                         ids=["no_width", "rung", "eight_ninths",
+                              "inside_a_block", "half", "one_column"])
+@pytest.mark.parametrize("order", [(1, 0, 2), (0, 1, 2)],
+                         ids=["rows_major", "stripes_major"])
+@pytest.mark.parametrize("shape", [LRC, REPAIR], ids=["lrc", "repair"])
+def test_a_large_result_is_asarray_of_it_up_to_its_width_in_a_kept_buffer(
+        results, shape, order, cut):
+    """Up to the block that holds its last payload column a result over
+    the threshold comes back bit-identical to np.asarray of the whole
+    result; the blocks past it never cross, so the kept buffer, handed
+    out again, still holds the last call's bytes there."""
+    width = None if cut is None else max(1, round(cut * shape[-1]))
+    first, first_host = _on_device(shape, order, 1)
+    out = engine._to_host(first)
+    assert np.array_equal(out, np.asarray(first))
+    buf = id(out)
+    del out
+    gc.collect()
+    reused, fresh = counts()
+    y, host = _on_device(shape, order, 2)
+    got = engine._to_host(y, width)
+    assert id(got) == buf and counts() == (reused + 1, fresh)
+    _, bounds = engine._splitter(shape, order)
+    end = shape[-1] if width is None else next(
+        z for _, z in bounds if z >= width)
+    assert np.array_equal(got[..., :end], np.asarray(y)[..., :end])
+    assert np.array_equal(got[..., end:], first_host[..., end:])
+    assert width is None or end - width < bounds[0][1]
+
+
+@pytest.mark.parametrize("shape,width,came", [
+    ((8, 22, 589824), 524288, 8), ((64, 2, 589824), 524288, 8),
+    ((64, 2, 720896), 699051, 11), ((8, 4, 1441792), 1398102, 11)],
+    ids=["ingest_lrc", "lrc_disk_repair", "disk_repair", "rs_encode"])
+def test_a_real_steps_blocks_leave_its_pad_behind_under_half_the_threshold(
+        shape, width, came):
+    """At the real threshold a cell's step is cut into blocks of equal
+    width, none over half the threshold, at least eight; the blocks that
+    carry its payload are `came` of them."""
+    _, bounds = engine._splitter(shape, (1, 0, 2))
+    cols = {z - a for a, z in bounds}
+    assert len(cols) == 1 and len(bounds) >= 8
+    assert math.prod(shape[:-1]) * cols.pop() <= hostmem.MALLOC_MMAP_MAX // 2
+    assert sum(a < width for a, _ in bounds) == came
