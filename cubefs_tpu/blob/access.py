@@ -14,6 +14,7 @@ device sees large contiguous arrays.
 from __future__ import annotations
 
 import contextvars
+import math
 import os
 import time
 import uuid
@@ -43,12 +44,6 @@ DEFAULT_POLICIES = [
     cm.Policy("EC6P6", min_size=(256 << 10) + 1, max_size=4 << 20),
     cm.Policy("EC12P4", min_size=(4 << 20) + 1, max_size=1 << 62),
 ]
-
-
-# A PUT's data rows above malloc's mmap threshold (utils/hostmem.py) are
-# kept for the next PUT of the same shape, up to STRIPE_ROWS_KEPT_BYTES
-# (then the oldest is freed).
-STRIPE_ROWS_KEPT_BYTES = 512 << 20
 
 
 @dataclass
@@ -89,8 +84,6 @@ class AccessHandler:
         self.delete_queue = delete_queue
         self._pool = ThreadPoolExecutor(max_workers=self.cfg.max_workers)
         self._encoders: dict[int, object] = {}
-        # data-row arrays of ended PUTs, oldest first (_take_stripe_rows)
-        self._free_rows: list[np.ndarray] = []
         self._lock = lockwitness.make_lock("AccessHandler._lock")
 
     def _submit(self, fn, *args):
@@ -240,14 +233,11 @@ class AccessHandler:
                         fails.append((bid, idx))
                 self._observe_pool_waits("put_shard", waits)
         except BaseException:
-            # no way out leaves a write of this PUT running; the step
-            # may still read `rows` (a wait that timed out), so the
-            # array is dropped and not kept
+            # no way out leaves a write of this PUT running (a step
+            # whose wait timed out may still read `rows`: what holds
+            # them keeps them from the next PUT, hostmem.KeptArrays)
             wait(futures)
             raise
-        # the step and every shard write of this PUT have ended: nothing
-        # but this thread can read `rows` now
-        self._return_stripe_rows(rows)
         for bid, n_ok in ok_per_bid.items():
             if n_ok < quorum:
                 if self.proxy is not None:
@@ -297,30 +287,16 @@ class AccessHandler:
         return steps
 
     def _take_stripe_rows(self, shape: tuple) -> np.ndarray:
-        """An uninitialised uint8 array of `shape`: the newest of that
-        shape on the free list, else a new one. Arrays malloc serves
-        from its own heap are warm already and never enter the list."""
-        rows = None
-        if shape[0] * shape[1] * shape[2] > hostmem.MALLOC_MMAP_MAX:
-            with self._lock:
-                free = self._free_rows
-                for k in range(len(free) - 1, -1, -1):
-                    if free[k].shape == shape:
-                        rows = free.pop(k)
-                        break
+        """An uninitialised uint8 array of `shape`: over malloc's mmap
+        threshold one the process keeps (`hostmem.KEPT`), else a new
+        one — malloc serves those from its own heap, warm already."""
+        if math.prod(shape) > hostmem.MALLOC_MMAP_MAX:
+            rows, came = hostmem.KEPT.take(shape)
+        else:
+            rows, came = np.empty(shape, dtype=np.uint8), "fresh"
         if tracelib.current() is not None:
-            metrics.access_stripe_buffers.inc(
-                result="fresh" if rows is None else "reused")
-        return rows if rows is not None else np.empty(shape, dtype=np.uint8)
-
-    def _return_stripe_rows(self, rows: np.ndarray) -> None:
-        if rows.nbytes <= hostmem.MALLOC_MMAP_MAX:
-            return
-        with self._lock:
-            free = self._free_rows
-            free.append(rows)
-            while sum(r.nbytes for r in free) > STRIPE_ROWS_KEPT_BYTES:
-                del free[0]
+            metrics.access_stripe_buffers.inc(result=came)
+        return rows
 
     def _write_shard(self, vol: VolumeInfo, unit, bid: int, shard: np.ndarray):
         addr = unit.node_addr

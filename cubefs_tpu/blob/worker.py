@@ -15,9 +15,9 @@ before it is checked and written back: no stored shard carries pad.
 The scheduler leases a volume's pending unit repairs together, and those
 of a plain Reed-Solomon volume are decoded from ONE read of its
 survivors (`units_per_read`): each step array is filled once, and one
-decode step a lost unit runs over it. The step arrays of a backlog are
-views of one buffer the worker keeps from its first step until it finds
-no task to lease (`_step_array`): pages touched once, not once a step.
+decode step a lost unit runs over it. A step array is a view of a buffer
+the process keeps (`_step_array`, `utils/hostmem.KEPT`): pages touched
+once, not once a step.
 A lost unit of an LRC volume is rebuilt from its AZ's local stripe
 (upstream's recoverByLocalStripe), the global stripe the fallback; a
 local stripe of one local parity leaves no survivor to check with, so
@@ -28,7 +28,6 @@ code from the same reads, and the two must agree before the write-back.
 from __future__ import annotations
 
 import dataclasses
-import math
 import os
 import threading
 import time
@@ -131,11 +130,7 @@ class RepairWorker:
         self._thread: threading.Thread | None = None
         self.completed = 0
         self.failed = 0
-        # what every step array of a backlog is a view of (_step_array):
-        # the worker's alone, run by one thread (the loop's or the
-        # caller's of run_once), None while it has nothing to do — and
         # how the last step's array came, `reused` or `fresh`
-        self._buffer: np.ndarray | None = None
         self._came = "fresh"
         # what the process's heap does with freed pages, as the last
         # lease found it: `kept` or `dynamic` (hostmem.keep_freed_heap)
@@ -182,7 +177,6 @@ class RepairWorker:
         def loop():
             while not self._stop.wait(0 if self.run_once() else idle_wait):
                 pass
-            self._buffer = None  # stopped mid-backlog: as when idle
 
         self._thread = threading.Thread(target=loop, daemon=True)
         self._thread.start()
@@ -194,15 +188,13 @@ class RepairWorker:
         """Acquire and execute one lease: a task and, where it repairs a
         unit of a volume, the volume's other pending unit repairs, which
         the scheduler leases with it. Each task is completed or failed
-        alone; returns True if a lease was run. A worker that finds none
-        lets its step buffer go: an idle worker holds nothing. The first
-        lease fixes the host's heap for the process
-        (`hostmem.keep_freed_heap`): what a task frees stays mapped, and
-        the next task's survivors land in pages the last one touched. A
-        process whose worker never leases keeps glibc's own."""
+        alone; returns True if a lease was run. The first lease fixes
+        the host's heap for the process (`hostmem.keep_freed_heap`):
+        what a task frees stays mapped, and the next task's survivors
+        land in pages the last one touched. A process whose worker never
+        leases keeps glibc's own."""
         meta, _ = self.sched.call("acquire_task", {"worker_id": self.worker_id})
         if not meta.get("task"):
-            self._buffer = None
             return False
         self._heap = "kept" if hostmem.keep_freed_heap() else "dynamic"
         if tracelib.enabled():
@@ -465,30 +457,20 @@ class RepairWorker:
 
     def _step_array(self, shape: tuple) -> np.ndarray:
         """The array of one decode step, to be filled and zeroed by its
-        caller: a C-contiguous view of the first bytes of the one flat
-        buffer the worker owns, so what it holds is the last steps'
-        survivors, this volume's or another's. The buffer is made by the
-        first step of a backlog, replaced — the old one let go first,
-        two are never alive — by a step that does not fit, kept across
-        steps, units and tasks, and let go by the `run_once` that finds
-        no task. Its caller must be done with the last view by then:
-        one step's array at a time."""
-        size = math.prod(shape)
-        self._came = "reused"
-        if self._buffer is None or self._buffer.size < size:
-            self._buffer = None  # let go first
-            self._buffer = np.empty(size, dtype=np.uint8)
-            self._came = "fresh"  # pages never touched
-        return self._buffer[:size].reshape(shape)
+        caller: a C-contiguous view of a buffer the process keeps
+        (`hostmem.KEPT`), so what it holds is an earlier step's
+        survivors, an earlier PUT's rows or a result."""
+        batch, self._came = hostmem.KEPT.take(shape)
+        return batch
 
     def _decode_groups(self, t, by_key, n_solve, total_code,
                        units: list[_Unit], exact, stripe=None) -> None:
         """One step array per group and `batch_stripes` bids, and over
         it one device step a lost unit. A unit whose check fails keeps
         the error and takes no further step; the others go on. The
-        array is a view of the worker's buffer (`_step_array`), this
-        loop's from `_stack` until every unit's step over it has
-        returned its rows to the host; nothing below keeps it."""
+        array (`_step_array`) is this loop's from `_stack` until every
+        unit's step over it has returned its rows to the host; nothing
+        below keeps it."""
         for (wide, subs), group in by_key.items():
             plans = [(unit, *self._repair_rows(t, subs, n_solve, total_code,
                                                unit.sub, stripe))
@@ -516,8 +498,7 @@ class RepairWorker:
                         except Exception as e:
                             unit.error = e
                     # the view goes before the next step asks for its
-                    # array: one that does not fit replaces the buffer,
-                    # and two such arrays must never be alive together
+                    # array: while it lives its buffer is not handed out
                     del batch
 
     def _apply(self, t, rows, batch, sizes, exact) -> np.ndarray:
@@ -561,8 +542,8 @@ class RepairWorker:
         bid at its own size and zeros past it, in a shape `ready` built
         a program for — `rs_kernel.repair_step_shape`: the group's width
         rung, zero stripes up to a stripe rung — which the batcher
-        passes whole. The array comes holding the last steps' survivors
-        (`_step_array`), so every byte of it is written here, a
+        passes whole. The array comes holding whatever its buffer last
+        held (`_step_array`), so every byte of it is written here, a
         survivor's or a zero: that is the guarantee which keeps one
         task's shards out of the next task's step, not a habit."""
         if exact:

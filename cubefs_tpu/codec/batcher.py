@@ -109,6 +109,7 @@ class CodecFuture:
     def resolve(self, value, exc: BaseException | None) -> None:
         self.value = value
         self.exc = exc
+        self.arr = None  # nothing reads the rows after this
         # write order matters (Dekker with result()): done first, then
         # read the event slot — the GIL makes each step atomic and
         # sequentially consistent, so either the collector sees done or
@@ -485,13 +486,14 @@ class BatchCodec:
                         return
                     q.subs = []
                 total = sum(s.stripes for s in batch)
+                n = len(batch)
                 try:
                     self._run_steps(key, q.coeff, batch, total, drain)
                 finally:
                     with self._lock:
                         self._pending -= total
                         self._seam_tick()
-                        self._open -= len(batch)
+                        self._open -= n
                         self._cond.notify_all()
         except BaseException as e:
             # a dying drainer (MemoryError, interrupt) must not strand
@@ -533,10 +535,16 @@ class BatchCodec:
         if stripe_cap is None:
             stripe_cap = self._caps[geometry] = rs_kernel.batch_cap(
                 *geometry, self.max_step_bytes, self.max_batch)
+        step: list[CodecFuture] = []
         try:
-            step: list[CodecFuture] = []
             stripes = 0
-            for sub in batch:
+            # taken off the list as it goes: a future whose step has run
+            # is its collector's alone, so the kept host buffer its
+            # result views (utils/hostmem) is free once the collector is
+            # done with it, not once this swap's last step has run
+            batch.reverse()
+            while batch:
+                sub = batch[-1]
                 # drain-time validation: key geometry comes from the
                 # shape, so the remaining per-submission failure is
                 # dtype — reject it alone (concatenate would silently
@@ -546,16 +554,18 @@ class BatchCodec:
                     sub.resolve(None, CodecAdmissionError(
                         f"{op}: stripe dtype must be uint8, got "
                         f"{sub.arr.dtype}"))
+                    batch.pop()
                     continue
                 if step and stripes + sub.stripes > stripe_cap:
                     self._one_step(key, coeff, step, drain)
                     step, stripes = [], 0
-                step.append(sub)
+                step.append(batch.pop())
                 stripes += sub.stripes
             if step:
                 self._one_step(key, coeff, step, drain)
         finally:
-            for sub in batch:  # belt-and-braces: nobody waits forever
+            # belt-and-braces: nobody waits forever
+            for sub in step + batch:
                 if not sub.done:
                     sub.resolve(None, CodecAdmissionError(
                         f"{op}: drain failed before this submission"))
@@ -636,6 +646,7 @@ class BatchCodec:
         off = 0
         for sub in step:  # resolve inlined: this is the hottest loop
             sub.value = out[off:off + len(sub.widths), :, :sub.width]
+            sub.arr = None  # read: the rows' kept buffer is the caller's
             sub.done = True  # write order: done before the event read
             ev = sub.event
             if ev is not None:

@@ -35,8 +35,6 @@ import contextvars
 import logging
 import math
 import os
-import sys
-import threading
 import time
 from typing import Protocol
 
@@ -94,46 +92,6 @@ _PHASE_SPANS = {p: f"{tracelib.PROFILE_PREFIX}codec.{p}"
 # small PUTs.
 PHASE_EVERY_S = 0.25
 
-# Result buffers the engine keeps, at most this many bytes of them (the
-# front door keeps its data rows to the same cap).
-RESULT_BUFFERS_KEPT_BYTES = 512 << 20
-
-
-class ResultBuffers:
-    """Host arrays a device result over malloc's mmap threshold lands in
-    (utils/hostmem.py): np.asarray would put it in a fresh mapping every
-    step, each page a fault at first touch. A buffer is handed out only
-    when nothing holds it — a caller's view, a future's slice or a PUT's
-    parity rows all keep a reference to it — and a shape whose buffers
-    are all held gets a new one, kept while the cap allows (the least
-    recently handed out go first)."""
-
-    def __init__(self, cap: int = RESULT_BUFFERS_KEPT_BYTES):
-        self.cap = cap
-        self._kept: list[np.ndarray] = []  # least recently handed out first
-        self._lock = threading.Lock()
-
-    def take(self, shape: tuple) -> tuple[np.ndarray, str]:
-        """(a uint8 array of `shape`, "reused" or "fresh")."""
-        with self._lock:  # two geometry queues' calls overlap
-            kept = self._kept
-            for k in range(len(kept)):
-                # 2: the list's reference and getrefcount's argument
-                if kept[k].shape == shape and sys.getrefcount(kept[k]) == 2:
-                    buf = kept.pop(k)
-                    kept.append(buf)
-                    return buf, "reused"
-            buf = np.empty(shape, dtype=np.uint8)
-            if buf.nbytes <= self.cap:
-                kept.append(buf)
-                while sum(b.nbytes for b in kept) > self.cap:
-                    del kept[0]
-            return buf, "fresh"
-
-
-RESULTS = ResultBuffers()
-
-
 # Column blocks of a large result in flight to the host ahead of the
 # one being copied into its kept buffer (PERF.md section 6: the micro-runs
 # that chose column blocks through host memory).
@@ -183,11 +141,11 @@ def _splitter(shape: tuple, order: tuple):
 
 def _to_host(y, width: int | None = None) -> np.ndarray:
     """Device result `y` as a host array. Over the mmap threshold it
-    lands in a kept buffer: its column blocks move to host memory
-    D2H_AHEAD ahead of the copy into the buffer, and a block that starts
-    at or past `width` (the payload's columns, where the caller knows
-    them) stays on the device — the buffer keeps whatever those columns
-    held."""
+    lands in an array the process keeps (`hostmem.KEPT`): its column
+    blocks move to host memory D2H_AHEAD ahead of the copy into the
+    array, and a block that starts at or past `width` (the payload's
+    columns, where the caller knows them) stays on the device — the
+    array keeps whatever those columns held."""
     if y.nbytes <= hostmem.MALLOC_MMAP_MAX:
         return np.asarray(y)
     import jax
@@ -195,7 +153,7 @@ def _to_host(y, width: int | None = None) -> np.ndarray:
     shape = tuple(y.shape)
     order = tuple(y.format.layout.major_to_minor)
     back = tuple(int(k) for k in np.argsort(order))
-    buf, came = RESULTS.take(shape)
+    buf, came = hostmem.KEPT.take(shape)
     split, bounds = _splitter(shape, order)
     pieces = split(y)
     live = [k for k, (a, _) in enumerate(bounds)

@@ -336,8 +336,9 @@ reconstruct_reads = DEFAULT.counter(
     "cubefs_reconstruct_total",
     "degraded-read reconstructions by stripe scope (local = intra-AZ "
     "LRC stripe, global = full-width RS)", ("path",))
-# a PUT's data rows (blob/access.py): `reused` came from the handler's
-# free list (mapped pages), `fresh` from the allocator; one a PUT
+# a PUT's data rows (blob/access.py): `reused` came from the process's
+# kept arrays (utils/hostmem.KEPT: mapped pages), `fresh` from the
+# allocator; one a PUT
 access_stripe_buffers = DEFAULT.counter(
     "cubefs_access_stripe_buffers_total",
     "data-row arrays PUTs filled, by where the array came from "
@@ -372,9 +373,9 @@ codec_step_bytes = DEFAULT.counter(
     "input bytes of drained device steps (payload / pad)",
     ("op", "kind"))
 # a device result over malloc's mmap threshold (codec/engine.py:
-# _to_host): `reused` lands in a host buffer the engine kept from an
-# earlier step of its shape (pages touched before), `fresh` in a new
-# one; one a device call over the threshold, none under it
+# _to_host): `reused` lands in a buffer the process kept
+# (utils/hostmem.KEPT: pages touched before), `fresh` in a new one; one
+# a device call over the threshold, none under it
 codec_result_buffers = DEFAULT.counter(
     "cubefs_codec_result_buffers_total",
     "device results over malloc's mmap threshold, by the host buffer "
@@ -522,9 +523,8 @@ repair_task_reads = DEFAULT.counter(
     "finished unit-repair tasks, by whose read of the survivors they "
     "were decoded from (own / shared)", ("reads",))
 # a decode step's array (blob/worker.py:_step_array): `reused` is a view
-# of the buffer the worker keeps while its backlog lasts (pages touched
-# before), `fresh` a new buffer — a backlog's first step, or one larger
-# than any before it; one a step
+# of a buffer the process kept (utils/hostmem.KEPT: pages touched
+# before), `fresh` a new buffer; one a step
 repair_step_arrays = DEFAULT.counter(
     "cubefs_repair_step_arrays_total",
     "arrays decode steps of repairs filled, by where the array came "

@@ -60,6 +60,18 @@ def _qos_burn_isolated():
     qos.DEFAULT._last_refresh = float("-inf")
 
 
+@pytest.fixture(autouse=True)
+def kept(monkeypatch):
+    """The process's kept arrays (`hostmem.KEPT`), empty for each test:
+    what one test's PUTs, results and repair steps leave there is
+    neither memory the next test holds nor a buffer it is handed."""
+    from cubefs_tpu.utils import hostmem
+
+    pool = hostmem.KeptArrays()
+    monkeypatch.setattr(hostmem, "KEPT", pool)
+    return pool
+
+
 def pytest_sessionfinish(session, exitstatus):
     """When the run executed under CUBEFS_SANITIZE=1, persist the lock
     witness's evidence (order graph edges, acquisition counters, RPC

@@ -1,10 +1,10 @@
 """A device step's result over malloc's mmap threshold lands in a host
-buffer the engine keeps (`engine.ResultBuffers`), not in a fresh mapping
-whose every page faults at first touch. What such a result holds must be
-byte for byte what the table engine computes; a buffer is handed out
-again only when nothing holds it; the kept bytes stay under the cap.
-Run on the device engine on the CPU, with the threshold lowered so the
-tests' sizes engage the path as a 104 MB LRC result does."""
+buffer the process keeps (`hostmem.KEPT`), not in a fresh mapping whose
+every page faults at first touch. What such a result holds must be byte
+for byte what the table engine computes, and a buffer is handed out
+again only when nothing holds it (the cap: `test_hostmem.py`). Run on
+the device engine on the CPU, with the threshold lowered so the tests'
+sizes engage the path as a 104 MB LRC result does."""
 
 import gc
 import math
@@ -24,12 +24,10 @@ S = 4096  # a shard; the step runs at the one-tile rung
 
 
 @pytest.fixture
-def results(monkeypatch):
-    """The engine's buffers, fresh for the test; the threshold at 64 KiB,
-    under a (4, 4, 32768) result."""
+def results(kept, monkeypatch):
+    """The process's kept arrays, empty for the test; the threshold at
+    64 KiB, under a (4, 4, 32768) result."""
     monkeypatch.setattr(hostmem, "MALLOC_MMAP_MAX", 64 << 10)
-    kept = engine.ResultBuffers()
-    monkeypatch.setattr(engine, "RESULTS", kept)
     return kept
 
 
@@ -37,10 +35,6 @@ def counts() -> tuple[float, float]:
     """(reused, fresh) of `cubefs_codec_result_buffers_total` so far."""
     return (metrics.codec_result_buffers.value(result="reused"),
             metrics.codec_result_buffers.value(result="fresh"))
-
-
-def kept_bytes(kept: engine.ResultBuffers) -> int:
-    return sum(buf.nbytes for buf in kept._kept)
 
 
 def _encode(rng, b):
@@ -157,22 +151,6 @@ def test_threads_calling_at_once_never_share_a_buffer(results):
     assert reused > 0  # the buffers went round
 
 
-def test_the_kept_bytes_never_exceed_the_cap(monkeypatch):
-    monkeypatch.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
-    small = engine.ResultBuffers(cap=3 * (4 * 4 * 32768))
-    monkeypatch.setattr(engine, "RESULTS", small)
-    tpu = engine.get_engine("tpu")
-    outs = []  # every result held: each call takes a buffer of its own
-    for b in (1, 2, 4, 4, 4, 8, 2):
-        outs.append(tpu.encode_parity(np.zeros((b, 12, S), np.uint8), 4))
-        assert kept_bytes(small) <= small.cap
-    # a buffer larger than the cap is handed out and never kept
-    buf, came = small.take((16, 4, 32768))
-    assert came == "fresh" and buf.nbytes > small.cap
-    assert all(kept is not buf for kept in small._kept)
-    assert kept_bytes(small) <= small.cap
-
-
 # ---------------- the way a large result comes back ----------------
 
 # EC16P20L2's PUT step and a repair's two-row step, at a rung of 18
@@ -210,13 +188,13 @@ def test_a_large_result_is_asarray_of_it_up_to_its_width_in_a_kept_buffer(
     first, first_host = _on_device(shape, order, 1)
     out = engine._to_host(first)
     assert np.array_equal(out, np.asarray(first))
-    buf = id(out)
+    buf = id(out.base)
     del out
     gc.collect()
     reused, fresh = counts()
     y, host = _on_device(shape, order, 2)
     got = engine._to_host(y, width)
-    assert id(got) == buf and counts() == (reused + 1, fresh)
+    assert id(got.base) == buf and counts() == (reused + 1, fresh)
     _, bounds = engine._splitter(shape, order)
     end = shape[-1] if width is None else next(
         z for _, z in bounds if z >= width)

@@ -21,9 +21,10 @@ from cubefs_tpu.blob.access import PutQuorumError
 from cubefs_tpu.codec import batcher
 from cubefs_tpu.codec import codemode as cmode
 from cubefs_tpu.utils import metrics, rpc
-# `cluster`: 16 units, and the free list engages at the tests' sizes
+# `cluster`: 16 units, and the kept arrays serve the tests' sizes
 from test_put_stripe_rows import (BLOB, assert_stored_equals_reference,
-                                  cluster)  # noqa: F401 (a fixture)
+                                  cluster,  # noqa: F401 (a fixture)
+                                  holders, rows_taken)
 
 WAIT_S = 30.0
 RS = [cmode.CodeMode.EC3P3, cmode.CodeMode.EC6P6, cmode.CodeMode.EC12P4]
@@ -166,7 +167,7 @@ def test_forked_put_stores_the_sequential_reference(cluster, rng, mode, size):
 
 
 def test_failed_encode_raises_after_the_started_writes_and_keeps_no_rows(
-        cluster, rng, monkeypatch):
+        cluster, kept, rng, monkeypatch):
     acc, mode = cluster.access, cmode.CodeMode.EC6P6
     t = cmode.tactic(mode)
     gate = Gate(monkeypatch, fail=RuntimeError("step fell over"))
@@ -187,6 +188,7 @@ def test_failed_encode_raises_after_the_started_writes_and_keeps_no_rows(
 
     subs.write = slow_write
     monkeypatch.setattr(acc, "_write_shard", slow_write)
+    taken = rows_taken(acc, monkeypatch)
     data = rng.integers(0, 256, BLOB + 5, dtype=np.uint8).tobytes()
     th, out = put_in_thread(acc, data, mode, at_end=lambda: running[0])
     try:
@@ -198,21 +200,24 @@ def test_failed_encode_raises_after_the_started_writes_and_keeps_no_rows(
     assert isinstance(out.get("exc"), RuntimeError), out
     assert "step fell over" in str(out["exc"]) and "loc" not in out
     # the PUT raised only after every write it had started ended, it
-    # started no parity write, and its array is not kept: the step that
-    # failed may not have let go of it
+    # started no parity write, and its array goes to no other PUT while
+    # what saw the failure (its error's frames) may still hold it
     started = subs.writes()
     assert len(started) == 2 * t.n
     assert all(f.done() for f in started) and out["at_end"] == 0
     assert subs.writes(lambda u: u.index >= t.n) == []
-    assert acc._free_rows == []
+    assert holders(kept, taken[0][0]) > 0
+    with pytest.raises(RuntimeError, match="step fell over"):
+        acc.put(data, codemode=mode)  # the gate is open: fails at once
+    assert taken[1] != taken[0]
 
 
 def test_quorum_counts_data_and_parity_writes_together(
-        cluster, rng, monkeypatch):
+        cluster, kept, rng, monkeypatch):
     """A refused data write and a refused parity write, on distinct
     units: the quorum is counted over both groups, both shards of every
     bid are queued for repair, and one failure too many fails the PUT —
-    after every write has ended, so its array is kept."""
+    after every write has ended, so the next PUT is handed its array."""
     acc, mode = cluster.access, cmode.CodeMode.EC6P6
     t = cmode.tactic(mode)
     assert (t.n, t.total, t.put_quorum) == (6, 12, 11)
@@ -224,12 +229,13 @@ def test_quorum_counts_data_and_parity_writes_together(
         return write(vol, unit, bid, shard)
 
     monkeypatch.setattr(acc, "_write_shard", write_or_refuse)
+    taken = rows_taken(acc, monkeypatch)
     data = rng.integers(0, 256, BLOB + 77, dtype=np.uint8).tobytes()
     # the codemode's own quorum, 11 of 12: one of each group is 10
     with pytest.raises(PutQuorumError, match="10/12"):
         acc.put(data, codemode=mode)
     assert cluster.repair_q.poll() == []
-    assert len(acc._free_rows) == 1
+    assert holders(kept, taken[0][0]) == 0
     # either of the two alone is inside it: neither group is exempt
     for alone in (2, 9):
         refused.clear()

@@ -1,22 +1,24 @@
 """A PUT's data rows (PR 29): one (blobs, n, S) array filled once from
-the payload, taken by the codec step as it is, kept for the next PUT of
-the same shape. What reaches each blobnode must be byte for byte the
-reference stripe's shard — also from an array that last held another
-PUT's bytes — and an array goes back to the free list only when no step
-and no shard write of its PUT can still read it."""
+the payload, taken by the codec step as it is and, over malloc's mmap
+threshold, a view of a buffer the process keeps (`hostmem.KEPT`). What
+reaches each blobnode must be byte for byte the reference stripe's
+shard — also from a buffer that last held 0xFF everywhere — and a
+buffer is handed to another PUT only when no step and no shard write of
+its PUT can still read it."""
 
+import gc
 import sys
 import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
 
 from cellbench import reference
-from cubefs_tpu.blob import access as access_mod
 from cubefs_tpu.blob.access import PutQuorumError
 from cubefs_tpu.codec import codemode as cmode
-from cubefs_tpu.ops import msr, rs_kernel
+from cubefs_tpu.ops import msr
 from cubefs_tpu.utils import hostmem, metrics
 from test_blob_e2e import Cluster
 
@@ -25,12 +27,52 @@ BLOB = 64 << 10  # the test cluster's blob size
 
 @pytest.fixture
 def cluster(tmp_path, monkeypatch):
-    # every size is "above the allocator's own reuse": the free list
-    # engages at the tests' sizes as it does for 64 MiB objects
+    # every size is "above the allocator's own reuse": the kept arrays
+    # serve the tests' sizes as they serve 64 MiB objects
     monkeypatch.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
     c = Cluster(tmp_path, n_nodes=4, disks_per_node=4)  # 16 units: EC12P4
     c.cm.allow_colocated_units = True
     return c
+
+
+def scribble(kept) -> None:
+    """0xFF into every kept buffer nothing holds (once the garbage that
+    may hold one is collected)."""
+    gc.collect()
+    with kept._lock:
+        for k in range(len(kept._kept)):
+            if sys.getrefcount(kept._kept[k]) == 2:
+                kept._kept[k].fill(0xFF)
+
+
+def holders(kept, address: int) -> int:
+    """How many references but the list's hold the kept buffer at
+    `address`, once the garbage is collected."""
+    gc.collect()
+    with kept._lock:
+        for k in range(len(kept._kept)):
+            if kept._kept[k].ctypes.data == address:
+                return sys.getrefcount(kept._kept[k]) - 2
+    raise AssertionError(f"no kept buffer at {address:#x}")
+
+
+def stripe_buffers() -> tuple[float, float]:
+    """(reused, fresh) of `cubefs_access_stripe_buffers_total` so far."""
+    return (metrics.access_stripe_buffers.value(result="reused"),
+            metrics.access_stripe_buffers.value(result="fresh"))
+
+
+def rows_taken(acc, monkeypatch) -> list[tuple[int, int]]:
+    """(address, bytes) of the data rows of every PUT from now on."""
+    taken, take = [], acc._take_stripe_rows
+
+    def taking(shape):
+        rows = take(shape)
+        taken.append((rows.ctypes.data, rows.nbytes))
+        return rows
+
+    monkeypatch.setattr(acc, "_take_stripe_rows", taking)
+    return taken
 
 
 def reference_stripe(blob: bytes, t: cmode.Tactic) -> np.ndarray:
@@ -89,13 +131,17 @@ SIZES = {"exact_multiple": 3 * BLOB, "short_last_blob": 2 * BLOB + 12_345,
 
 @pytest.mark.parametrize("size", SIZES.values(), ids=SIZES.keys())
 @pytest.mark.parametrize("mode", MODES, ids=[m.name for m in MODES])
-def test_stored_shards_equal_the_reference_stripe(cluster, rng, mode, size):
+def test_stored_shards_equal_the_reference_stripe(cluster, kept, rng, mode,
+                                                 size):
     """Through an array that last held 0xFF everywhere: the PUT before
-    has the same stripe shape, so its array is the one reused."""
+    has the same stripe shape, so a kept buffer fits, and every kept
+    buffer is scribbled over."""
     cluster.access.put(b"\xff" * size, codemode=mode)
-    assert len(cluster.access._free_rows) == 1
+    scribble(kept)
+    reused = stripe_buffers()[0]
     data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
     loc = cluster.access.put(data, codemode=mode)
+    assert stripe_buffers()[0] == reused + 1
     assert loc.crc == reference.crc32(data)
     assert_stored_equals_reference(cluster, loc, data)
     assert cluster.access.get(loc) == data
@@ -103,16 +149,16 @@ def test_stored_shards_equal_the_reference_stripe(cluster, rng, mode, size):
 
 @pytest.mark.parametrize("mode", [cmode.CodeMode.EC6P3, cmode.CodeMode.EC12P4],
                          ids=["EC6P3", "EC12P4"])
-def test_reused_rows_leak_nothing(cluster, mode):
+def test_reused_rows_leak_nothing(cluster, kept, mode):
     """0xFF everywhere, then a shorter payload of the same stripe
     shape: every pad byte stored is 0, the payload comes back."""
     t = cmode.tactic(mode)
     cluster.access.put(b"\xff" * (2 * BLOB), codemode=mode)
-    rows = cluster.access._free_rows[-1]
-    rows[:] = 0xFF  # the pad bytes too
+    scribble(kept)  # the pad bytes too
+    reused = stripe_buffers()[0]
     short = b"\xaa" * (BLOB + 100)  # two blobs again, the second short
     loc = cluster.access.put(short, codemode=mode)
-    assert cluster.access._free_rows[-1] is rows  # it was the one reused
+    assert stripe_buffers()[0] == reused + 1  # a kept buffer, 0xFF
     shards = stored_shards(cluster, loc)
     for k, blob_len in enumerate([BLOB, 100]):
         stored = b"".join(shards[k][:t.n])
@@ -129,44 +175,48 @@ def test_counter_reads_fresh_then_reused(cluster, rng):
     for want in [(1, 0), (1, 1), (1, 2)]:
         cluster.access.put(data, codemode=cmode.CodeMode.EC6P3)
         assert (read("fresh") - fresh0, read("reused") - reused0) == want
-    # another shape finds nothing to reuse
+    # a smaller shape fits a kept buffer; a larger one finds none
     cluster.access.put(data[:10], codemode=cmode.CodeMode.EC6P3)
-    assert (read("fresh") - fresh0, read("reused") - reused0) == (2, 2)
+    assert (read("fresh") - fresh0, read("reused") - reused0) == (1, 3)
+    cluster.access.put(data * 2, codemode=cmode.CodeMode.EC6P3)
+    assert (read("fresh") - fresh0, read("reused") - reused0) == (2, 3)
 
 
-def test_small_rows_stay_with_the_allocator(tmp_path, rng):
-    """As shipped the list engages only above the allocator's mmap
+def test_small_rows_stay_with_the_allocator(tmp_path, kept, rng):
+    """As shipped the kept arrays serve only above the allocator's mmap
     ceiling: a small PUT takes and leaves nothing."""
     c = Cluster(tmp_path)
     data = rng.integers(0, 256, 100_000, dtype=np.uint8).tobytes()
     for _ in range(2):
         loc = c.access.put(data, codemode=cmode.CodeMode.EC6P3)
-    assert c.access._free_rows == []
+    assert kept._kept == []
     assert c.access.get(loc) == data
 
 
 def test_concurrent_puts_never_share_rows(cluster, rng, monkeypatch):
-    """Eight clients, more than the cores' worth of switches: an array
+    """Eight clients, more than the cores' worth of switches: a buffer
     is out with one PUT at a time, and every object reads back."""
     acc = cluster.access
-    out, guard, clashes = set(), threading.Lock(), []
-    take, give = acc._take_stripe_rows, acc._return_stripe_rows
+    out, guard, clashes = {}, threading.Lock(), []
+    take = acc._take_stripe_rows
+
+    def gone(key):
+        with guard:
+            del out[key]
 
     def taking(shape):
         rows = take(shape)
+        span = (rows.ctypes.data, rows.ctypes.data + rows.nbytes)
         with guard:
-            if id(rows) in out:
-                clashes.append(id(rows))
-            out.add(id(rows))
+            if any(a < span[1] and span[0] < z for a, z in out.values()):
+                clashes.append(span)
+            out[id(rows)] = span
+        # out until the PUT drops its rows: called before the view lets
+        # go of its buffer
+        weakref.finalize(rows, gone, id(rows))
         return rows
 
-    def giving(rows):
-        with guard:
-            out.discard(id(rows))
-        give(rows)
-
     monkeypatch.setattr(acc, "_take_stripe_rows", taking)
-    monkeypatch.setattr(acc, "_return_stripe_rows", giving)
     payloads = [rng.integers(0, 256, 2 * BLOB + 7 * i, dtype=np.uint8)
                 .tobytes() for i in range(8)]
     results: dict[int, list] = {}
@@ -187,7 +237,8 @@ def test_concurrent_puts_never_share_rows(cluster, rng, monkeypatch):
         assert not any(th.is_alive() for th in threads)
     finally:
         sys.setswitchinterval(old)
-    assert clashes == [] and out == set()
+    gc.collect()
+    assert clashes == [] and out == {}
     assert metrics.access_stripe_buffers.value(result="reused") > 0
     for i, locs in results.items():
         assert len(locs) == 6
@@ -197,15 +248,15 @@ def test_concurrent_puts_never_share_rows(cluster, rng, monkeypatch):
 
 
 def test_failed_quorum_returns_rows_only_after_every_write(
-        cluster, rng, monkeypatch):
-    """A PUT that fails its quorum still waits for every shard write;
-    the array goes back only then."""
+        cluster, kept, rng, monkeypatch):
+    """A PUT that fails its quorum still waits for every shard write,
+    and then nothing holds its array: the next PUT is handed it."""
     acc = cluster.access
     for node in cluster.nodes[:2]:
         for d in node.disk_ids:
             node.break_disk(d)
-    running, guard, seen = [0], threading.Lock(), []
-    write, give = acc._write_shard, acc._return_stripe_rows
+    running, guard = [0], threading.Lock()
+    write = acc._write_shard
 
     def slow_write(*a):
         with guard:
@@ -217,72 +268,75 @@ def test_failed_quorum_returns_rows_only_after_every_write(
             with guard:
                 running[0] -= 1
 
-    def giving(rows):
-        seen.append(running[0])
-        give(rows)
-
     monkeypatch.setattr(acc, "_write_shard", slow_write)
-    monkeypatch.setattr(acc, "_return_stripe_rows", giving)
+    taken = rows_taken(acc, monkeypatch)
     data = rng.integers(0, 256, 10_000, dtype=np.uint8).tobytes()
     with pytest.raises(PutQuorumError):
         acc.put(data, codemode=cmode.CodeMode.EC6P3)
-    assert seen == [0] and len(acc._free_rows) == 1
+    assert running[0] == 0
+    assert holders(kept, taken[0][0]) == 0
 
 
 def test_rows_are_dropped_while_a_write_may_still_read_them(
         cluster, rng, monkeypatch):
-    """A PUT that ends in an error while its writes may still read the
-    rows (one write's future raises, the others still run): the array
-    is dropped, not kept. Since PR 35 such a PUT raises only once the
-    writes it started have ended, so the gate opens on a timer."""
+    """A PUT that ends in an error while its step or its writes may
+    still read the rows: no other PUT is handed them while anything
+    holds them. Such a PUT raises only once the writes it started have
+    ended, so the gate opens on a timer; a step whose wait timed out
+    still holds its submission, as the batcher's queue does."""
     acc = cluster.access
     write, release = acc._write_shard, threading.Event()
+    running, guard = [0], threading.Lock()
     threading.Timer(0.2, release.set).start()
 
     def write_or_die(vol, unit, bid, shard):
         if unit.index == 0:
             raise RuntimeError("boom")
-        release.wait(10)
-        return write(vol, unit, bid, shard)
+        with guard:
+            running[0] += 1
+        try:
+            release.wait(10)
+            return write(vol, unit, bid, shard)
+        finally:
+            with guard:
+                running[0] -= 1
 
     monkeypatch.setattr(acc, "_write_shard", write_or_die)
+    taken = rows_taken(acc, monkeypatch)
     data = rng.integers(0, 256, 10_000, dtype=np.uint8).tobytes()
     try:
         with pytest.raises(RuntimeError, match="boom"):
             acc.put(data, codemode=cmode.CodeMode.EC6P3)
-        assert acc._free_rows == []
+        assert running[0] == 0
     finally:
         release.set()
-    # and a PUT whose step never ended keeps its array out of the list
+    # and a PUT whose step never ended: its array goes to no other PUT
+    # while the step holds it
     monkeypatch.setattr(acc, "_write_shard", write)
+
     class NeverEnds:
+        def __init__(self, rows):
+            self.rows = rows
+
         def wait(self, timeout=120.0):
             raise TimeoutError("step")
 
     enc = acc._encoder(int(cmode.CodeMode.EC6P3))
-    monkeypatch.setattr(enc, "encode_rows_async",
-                        lambda rows, shard_size: NeverEnds())
+    submit = enc.encode_rows_async
+    steps = []
+    monkeypatch.setattr(enc, "encode_rows_async", lambda rows, shard_size:
+                        steps.append(NeverEnds(rows)) or steps[-1])
     with pytest.raises(TimeoutError):
         acc.put(data, codemode=cmode.CodeMode.EC6P3)
-    assert acc._free_rows == []
-
-
-def test_free_list_never_exceeds_its_bound(cluster, rng, monkeypatch):
-    """Shapes that never repeat (a width rung each: the list keys by
-    the rung shape): the list stays under its bound in bytes, the
-    oldest array goes first."""
-    acc = cluster.access
-    monkeypatch.setattr(access_mod, "STRIPE_ROWS_KEPT_BYTES", 2_200_000)
-    blobs = [1, 2, 3, 4, 5, 6]
-    for k in blobs:
-        data = rng.integers(0, 256, k * BLOB, dtype=np.uint8).tobytes()
-        acc.put(data, codemode=cmode.CodeMode.EC6P3)
-        assert sum(r.nbytes for r in acc._free_rows) <= 2_200_000
-    # k stripes of 6 rows at the width rung of ceil(BLOB / 6) bytes,
-    # one tile: the newest arrays that fit are kept
-    assert [r.shape for r in acc._free_rows] == [
-        (k, 6, rs_kernel.rung_width(-(-BLOB // 6))) for k in blobs[-2:]]
-    # one array larger than the bound is not kept at all
-    monkeypatch.setattr(access_mod, "STRIPE_ROWS_KEPT_BYTES", 10)
-    acc.put(b"x" * 1000, codemode=cmode.CodeMode.EC6P3)
-    assert acc._free_rows == []
+    held = taken[-1]
+    monkeypatch.setattr(enc, "encode_rows_async", submit)
+    gc.collect()
+    for _ in range(2):
+        loc = acc.put(data, codemode=cmode.CodeMode.EC6P3)
+        assert taken[-1] != held
+        assert_stored_equals_reference(cluster, loc, data)
+    del steps[:]  # the step lets go
+    gc.collect()
+    reused = stripe_buffers()[0]
+    acc.put(data, codemode=cmode.CodeMode.EC6P3)
+    assert stripe_buffers()[0] == reused + 1

@@ -1,15 +1,13 @@
-"""The repair worker keeps its step array (PR 40): every decode step's
-array is a view of one buffer the worker owns from the first step of a
-backlog until it finds no task to lease — made once, replaced (the old
-one let go first) by a step that does not fit, and written in full by
-`_stack`, so what the last step left in it reaches neither the device's
-live columns nor a stored shard. What a task asks of the nodes, the
-scheduler and the engine is the parent's, call for call. CPU, small
+"""A repair step's array is kept: every decode step's array is a
+view of a buffer the process keeps (`hostmem.KEPT`) — made by a step
+that fits no buffer nothing holds, handed to the next step that fits it
+once the last view is gone, kept while the worker is idle — and written
+in full by `_stack`, so what the buffer last held reaches neither the
+device's live columns nor a stored shard. What a task asks of the nodes,
+the scheduler and the engine is the parent's, call for call. CPU, small
 sizes, seeded; the plain reference is cellbench/reference.py through
 `reference_stripe`."""
 
-import gc
-import sys
 import time
 import weakref
 
@@ -19,9 +17,10 @@ import pytest
 from cubefs_tpu.codec import codemode as cmode
 from cubefs_tpu.codec.batcher import BatchCodec, admit
 from cubefs_tpu.ops import rs_kernel
-from cubefs_tpu.utils import metrics
+from cubefs_tpu.utils import hostmem, metrics
 from cubefs_tpu.utils import trace as tracelib
-from test_put_stripe_rows import reference_stripe
+from test_put_stripe_rows import holders, reference_stripe
+from test_put_stripe_rows import scribble as scribble_kept
 from test_repair_lease import recorded, sched_calls, shard_sizes
 from test_repair_rungs import fill, fleet, lose, rebuilt
 
@@ -31,16 +30,17 @@ ENGINES = ["tpu", "cpp", "numpy-xor", "numpy"]
 
 
 class Copying(BatchCodec):
-    """Keeps a copy of what every engine call was handed, and the array
-    itself: a step's array is a view of a buffer that is filled again,
-    so what it held is gone by the time a test looks."""
+    """Keeps a copy of what every engine call was handed, and the id of
+    the array itself: a step's array is a view of a buffer that is
+    filled again, so what it held is gone by the time a test looks —
+    and a reference to it would keep the buffer from the next step."""
 
     def __init__(self, **kw):
         super().__init__(**kw)
-        self.seen: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+        self.seen: list[tuple[np.ndarray, np.ndarray, int]] = []
 
     def _engine_call(self, key, coeff, arr):
-        self.seen.append((coeff.copy(), arr.copy(), arr))
+        self.seen.append((coeff.copy(), arr.copy(), id(arr)))
         return super()._engine_call(key, coeff, arr)
 
 
@@ -71,17 +71,23 @@ def fill_one_size(c, mode, seed, count, size) -> list:
 
 
 def scribble(c) -> None:
-    if c.worker._buffer is not None:
-        c.worker._buffer[:] = 0xFF
+    scribble_kept(hostmem.KEPT)
+
+
+def held_buffers() -> int:
+    """Kept buffers something holds, once the garbage is collected."""
+    kept = hostmem.KEPT
+    addresses = [buf.ctypes.data for buf in kept._kept]
+    return sum(holders(kept, a) > 0 for a in addresses)
 
 
 def drain_scribbling(c, max_leases=50) -> int:
-    """Run the backlog to its end with the kept buffer overwritten with
-    0xFF after every lease; the number of leases run."""
+    """Run the backlog to its end with every kept buffer overwritten
+    with 0xFF after every lease; the number of leases run."""
     for ran in range(max_leases):
         if not c.worker.run_once():
             return ran
-        assert c.worker._buffer is not None  # kept while the backlog lasts
+        assert hostmem.KEPT._kept  # kept after the lease
         scribble(c)
     raise AssertionError("worker did not drain")
 
@@ -171,7 +177,7 @@ def test_a_backlog_of_one_size_volumes_is_rebuilt_over_one_scribbled_buffer(
     before = arrays()
     assert drain_scribbling(c) == 3
     assert c.worker.completed == 3 and c.worker.failed == 0
-    assert c.worker._buffer is None  # the backlog ended
+    assert held_buffers() == 0  # the backlog ended: nothing holds one
     for objects, bad in volumes:
         assert_rebuilt(c, objects, EC12P4, [bad])
     rung_b, rung_s = rs_kernel.repair_step_shape(
@@ -188,8 +194,8 @@ def test_a_backlog_of_one_size_volumes_is_rebuilt_over_one_scribbled_buffer(
 def test_mixed_size_volumes_share_the_buffer_larger_after_smaller_and_back(
         tmp_path, order):
     """Volumes of seeded log-uniform sizes over the three RS codemodes
-    in one backlog: steps of many shapes follow one another over one
-    buffer that held 0xFF — a larger one replaces it, a smaller one is
+    in one backlog: steps of many shapes follow one another over kept
+    buffers that held 0xFF — a larger step makes one, a smaller one is
     a prefix view — and every shard comes out as the reference's."""
     copying = Copying()
     c = keeper(tmp_path, copying=copying)
@@ -252,7 +258,7 @@ def test_the_units_of_a_lease_are_decoded_over_one_view(tmp_path, mode,
     for i in range(0, len(copying.seen), k):
         rows, held, arr = copying.seen[i]
         for rows_j, held_j, arr_j in copying.seen[i + 1:i + k]:
-            assert arr_j is arr  # one array, not one a unit
+            assert arr_j == arr  # one array, not one a unit
             assert np.array_equal(held_j, held)  # nothing wrote between
             assert rows_j.tobytes() != rows.tobytes()
         assert rows.shape == (rs_kernel.REPAIR_ROWS, t.n)
@@ -262,9 +268,9 @@ def test_the_units_of_a_lease_are_decoded_over_one_view(tmp_path, mode,
 def test_a_steps_result_is_its_own_memory_on_every_engine_leg(tmp_path,
                                                               engine):
     """What `_apply` returns shares nothing with the array it was
-    handed, and the worker's buffer has no holder but the worker once
-    the lease has returned: neither the batcher nor an engine keeps a
-    submitted step array (a CPU `jnp.asarray` may alias host memory)."""
+    handed, and no kept buffer has a holder once the lease has
+    returned: neither the batcher nor an engine keeps a submitted step
+    array (a CPU `jnp.asarray` may alias host memory)."""
     c = keeper(tmp_path, engine=engine)
     objects = fill(c, EC6P6, seed=43, count=12, lo=20_000)
     vid = objects[0][1].slices[0].vid
@@ -275,7 +281,7 @@ def test_a_steps_result_is_its_own_memory_on_every_engine_leg(tmp_path,
 
     def apply(t, rows, batch, sizes, exact):
         out = real(t, rows, batch, sizes, exact)
-        assert not np.shares_memory(out, c.worker._buffer)
+        assert not np.shares_memory(out, batch.base)
         want, held = np.array(out), batch.copy()
         batch[...] = 0xFF  # the next fill, early
         assert np.array_equal(out, want)
@@ -287,10 +293,9 @@ def test_a_steps_result_is_its_own_memory_on_every_engine_leg(tmp_path,
     assert c.worker.run_once() and c.worker.completed == 2
     assert len(checked) >= 2
     # jax on the CPU shares a host array's memory and drops it with
-    # its own garbage; on the device it copies
-    gc.collect()
-    holders = sys.getrefcount(c.worker._buffer)
-    assert holders == 2  # the attribute and getrefcount's own argument
+    # its own garbage (`held_buffers` collects it); on the device it
+    # copies
+    assert hostmem.KEPT._kept and held_buffers() == 0
     assert_rebuilt(c, objects, EC6P6, [2, 8])
 
 
@@ -309,45 +314,47 @@ def test_first_step_fresh_then_reused_and_a_larger_step_fresh_once(
         shapes.append((16, 6, rs_kernel.rung_width(size // 6)))
     before = arrays()
     tracelib.reset_collector()
-    held = []  # (buffers made so far, the kept one's size) after each lease
+    held = []  # (buffers made so far, the kept sizes) after each lease
     while c.worker.run_once():
-        held.append((len(made), c.worker._buffer.size))
+        held.append((len(made), sorted(b.size for b in hostmem.KEPT._kept)))
     assert c.worker.completed == 5
     tags = step_tags()
     assert [(x["rung_b"], 6, x["rung_s"]) for x in tags] == shapes
     assert [x["array"] for x in tags] == [
         "fresh", "reused", "fresh", "reused", "reused"]
     assert arrays_since(before) == (3, 2)
-    # two buffers in all, the second made when the first was gone, and
-    # the smaller step after it a view of the larger buffer
-    assert [alive for _, alive in made] == [0, 0]
+    # two buffers in all, the second made while the first was kept, and
+    # both kept when idle: the smaller steps after the larger one are
+    # views of the smaller buffer (the smallest that fits)
+    assert [alive for _, alive in made] == [0, 1]
     first, second = (16 * 6 * shapes[i][2] for i in (0, 2))
-    assert held == [(1, first), (1, first), (2, second), (2, second),
-                    (2, second)]
-    assert all(ref() is None for ref, _ in made)  # and none when idle
+    assert held == [(1, [first]), (1, [first]), (2, [first, second]),
+                    (2, [first, second]), (2, [first, second])]
+    assert all(ref() is not None for ref, _ in made)
 
 
-def test_a_view_is_the_head_of_the_buffer_and_c_contiguous(tmp_path):
+def test_a_view_is_the_head_of_the_buffer_and_c_contiguous(tmp_path, kept):
     c = keeper(tmp_path)
     w = c.worker
     a = w._step_array((8, 6, 32768))
-    buf = w._buffer
-    assert buf.ndim == 1 and buf.dtype == np.uint8 and buf.size == a.size
+    buf = weakref.ref(a.base)  # not a reference: that would hold it
+    assert buf().ndim == 1 and buf().dtype == np.uint8
+    assert buf().size == a.size
     assert a.flags.c_contiguous and a.dtype == np.uint8
-    assert a.ctypes.data == buf.ctypes.data
+    assert a.ctypes.data == buf().ctypes.data
     del a
     b = w._step_array((8, 3, 32768))  # fits: the same buffer's head
-    assert w._buffer is buf and b.shape == (8, 3, 32768)
-    assert b.flags.c_contiguous and b.ctypes.data == buf.ctypes.data
+    assert b.base is buf() and b.shape == (8, 3, 32768)
+    assert b.flags.c_contiguous and b.ctypes.data == buf().ctypes.data
     b[...] = 7
-    assert (buf[:b.size] == 7).all()
-    ref = weakref.ref(buf)
-    del b, buf
-    big = w._step_array((16, 6, 32768))  # does not: a new one, the old gone
-    assert ref() is None and w._buffer.size == big.size
+    assert (buf()[:b.size] == 7).all()
+    del b
+    big = w._step_array((16, 6, 32768))  # does not: a new one, the old kept
+    assert big.base.size == big.size and big.base is not buf()
+    assert sorted(x.size for x in kept._kept) == [buf().size, big.size]
 
 
-def test_the_door_closes_the_counter_and_the_tag(tmp_path, monkeypatch):
+def test_the_door_closes_the_counter_and_the_tag(tmp_path, kept, monkeypatch):
     monkeypatch.setenv("CUBEFS_TRACE", "0")
     c = keeper(tmp_path)
     objects = fill(c, EC3P3, seed=44, count=6)
@@ -356,16 +363,16 @@ def test_the_door_closes_the_counter_and_the_tag(tmp_path, monkeypatch):
     tracelib.reset_collector()
     assert c.worker.run_once() and c.worker.completed == 1
     assert arrays_since(before) == (0, 0) and step_tags() == []
-    assert c.worker._buffer is not None  # kept all the same
+    assert kept._kept  # kept all the same
     assert_rebuilt(c, objects, EC3P3, [1])
 
 
-# ---------------- (c) an idle worker holds nothing ----------------
+# ---------------- (c) an idle worker holds no view ----------------
 
-def test_no_task_to_lease_lets_the_buffer_go_and_the_next_backlog_starts_fresh(
-        tmp_path):
+def test_an_idle_workers_buffer_serves_the_next_backlogs_first_step(
+        tmp_path, kept):
     c = keeper(tmp_path)
-    assert c.worker.run_once() is False and c.worker._buffer is None
+    assert c.worker.run_once() is False and kept._kept == []
     volumes = []
     for seed in (1, 2, 3):
         new_volume(c)
@@ -374,32 +381,41 @@ def test_no_task_to_lease_lets_the_buffer_go_and_the_next_backlog_starts_fresh(
         c.sched.manual_migrate(objects[0][1].slices[0].vid, 2)
     before = arrays()
     assert c.worker.run_once() and c.worker.run_once()
-    first = weakref.ref(c.worker._buffer)
-    assert first() is not None and arrays_since(before) == (1, 1)
+    assert arrays_since(before) == (1, 1) and len(kept._kept) == 1
+    first = kept._kept[0].ctypes.data
     assert c.worker.run_once() is False
-    assert c.worker._buffer is None and first() is None
+    assert holders(kept, first) == 0  # idle: no view left
     c.sched.manual_migrate(volumes[2][0][1].slices[0].vid, 2)
+    seen, take = [], c.worker._step_array
+
+    def seam(shape):
+        arr = take(shape)
+        seen.append(arr.ctypes.data)
+        return arr
+
+    c.worker._step_array = seam
     assert c.worker.run_once()
-    assert arrays_since(before) == (1, 2)
-    assert c.worker._buffer is not None
+    assert arrays_since(before) == (2, 1) and seen == [first]
     for objects in volumes:
         assert_rebuilt(c, objects, EC3P3, [2])
 
 
-def test_the_workers_own_loop_holds_nothing_when_idle_or_stopped(tmp_path):
+def test_the_workers_own_loop_holds_no_view_when_idle_or_stopped(
+        tmp_path, kept):
     c = keeper(tmp_path)
     objects = fill(c, EC6P6, seed=45, count=8)
     c.sched.manual_migrate(objects[0][1].slices[0].vid, 3)
     c.worker.start(idle_wait=0.01)
     try:
         deadline = time.monotonic() + 60
-        while time.monotonic() < deadline and not (
-                c.worker.completed == 1 and c.worker._buffer is None):
+        while time.monotonic() < deadline and c.worker.completed < 1:
             time.sleep(0.01)
+        time.sleep(0.05)  # idle: the loop asks for work and finds none
+        assert c.worker.completed == 1 and held_buffers() == 0
     finally:
         c.worker.stop()
         c.worker._thread.join(timeout=30)
-    assert c.worker.completed == 1 and c.worker._buffer is None
+    assert kept._kept and held_buffers() == 0
     assert_rebuilt(c, objects, EC6P6, [3])
 
 
@@ -500,16 +516,17 @@ def test_an_msr_conventional_decode_takes_its_exact_size_array_from_the_seam(
     objects = fill(c, mode, seed=46, count=10, lo=4_000)
     vid = objects[0][1].slices[0].vid
     sizes = shard_sizes(c, vid, 0)
-    asked, handed = [], []
+    asked, handed, at = [], [], []
     take, apply = c.worker._step_array, c.worker.codec.matrix_apply
 
     def seam(shape):
         asked.append(shape)
-        return take(shape)
+        arr = take(shape)
+        at.append(arr.ctypes.data)
+        return arr
 
     def matrix_apply(rows, shards, width=None):
-        handed.append((shards.shape,
-                       shards.ctypes.data == c.worker._buffer.ctypes.data))
+        handed.append((shards.shape, shards.ctypes.data == at[-1]))
         return apply(rows, shards, width=width)
 
     c.worker._step_array = seam

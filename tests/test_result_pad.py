@@ -1,8 +1,8 @@
 """No pad column of a device step reaches a shard. A large result's
 column blocks that lie wholly past the step's payload width are never
 brought back (`codec/engine.py:_to_host`): those columns of the kept
-host buffer hold whatever they held before — here a poison byte, put in
-every buffer as it is handed out. An EC16P20L2 PUT and the rebuild of a
+host array hold whatever they held before — here a poison byte, put in
+every kept array as it is handed out. An EC16P20L2 PUT and the rebuild of a
 unit it lost, at the tests' width (4 KiB shards in a 32 KiB rung) and at
 `ingest-lrc`'s and `lrc-disk-repair`'s (8 MiB blobs: 524288 columns of a
 589824-column rung), store and rebuild the plain reference's shards,
@@ -27,13 +27,13 @@ POISON = 0xA5
 
 
 @pytest.fixture
-def cuts(monkeypatch):
-    """Every device result through the kept buffers, each buffer
-    poisoned as it is handed out; returns the (blocks brought back,
+def cuts(kept, monkeypatch):
+    """Every device result through the kept arrays, each array poisoned
+    as it is handed out (a PUT's rows and a repair's step arrays too:
+    their callers write every byte); returns the (blocks brought back,
     blocks) of every large result."""
     monkeypatch.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
     monkeypatch.setattr(batcher_mod.DEFAULT, "dp_enabled", False)
-    kept = engine.ResultBuffers()
     take = kept.take
 
     def poisoned(shape):
@@ -42,7 +42,6 @@ def cuts(monkeypatch):
         return buf, came
 
     monkeypatch.setattr(kept, "take", poisoned)
-    monkeypatch.setattr(engine, "RESULTS", kept)
     seen = []
     to_host = engine._to_host
 

@@ -20,7 +20,8 @@ from cubefs_tpu.codec.engine import get_engine
 from cubefs_tpu.ops import gf256, pallas_gf, rs_kernel
 from cubefs_tpu.utils import hostmem, metrics, rpc
 from test_blob_e2e import Cluster
-from test_put_stripe_rows import (BLOB, MODES, assert_stored_equals_reference)
+from test_put_stripe_rows import (BLOB, MODES, assert_stored_equals_reference,
+                                  scribble, stripe_buffers)
 
 TILE = pallas_gf.DEFAULT_TILE
 NUMPY = get_engine("numpy")
@@ -225,7 +226,7 @@ def test_the_device_engine_pads_what_reaches_it_in_any_other_shape(rng):
 @pytest.fixture(scope="module")
 def cluster(tmp_path_factory):
     """One cluster for every case below, its codec callers on the
-    device engine; the free list engages at the tests' sizes."""
+    device engine; the kept arrays serve the tests' sizes."""
     mp = pytest.MonkeyPatch()
     mp.setattr(hostmem, "MALLOC_MMAP_MAX", 0)
     c = Cluster(tmp_path_factory.mktemp("ladder"), n_nodes=4,
@@ -238,7 +239,9 @@ def cluster(tmp_path_factory):
 
 @pytest.mark.parametrize("case", range(64))
 @pytest.mark.parametrize("mode", MODES, ids=[m.name for m in MODES])
-def test_a_put_of_any_size_stores_the_reference_stripe(cluster, mode, case):
+def test_a_put_of_any_size_stores_the_reference_stripe(cluster, kept,
+                                                       monkeypatch, mode,
+                                                       case):
     """A seeded random byte count (1 B .. 3 blobs, log-uniform) through
     an array that last held 0xFF everywhere, pad columns too: stored
     shards, parity and CRCs are the reference stripe's — what the code
@@ -247,13 +250,16 @@ def test_a_put_of_any_size_stores_the_reference_stripe(cluster, mode, case):
     r = np.random.default_rng([int(mode), case])
     size = int(np.exp(r.uniform(0.0, np.log(3 * BLOB))))
     data = r.integers(0, 256, size, dtype=np.uint8).tobytes()
+    shapes, take = [], acc._take_stripe_rows
+    monkeypatch.setattr(acc, "_take_stripe_rows", lambda shape: shapes.append(
+        shape) or take(shape))
     acc.put(b"\xff" * size, codemode=mode)
-    rows = acc._free_rows[-1]
-    rows[:] = 0xFF
+    scribble(kept)
+    reused = stripe_buffers()[0]
     loc = acc.put(data, codemode=mode)
-    assert acc._free_rows[-1] is rows  # the one reused
+    assert stripe_buffers()[0] == reused + 1  # a kept buffer, 0xFF
     enc = acc._encoder(int(mode))
-    assert rows.shape[2] == enc.row_width(enc.shard_size(min(size, BLOB)))
+    assert shapes[1][2] == enc.row_width(enc.shard_size(min(size, BLOB)))
     assert loc.crc == reference.crc32(data)
     assert_stored_equals_reference(cluster, loc, data)
     assert acc.get(loc) == data
