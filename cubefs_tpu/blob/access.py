@@ -268,11 +268,18 @@ class AccessHandler:
 
     def ready(self, max_object_bytes: int) -> int:
         """What a deployment does once at start-up, from what it knows:
-        the codemodes its policies serve and its largest object. Every
-        program a PUT of 1..`max_object_bytes` bytes (alone or met by
-        others in a codec step) or a degraded GET of one can ask the
-        device for is built here, so none is compiled inside a request.
-        Never implied by construction; returns the number of steps."""
+        the codemodes its policies serve and its largest object. Built
+        here, so none is compiled inside a request: every program a PUT
+        of 1..`max_object_bytes` bytes can ask the device for, alone or
+        met by others in a codec step, and (`Encoder.ready`) the decode
+        of an RS codemode's degraded GET of one at every step shape that
+        concurrent GETs which lost the same units can meet at: the
+        (n, n) matrix at every width rung of the codemode's blobs and
+        every stripe rung up to the batcher's bounds. Of an LRC
+        codemode only the one-stripe global decode is built, not its
+        local-stripe decodes (`_local_reconstruct`); of an MSR codemode
+        no decode. Never implied by construction; returns the number
+        of encode steps."""
         blob_size = self.cfg.blob_size
         steps = 0
         for p in self.cfg.policies:
@@ -339,11 +346,14 @@ class AccessHandler:
             )
             for k in range(sl.count):
                 payload_len = min(sl.blob_size, remaining)
-                out += self._get_blob(enc, vol, sl.min_bid + k, payload_len)
+                blob = self._get_blob(enc, vol, sl.min_bid + k, payload_len)
+                with tracelib.stage("assemble"):
+                    out += blob
                 remaining -= payload_len
-        data = bytes(out)
-        if loc.crc and zlib.crc32(data) != loc.crc:
-            raise GetError("payload crc mismatch after reassembly")
+        with tracelib.stage("assemble"):
+            data = bytes(out)
+            if loc.crc and zlib.crc32(data) != loc.crc:
+                raise GetError("payload crc mismatch after reassembly")
         return data
 
     def _read_shard(self, vol: VolumeInfo, idx: int, bid: int):
@@ -402,8 +412,9 @@ class AccessHandler:
                         errs[i] = err
             self._observe_pool_waits("get_shard", waits)
         if all(i in got for i in range(t.n)):  # got may also hold hedged parity
-            data = b"".join(got[i] for i in range(t.n))
-            return data[:payload_len]
+            with tracelib.stage("assemble"):
+                data = b"".join(got[i] for i in range(t.n))
+                return data[:payload_len]
 
         # degraded read. If the hedge already yielded n shards (mixed
         # data+parity), decode straight away — draining the straggler
@@ -428,8 +439,9 @@ class AccessHandler:
                         vol, bid, {i: got[i] for i in errs if i in got},
                         errs)
                     metrics.reconstruct_reads.inc(path="local")
-                    data = b"".join(got[i] for i in range(t.n))
-                    return data[:payload_len]
+                    with tracelib.stage("assemble"):
+                        data = b"".join(got[i] for i in range(t.n))
+                        return data[:payload_len]
             extra_idx = [i for i in range(t.n, t.n + t.m)
                          if i not in got and i not in errs]
             for i, p, err in self._map(
@@ -461,8 +473,9 @@ class AccessHandler:
             vol, bid,
             {i: stripe[i].tobytes() for i in all_bad if i in errs and i < t.n},
             errs)
-        data = np.ascontiguousarray(stripe[: t.n]).reshape(-1)[:payload_len]
-        return data.tobytes()
+        with tracelib.stage("assemble"):
+            data = np.ascontiguousarray(stripe[: t.n]).reshape(-1)
+            return data[:payload_len].tobytes()
 
     def _read_repair(self, vol: VolumeInfo, bid: int,
                      repaired: dict[int, bytes], errs: dict) -> None:

@@ -134,20 +134,26 @@ class Encoder:
 
     def ready(self, lo: int, hi: int, stripes: int = 1) -> int:
         """Build every program that encodes of blobs of `lo`..`hi`
-        payload bytes, up to `stripes` blobs a call, can ask the
-        device for: one zero step through the encoder's own door at
-        every rung of the ladder that its batcher's bounds can reach
-        (a device engine builds a rung's decode program with its
-        encode). What a deployment does once, before its first request;
-        returns the number of steps."""
+        payload bytes, up to `stripes` blobs a call, and degraded reads
+        of such blobs can ask the device for: one zero step through the
+        encoder's own door at every rung of the ladder that its
+        batcher's bounds can reach, then every decode step of
+        `_decode_shapes`. What a deployment does once, before its first
+        request; returns the number of encode steps (every program it
+        builds, decodes too, counts in `cubefs_codec_programs_total`)."""
         batcher = self._batcher()
         if batcher is None:
             return 0
         cols, lo_w, hi_w = self._step_geometry(lo, hi)
-        shapes = rs_kernel.ladder(cols, lo_w, hi_w, batcher.max_step_bytes,
-                                  batcher.max_batch, stripes)
+        bounds = (batcher.max_step_bytes, batcher.max_batch)
+        shapes = rs_kernel.ladder(cols, lo_w, hi_w, *bounds, stripes)
         for b, width in shapes:
             self._ready_step(b, width)
+        decodes = self._decode_shapes(lo_w, hi_w, *bounds)
+        n = self.t.n
+        for b, width in decodes:
+            self.engine.matrix_apply(np.eye(n, dtype=np.uint8),
+                                     np.zeros((b, n, width), dtype=np.uint8))
         return len(shapes)
 
     def _step_geometry(self, lo: int, hi: int) -> tuple[int, int, int]:
@@ -157,6 +163,23 @@ class Encoder:
     def _ready_step(self, b: int, width: int) -> None:
         self.encode_rows_async(
             np.zeros((b, self.t.n, width), dtype=np.uint8)).wait()
+
+    def _decode_shapes(self, lo_w: int, hi_w: int, max_step_bytes: int,
+                       max_batch: int) -> list[tuple[int, int]]:
+        """(B_rung, S_rung) of every decode a degraded GET of shards of
+        `lo_w`..`hi_w` bytes can ask for. A GET decodes a blob at a time
+        by one (n, n) matrix (`_reconstruct` pads it to n rows), and the
+        batcher joins concurrent GETs that lost the same units into one
+        step: every stripe rung up to its bounds at every width rung.
+        Where m == n these are the encode's own programs, built above.
+        The split with the device engine: `engine.ready_decode` builds
+        the one-stripe rung with a geometry's first encode, for a
+        process that never calls `ready`; the door builds the rest, and
+        its one-stripe steps find that program built."""
+        if self.t.m == self.t.n:
+            return []
+        return rs_kernel.ladder(self.t.n, lo_w, hi_w, max_step_bytes,
+                                max_batch)
 
     # -- reference Encoder interface ------------------------------------
     def encode(self, shards: np.ndarray) -> np.ndarray:
@@ -268,11 +291,12 @@ class Encoder:
             raise ECError(f"unrecoverable: only {len(present)} of {n} shards")
         rows = rs_kernel.reconstruct_rows(n, total, present, wanted)
         if len(wanted) < n:
-            # one decode shape per geometry, (n, n, S), whatever is
-            # missing: the device engines compile that program with the
-            # geometry's encode (engine.ready_decode), so a survivor
-            # set nobody has seen costs a matrix upload, not a compile.
-            # The zero rows' outputs are dropped.
+            # one decode matrix shape per geometry, (n, n), whatever is
+            # missing: the device engines compile its one-stripe program
+            # with the geometry's encode (engine.ready_decode) and
+            # `ready` its coalesced steps, so a survivor set nobody has
+            # seen costs a matrix upload, not a compile. The zero rows'
+            # outputs are dropped.
             rows = np.concatenate(
                 [rows, np.zeros((n - len(wanted), n), dtype=np.uint8)])
         rec = self.engine.matrix_apply(rows, shards[..., present[:n], :])
@@ -351,6 +375,12 @@ class MsrEncoder(Encoder):
         self.encode_rows_async(np.zeros(
             (b, self.t.n, width * self.alpha), dtype=np.uint8)).wait()
 
+    def _decode_shapes(self, lo_w: int, hi_w: int, max_step_bytes: int,
+                       max_batch: int) -> list[tuple[int, int]]:
+        """None: a repair's rows over the sub-shards are its own, a
+        shape per lost set (`_reconstruct`)."""
+        return []
+
     def _submit_rows(self, data: np.ndarray, shard_size: int):
         batcher = self._batcher()
         if batcher is None:
@@ -422,15 +452,17 @@ class LrcEncoder(Encoder):
     def rows(self) -> np.ndarray:
         return _lrc_rows(self.t)
 
-    def _ready_step(self, b: int, width: int) -> None:
-        """The composed step's program and, once a rung, the (n, n)
-        decode of a degraded GET at it: an RS encode gets that from the
-        device engine (engine.ready_decode), a step of rows does not."""
-        super()._ready_step(b, width)
-        if b == 1:
-            n = self.t.n
-            self.engine.matrix_apply(np.eye(n, dtype=np.uint8),
-                                     np.zeros((1, n, width), dtype=np.uint8))
+    def _decode_shapes(self, lo_w: int, hi_w: int, max_step_bytes: int,
+                       max_batch: int) -> list[tuple[int, int]]:
+        """One (n, n) decode a width rung, of one stripe: a degraded GET
+        mends a lost data unit inside its AZ's local stripe first
+        (blob/access.py `_local_reconstruct`, decodes of its own rows
+        that no door builds), so the global decode, and GETs that meet
+        in one, are the rare case. An RS encode gets the same program
+        from the device engine (engine.ready_decode); a step of
+        composed rows does not."""
+        return [(1, w) for b, w in rs_kernel.ladder(
+            self.t.n, lo_w, hi_w, max_step_bytes, max_batch) if b == 1]
 
     def _parity(self, data: np.ndarray) -> np.ndarray:
         t = self.t

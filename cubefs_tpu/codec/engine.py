@@ -217,12 +217,14 @@ def device_call(eng, op: str, matrix, program, host_in: np.ndarray,
 def ready_decode(n: int, s: int) -> None:
     """Called by the device engine after an encode of geometry (n data
     shards at the width rung s): the first time, one zero stripe goes
-    through the rung's decode shape — n rows solved from n survivors,
-    (1, n, s), what codec/encoder.py's reconstruct always asks for — so
-    its program is compiled (and its Pallas gate paid) with the encode's
-    and a hedged or degraded GET of any size in the rung never compiles
-    inside a request. Where n == m that is the encode's own program and
-    nothing is built."""
+    through the rung's one-stripe decode shape — n rows solved from n
+    survivors, (1, n, s), what codec/encoder.py's reconstruct asks for
+    a blob — so its program is compiled (and its Pallas gate paid) with
+    the encode's, and a hedged or degraded GET that meets no other GET
+    in its step never compiles inside a request. The steps of 2, 4 or 8
+    stripes that concurrent degraded GETs meet in are built by the
+    deployment's door (`Encoder.ready`), not here. Where n == m that is
+    the encode's own program and nothing is built."""
     def build() -> bool:
         coeff = np.eye(n, dtype=np.uint8)
         planes, program = rs_kernel.plan(coeff, (1, n, s))

@@ -193,6 +193,7 @@ def test_ready_builds_the_composed_step_at_every_rung(monkeypatch):
     shapes = rs_kernel.ladder(16, 2048, -(-size // 16),
                               bc.max_step_bytes, bc.max_batch, 1)
     assert steps == len(encodes) == len(shapes)
+    assert len(decodes) == len({s for _, s in shapes})
     assert {k[2:4] for k in encodes} == {(16, 22)}
     assert {k[6] for k in encodes} == {2}
     assert sorted({k[4] for k in decodes}) == sorted({s for _, s in shapes})
