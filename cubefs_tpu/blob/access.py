@@ -19,6 +19,7 @@ import os
 import time
 import uuid
 import zlib
+from collections.abc import Container
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 
@@ -336,9 +337,13 @@ class AccessHandler:
                 return self._get(loc)
 
     def _get(self, loc: Location) -> bytes:
+        """The object's payload, crossing host memory once: every blob's
+        pieces (`_get_blob`) in order, one `b"".join` — which runs
+        without the GIL, its pieces all exact `bytes` and the total over
+        1 MiB — and the location's CRC over the bytes returned."""
         enc = self._encoder(loc.codemode)
-        t = enc.t
-        out = bytearray()
+        pieces: list[bytes] = []
+        decoded = 0
         remaining = loc.size
         for sl in loc.slices:
             vol = VolumeInfo.from_dict(
@@ -346,14 +351,18 @@ class AccessHandler:
             )
             for k in range(sl.count):
                 payload_len = min(sl.blob_size, remaining)
-                blob = self._get_blob(enc, vol, sl.min_bid + k, payload_len)
-                with tracelib.stage("assemble"):
-                    out += blob
+                blob_pieces, blob_decoded = self._get_blob(
+                    enc, vol, sl.min_bid + k, payload_len)
+                pieces += blob_pieces
+                decoded += blob_decoded
                 remaining -= payload_len
         with tracelib.stage("assemble"):
-            data = bytes(out)
+            data = b"".join(pieces)
             if loc.crc and zlib.crc32(data) != loc.crc:
                 raise GetError("payload crc mismatch after reassembly")
+        if tracelib.current() is not None:
+            metrics.access_get_bytes.inc(len(data) - decoded, source="read")
+            metrics.access_get_bytes.inc(decoded, source="decoded")
         return data
 
     def _read_shard(self, vol: VolumeInfo, idx: int, bid: int):
@@ -376,11 +385,14 @@ class AccessHandler:
 
     HEDGE_DELAY = 0.05  # backup-request trigger (stream_get.go hedging)
 
-    def _get_blob(self, enc, vol: VolumeInfo, bid: int, payload_len: int) -> bytes:
+    def _get_blob(self, enc, vol: VolumeInfo, bid: int, payload_len: int
+                  ) -> tuple[list[bytes], int]:
+        """The blob's payload as pieces, not joined: its data units in
+        order, each the `bytes` its read returned (or the `bytes` of its
+        decoded row), those wholly past `payload_len` left out and the
+        one it ends inside cut (`payload_pieces`). Returns the pieces
+        and how many of their bytes were decoded."""
         t = enc.t
-        shard_size = enc.shard_size(
-            payload_len if payload_len > 0 else 1
-        )
         # fast path: read the N data shards; if any straggle past the
         # hedge delay, fire backup requests at parity shards and take the
         # first n results (the reference's n-of-N+x hedged GET)
@@ -413,8 +425,8 @@ class AccessHandler:
             self._observe_pool_waits("get_shard", waits)
         if all(i in got for i in range(t.n)):  # got may also hold hedged parity
             with tracelib.stage("assemble"):
-                data = b"".join(got[i] for i in range(t.n))
-                return data[:payload_len]
+                return payload_pieces([got[i] for i in range(t.n)], (),
+                                      payload_len)
 
         # degraded read. If the hedge already yielded n shards (mixed
         # data+parity), decode straight away — draining the straggler
@@ -430,7 +442,8 @@ class AccessHandler:
             # LRC: before widening to the global stripe, try repairing
             # each missing data shard inside its local stripe — reads
             # stay within one AZ (the client's first, when labeled)
-            if t.l and any(i not in got for i in range(t.n)):
+            lost = [i for i in range(t.n) if i not in got]
+            if t.l and lost:
                 with tracelib.stage("local_reconstruct"):
                     self._local_reconstruct(enc, vol, bid, got, errs)
                 if all(i in got for i in range(t.n)):
@@ -440,8 +453,8 @@ class AccessHandler:
                         errs)
                     metrics.reconstruct_reads.inc(path="local")
                     with tracelib.stage("assemble"):
-                        data = b"".join(got[i] for i in range(t.n))
-                        return data[:payload_len]
+                        return payload_pieces([got[i] for i in range(t.n)],
+                                              lost, payload_len)
             extra_idx = [i for i in range(t.n, t.n + t.m)
                          if i not in got and i not in errs]
             for i, p, err in self._map(
@@ -474,8 +487,9 @@ class AccessHandler:
             {i: stripe[i].tobytes() for i in all_bad if i in errs and i < t.n},
             errs)
         with tracelib.stage("assemble"):
-            data = np.ascontiguousarray(stripe[: t.n]).reshape(-1)
-            return data[:payload_len].tobytes()
+            return payload_pieces(
+                [got[i] if i in got else stripe[i] for i in range(t.n)],
+                missing, payload_len)
 
     def _read_repair(self, vol: VolumeInfo, bid: int,
                      repaired: dict[int, bytes], errs: dict) -> None:
@@ -637,6 +651,31 @@ def fill_stripe_rows(rows: np.ndarray, data: bytes, blob_size: int,
             stripe[full, :rest] = blob[full * shard_size :]
             stripe[full, rest:] = 0
             stripe[full + 1 :] = 0
+
+
+def payload_pieces(rows: list, lost: Container[int], payload_len: int
+                   ) -> tuple[list[bytes], int]:
+    """The first `payload_len` bytes of a blob's data rows `rows` as
+    exact `bytes` pieces, in order — what `b"".join` needs to run
+    without the GIL. A `bytes` row that lies wholly inside the payload
+    is passed as it is, uncopied; the row the payload ends inside is
+    cut, the rows past it are left out, and a row of any other type (a
+    decoded ndarray row, a transport's memoryview) is copied into
+    `bytes`. Returns the pieces and how many of their bytes came from
+    the rows whose indexes are in `lost` (decoded, not read)."""
+    pieces, decoded, at = [], 0, 0
+    for i, row in enumerate(rows):
+        if at >= payload_len:
+            break
+        take = min(len(row), payload_len - at)
+        piece = row if take == len(row) else row[:take]
+        if type(piece) is not bytes:
+            piece = bytes(piece)
+        pieces.append(piece)
+        if i in lost:
+            decoded += take
+        at += take
+    return pieces, decoded
 
 
 NodePool = rpc.NodePool  # canonical home: cubefs_tpu/utils/rpc.py

@@ -351,6 +351,13 @@ access_shard_writes = DEFAULT.counter(
     "shard writes of PUTs by whether the write had ended when the PUT's "
     "encode did (under_encode) or not yet, parity writes among them "
     "(after_encode)", ("when",))
+# a GET's payload bytes (blob/access.py) by where they were taken from:
+# a shard read as it came back, or a data unit decoded in the request;
+# counted once a GET
+access_get_bytes = DEFAULT.counter(
+    "cubefs_access_get_bytes_total",
+    "payload bytes GETs returned, by whether they were taken from a "
+    "shard read (read) or a decoded data unit (decoded)", ("source",))
 
 # batched codec admission (codec/batcher.py): device-sized steps
 codec_batch_submissions = DEFAULT.counter(
